@@ -109,6 +109,9 @@ def main(argv: Optional[List[str]] = None) -> None:
         legion_sharded, roofline, serve_load, serve_pipeline, tpu_scale,
         workload_zoo,
     )
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     args = list(sys.argv[1:] if argv is None else argv)
     json_dir = None

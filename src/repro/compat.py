@@ -1,15 +1,11 @@
-"""Version shims for the moving parts of the JAX API this repo touches.
+"""The few JAX mesh and ``shard_map`` calls this repo makes, in one place.
 
-The repo targets the modern surface (``jax.shard_map``, ``axis_types`` on
-``jax.make_mesh``, pair-form ``AbstractMesh``); older installs (0.4.x) spell
-these ``jax.experimental.shard_map.shard_map(check_rep=...)``, no
-``axis_types``, and ``AbstractMesh(axis_sizes, axis_names)``.  Everything
-that depends on one of these goes through this module so the rest of the
-code is version-agnostic.
+The repo targets the installed JAX (0.9): ``jax.shard_map`` with
+``check_vma``, ``jax.make_mesh`` with explicit ``Auto`` axis types, and the
+positional ``AbstractMesh(axis_sizes, axis_names)``.  Callers go through
+these helpers so that a later API move touches one file.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 
@@ -25,92 +21,33 @@ def on_tpu() -> bool:
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names=None):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    ``check_vma`` maps onto the old API's ``check_rep`` (both gate the same
-    replication/varying-axis verification); ``axis_names`` (the manual axes)
-    maps onto the old API's complementary ``auto`` set.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """``jax.shard_map``; ``axis_names`` (the manual axes) defaults to all
+    of the mesh's axes."""
     kwargs = {}
     if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, **kwargs,
+        check_vma=check_vma, **kwargs,
     )
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit-auto axis types where supported.
+    """``jax.make_mesh`` with every axis of type ``Auto``.
 
     ``devices`` restricts the mesh to a subset (e.g. the first N of
     ``jax.devices()`` when a plan has fewer Legions than the host has
-    devices); older ``jax.make_mesh`` without the parameter falls back to a
-    direct ``Mesh`` construction.
+    devices).
     """
-    params = inspect.signature(jax.make_mesh).parameters
     kwargs = {}
-    if "axis_types" in params:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
     if devices is not None:
-        if "devices" not in params:
-            import numpy as np
-            return jax.sharding.Mesh(
-                np.asarray(devices).reshape(tuple(axis_shapes)),
-                tuple(axis_names),
-            )
         kwargs["devices"] = tuple(devices)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def _register_optimization_barrier_ad() -> None:
-    """Old JAX lacks a differentiation rule for ``optimization_barrier``.
-
-    The barrier is the identity function, so its JVP passes tangents
-    straight through; since the primitive then never appears in the linear
-    jaxpr, no transpose rule is required.  New JAX ships its own rule and
-    this is a no-op.
-    """
-    try:
-        from jax._src.lax import lax as _lax_internal
-        from jax.interpreters import ad as _ad
-        prim = _lax_internal.optimization_barrier_p
-    except (ImportError, AttributeError):
-        return
-    if prim in _ad.primitive_jvps:
-        return
-
-    def _jvp(primals, tangents):
-        return prim.bind(*primals), list(tangents)
-
-    _ad.primitive_jvps[prim] = _jvp
-
-
-_register_optimization_barrier_ad()
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs,
+    )
 
 
 def abstract_mesh(axis_shapes, axis_names):
-    """Device-free mesh for sharding-rule logic and tests.
-
-    New JAX takes ``AbstractMesh((("data", 16), ...))`` pairs; old JAX takes
-    ``AbstractMesh((16, ...), ("data", ...))`` positionally.
-    """
-    try:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_shapes))
-        )
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(axis_shapes), tuple(axis_names)
-        )
+    """Device-free mesh for sharding-rule logic and tests."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))

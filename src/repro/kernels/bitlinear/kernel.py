@@ -29,20 +29,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _unpack_kmajor_inkernel(packed: jnp.ndarray, bits: int) -> jnp.ndarray:
     """Kernel-safe K-major unpack (scalar shift constants only — Pallas
-    kernels may not capture array constants)."""
+    kernels may not capture array constants).
+
+    The bit work runs on int32: Mosaic has no 8-bit vector shifts, and the
+    sublane interleave below only lowers for 32-bit values.  Field ``i`` is
+    shifted to the top of the word and arithmetic-shifted back down, which
+    sign-extends it; the values are narrowed to int8 for the MXU last.
+    """
     per_byte = 8 // bits
-    mask = (1 << bits) - 1
-    sign_bit = 1 << (bits - 1)
-    parts = []
-    for i in range(per_byte):
-        v = jnp.bitwise_and(
-            jnp.right_shift(packed, jnp.uint8(bits * i)), jnp.uint8(mask)
-        ).astype(jnp.int8)
-        # sign-extend: subtract 2*sign_bit where the sign bit is set
-        v = v - jnp.left_shift(jnp.bitwise_and(v, sign_bit), 1)
-        parts.append(v)
+    word = packed.astype(jnp.int32)
+    parts = [
+        jnp.right_shift(jnp.left_shift(word, 32 - bits * (i + 1)), 32 - bits)
+        for i in range(per_byte)
+    ]
     stacked = jnp.stack(parts, axis=1)  # [bk/pb, pb, bn] — sublane expand
-    return stacked.reshape(packed.shape[0] * per_byte, packed.shape[1])
+    return stacked.reshape(
+        packed.shape[0] * per_byte, packed.shape[1]).astype(jnp.int8)
 
 
 def _bitlinear_kernel(x_ref, wp_ref, out_ref, acc_ref, *, n_k_tiles: int,

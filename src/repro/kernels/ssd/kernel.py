@@ -29,7 +29,7 @@ NEG_INF = -1e30
 
 
 def _ssd_kernel(
-    dta_ref,    # (1, q)     f32 — dt * A, negative
+    la_ref,     # (1, 1, q)  f32 — la = within-chunk cumsum(dt * A), a row
     dtx_ref,    # (1, q, p)  f32 — dt * x
     b_ref,      # (1, q, n)  f32
     c_ref,      # (1, q, n)  f32
@@ -43,16 +43,18 @@ def _ssd_kernel(
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    dta = dta_ref[0]                            # [q]
+    la_row = la_ref[0]                          # [1, q] log-decay from start
     dtx = dtx_ref[0].astype(jnp.float32)        # [q, p]
     b = b_ref[0].astype(jnp.float32)            # [q, n]
     c = c_ref[0].astype(jnp.float32)            # [q, n]
 
-    la = jnp.cumsum(dta)                        # [q] log-decay from chunk start
+    # la arrives as a lane row; the column form comes from a 2-D transpose
+    la_j = jnp.broadcast_to(la_row, (q, q))     # [i, j] -> la_j
+    la_i = la_j.T                               # [i, j] -> la_i
+    la_col = la_i[:, 0:1]                       # [q, 1]
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    seg = jnp.where(ii >= jj, la[:, None] - la[None, :], NEG_INF)
-    decay = jnp.exp(seg)                        # [q, q] causal decay mask L
+    decay = jnp.exp(jnp.where(ii >= jj, la_i - la_j, NEG_INF))  # causal L
 
     scores = jax.lax.dot_general(               # (C B^T) o L
         c, b, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -64,14 +66,14 @@ def _ssd_kernel(
     )
     h_prev = h_ref[...]                         # [n, p]
     y += jax.lax.dot_general(                   # inter-chunk (state readout)
-        c * jnp.exp(la)[:, None], h_prev,
+        c * jnp.exp(la_col), h_prev,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    la_total = la[q - 1]
-    decay_out = jnp.exp(la_total - la)          # [q]
+    la_total = la_row[:, q - 1:q]               # [1, 1]
+    decay_out = jnp.exp(la_total - la_col)      # [q, 1]
     h_ref[...] = jnp.exp(la_total) * h_prev + jax.lax.dot_general(
-        b * decay_out[:, None], dtx,
+        b * decay_out, dtx,
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -93,12 +95,17 @@ def ssd_scan(
     n = b.shape[-1]
     if s % chunk:
         raise ValueError(f"S={s} not divisible by chunk={chunk}")
+    # The within-chunk cumsum runs in XLA; the kernel takes it as [BH, 1, S]
+    # so each (1, chunk) block spans the whole sublane dim (TPU tiling rule).
+    la = jnp.cumsum(
+        dta.astype(jnp.float32).reshape(bh, s // chunk, chunk), axis=-1,
+    ).reshape(bh, 1, s)
     kernel = functools.partial(_ssd_kernel, q=chunk)
     return pl.pallas_call(
         kernel,
         grid=(bh, s // chunk),
         in_specs=[
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, chunk), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
@@ -107,4 +114,4 @@ def ssd_scan(
         out_shape=jax.ShapeDtypeStruct((bh, s, p), dtx.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(dta, dtx, b, c)
+    )(la, dtx, b, c)

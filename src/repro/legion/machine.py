@@ -576,7 +576,8 @@ class ShardedExecutor(ExecutorBackend):
                  axis: str = "legion") -> None:
         self.devices = devices
         self.axis = axis
-        self.devices_used = 0      # set per execute()
+        # distinct devices holding a shard of the last result (per execute)
+        self.devices_used = 0
         # mesh + jitted shard_map per (shard count, shared-x): keeps function
         # identity stable so repeat executions hit jit's compilation cache
         # instead of retracing every call
@@ -663,7 +664,6 @@ class ShardedExecutor(ExecutorBackend):
                 ws_stack[li, slot, :, :wsl.shape[1]] = wsl
                 slots.append((li, slot, a))
 
-        self.devices_used = nshard
         key = (nshard, shared_x)
         if key not in self._fns:
             mesh = make_mesh((nshard,), (self.axis,),
@@ -683,8 +683,10 @@ class ShardedExecutor(ExecutorBackend):
                           rules.spec("legion", "round", "k", "n")),
                 out_specs=rules.spec("legion", "round", "m", "n"),
             ))
-        stacked = np.asarray(self._fns[key](jnp.asarray(xs_stack),
-                                            jnp.asarray(ws_stack)))
+        result = self._fns[key](jnp.asarray(xs_stack), jnp.asarray(ws_stack))
+        # counted from where the shards landed, not from the mesh we asked for
+        self.devices_used = len({s.device for s in result.addressable_shards})
+        stacked = np.asarray(result)
 
         out = np.zeros((ctx.count, ctx.m, ctx.n), dtype=ctx.out_dtype)
         for (li, slot, a) in slots:

@@ -110,8 +110,13 @@ def bit_linear_serve(
     """
     if backend == "pallas":
         from repro.kernels.bitlinear import ops as bl_ops
+        # qw packs along N (what dequantize reads); the kernel streams
+        # K-major packing, so the same values are repacked for it
+        w = packing.unpack(qw.packed, qw.bits).reshape(qw.shape)
+        pack_k = (packing.pack_2bit_kmajor if qw.bits == 2
+                  else packing.pack_4bit_kmajor)
         xq, xs = quantize_act_int8(x)
-        out = bl_ops.bitlinear_matmul(xq, qw.packed, interpret=True)
+        out = bl_ops.tile_gemm(xq, pack_k(w), bits=qw.bits, backend="pallas")
         return out.astype(x.dtype) * (xs * qw.scale).astype(x.dtype)
     w = qw.dequantize().astype(x.dtype)
     xq, xs = quantize_act_int8(x)

@@ -183,8 +183,16 @@ def test_window_truncation_flagged():
 
 
 def test_prefill_chunk_bit_exact():
-    """Chunked prefill == whole-prompt prefill, bit for bit: final logits,
-    every cache leaf, and the decode continuation."""
+    """Chunked prefill == whole-prompt prefill: final logits, every cache
+    leaf, and the decode continuation.
+
+    Equal up to float32 summation order: XLA picks its matmul blocking per
+    shape, so a 5/4/3-token chunk and a 12-token prompt add the same
+    d_model=128 products in different orders.  That bounds each dot's
+    difference by about 128 * 2**-24 ~ 1e-5 relative (measured: ~2e-6 on
+    values of magnitude ~4); a wrong position, mask or cache offset moves
+    values by O(1e-1) or more."""
+    tol = dict(rtol=1e-5, atol=1e-5)
     cfg = reduced(get_config("qwen3-1.7b")).replace(dtype="float32",
                                                     quantization="none")
     api = build_model(cfg)
@@ -201,14 +209,14 @@ def test_prefill_chunk_bit_exact():
         lg, cache = api.prefill_chunk(
             params, jnp.asarray(prompt[:, pos0:pos0 + c]), cache, pos0)
         pos0 += c
-    assert jnp.array_equal(lg[:, -1], lg_whole[:, -1])
+    np.testing.assert_allclose(lg[:, -1], lg_whole[:, -1], **tol)
     for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(whole_cache)):
-        assert jnp.array_equal(a, b)
+        np.testing.assert_allclose(a, b, **tol)
     # decode continues identically from either cache
     tok = jnp.argmax(lg_whole[:, -1], axis=-1).astype(jnp.int32)
     lg_a, _ = api.decode(params, tok, whole_cache, jnp.int32(12))
     lg_b, _ = api.decode(params, tok, cache, jnp.int32(12))
-    assert jnp.array_equal(lg_a, lg_b)
+    np.testing.assert_allclose(lg_a, lg_b, **tol)
 
 
 def test_inflight_engine_matches_legacy_bit_exact():

@@ -91,6 +91,18 @@ def test_bit_linear_serve_matches_dequant(rng):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_bit_linear_serve_pallas_matches_reference(rng):
+    """The Pallas branch (interpret mode here, compiled on a TPU) repacks
+    the N-packed weights K-major and agrees with the XLA branch."""
+    x = jnp.array(rng.standard_normal((4, 32)), jnp.float32)
+    w = jnp.array(rng.standard_normal((32, 16)), jnp.float32)
+    qt = bitnet.pack_weight_ternary(w)
+    np.testing.assert_allclose(
+        np.asarray(bitnet.bit_linear_serve(x, qt, backend="pallas")),
+        np.asarray(bitnet.bit_linear_serve(x, qt, backend="reference")),
+        rtol=1e-5, atol=1e-5)
+
+
 def test_packed_dequantize_roundtrip(rng):
     w = jnp.array(rng.standard_normal((8, 16)), jnp.float32)
     qt = bitnet.pack_weight_ternary(w)
