@@ -50,6 +50,7 @@ from typing import (
 )
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.legion.program import Program, ProgramReport, ProgramStage
@@ -74,6 +75,19 @@ from repro.legion.modes import (
 )
 from repro.legion.trace import StageValidation, TrafficTotals, TrafficTracer
 from repro.quant.packing import pack_2bit_kmajor, pack_4bit_kmajor
+
+# Host spans on the profiler's clock (recorded only while a profiler trace
+# is on): a program's stages and pipeline, a stage's phases, and each tile
+# kernel call with its device-to-host copy.
+STAGE_SPAN = "legion.stage"
+PIPELINE_SPAN = "legion.pipeline"
+PLAN_SPAN = "legion.plan"
+PREPARE_SPAN = "legion.prepare"
+EXECUTE_SPAN = "legion.execute"
+CHECK_SPAN = "legion.check"
+VALIDATE_SPAN = "legion.validate"
+TILE_SPAN = "legion.tile"
+SYNC_SPAN = "legion.sync"
 
 GRANULARITIES = ("window", "kernel")
 # "auto" = the kernels' own dispatch (Pallas on TPU, reference elsewhere)
@@ -375,16 +389,20 @@ def _backend_call(ctx: ExecContext, xs: np.ndarray, inst: int, k_lo: int,
     """One tile GEMM: x rows [*, k_lo:k_hi] @ w[k_lo:k_hi, c_lo:c_hi]."""
     from repro.legion.runtime import _instance_view
 
-    if ctx.mode.backend == BITLINEAR:
-        factor = 8 // ctx.mode.weight_bits
-        wp = ctx.packed[inst][k_lo // factor:k_hi // factor, c_lo:c_hi]
-        from repro.kernels.bitlinear.ops import tile_gemm as bl_tile
-        return np.asarray(bl_tile(
-            xs[:, k_lo:k_hi].astype(np.int8), wp,
-            bits=ctx.mode.weight_bits, backend=ctx.kernel_backend,
-        ))
-    ws = _instance_view(ctx.w_pad, inst, 2)[k_lo:k_hi, c_lo:c_hi]
-    return np.asarray(dense_tile_gemm(xs[:, k_lo:k_hi], ws))
+    with TraceAnnotation(TILE_SPAN):
+        if ctx.mode.backend == BITLINEAR:
+            factor = 8 // ctx.mode.weight_bits
+            wp = ctx.packed[inst][k_lo // factor:k_hi // factor, c_lo:c_hi]
+            from repro.kernels.bitlinear.ops import tile_gemm as bl_tile
+            out = bl_tile(
+                xs[:, k_lo:k_hi].astype(np.int8), wp,
+                bits=ctx.mode.weight_bits, backend=ctx.kernel_backend,
+            )
+        else:
+            ws = _instance_view(ctx.w_pad, inst, 2)[k_lo:k_hi, c_lo:c_hi]
+            out = dense_tile_gemm(xs[:, k_lo:k_hi], ws)
+        with TraceAnnotation(SYNC_SPAN):
+            return np.asarray(out)
 
 
 def _kernel_call(ctx: ExecContext, xs: np.ndarray, inst: int, lo: int,
@@ -392,20 +410,25 @@ def _kernel_call(ctx: ExecContext, xs: np.ndarray, inst: int, lo: int,
     """Whole-slice kernel dispatch (Pallas path exercisable)."""
     from repro.legion.runtime import _instance_view
 
-    if ctx.mode.backend == BITLINEAR:
-        from repro.kernels.bitlinear.ops import tile_gemm as bl_tile
-        return np.asarray(bl_tile(
-            xs.astype(np.int8), ctx.packed[inst][:, lo:hi],
-            bits=ctx.mode.weight_bits, backend=ctx.kernel_backend,
-        ))
-    ws = _instance_view(ctx.w_pad, inst, 2)[:, lo:hi]
-    if ctx.mode.backend == BLOCK_SPARSE:
-        from repro.kernels.block_sparse.ops import tile_gemm as bs_tile
-        return np.asarray(bs_tile(
-            xs.astype(np.float32), ws.astype(np.float32),
-            backend=ctx.kernel_backend,
-        ))
-    return np.asarray(dense_tile_gemm(xs, ws))
+    with TraceAnnotation(TILE_SPAN):
+        if ctx.mode.backend == BITLINEAR:
+            from repro.kernels.bitlinear.ops import tile_gemm as bl_tile
+            out = bl_tile(
+                xs.astype(np.int8), ctx.packed[inst][:, lo:hi],
+                bits=ctx.mode.weight_bits, backend=ctx.kernel_backend,
+            )
+        else:
+            ws = _instance_view(ctx.w_pad, inst, 2)[:, lo:hi]
+            if ctx.mode.backend == BLOCK_SPARSE:
+                from repro.kernels.block_sparse.ops import tile_gemm as bs_tile
+                out = bs_tile(
+                    xs.astype(np.float32), ws.astype(np.float32),
+                    backend=ctx.kernel_backend,
+                )
+            else:
+                out = dense_tile_gemm(xs, ws)
+        with TraceAnnotation(SYNC_SPAN):
+            return np.asarray(out)
 
 
 def _window_partial(ctx: ExecContext, xs: np.ndarray, a: Assignment,
@@ -1004,11 +1027,12 @@ class Machine:
         for idx, stage in enumerate(program.topo_order()):
             _each(shared, "on_stage_begin", stage=stage.name, index=idx,
                   deps=stage.deps)
-            rep = self._run_stage(
-                stage, produced, seed=seed, check_outputs=check_outputs,
-                validate=validate, rtol=rtol, caller_instruments=caller,
-                bind_caller=len(program) == 1,
-            )
+            with TraceAnnotation(STAGE_SPAN, stage=stage.name):
+                rep = self._run_stage(
+                    stage, produced, seed=seed, check_outputs=check_outputs,
+                    validate=validate, rtol=rtol, caller_instruments=caller,
+                    bind_caller=len(program) == 1,
+                )
             produced[stage.name] = rep.outputs
             reports[stage.name] = rep
             _each(shared, "on_stage_end", stage=stage.name,
@@ -1027,7 +1051,8 @@ class Machine:
                 rc = rep.cycles.round_criticals()
                 rounds[name] = [b for key in sorted(rc) for b in rc[key]]
             if rounds is not None:
-                pipeline = compute_pipeline(program, rounds)
+                with TraceAnnotation(PIPELINE_SPAN):
+                    pipeline = compute_pipeline(program, rounds)
 
         if self.metrics is not None:
             self.metrics.counter("machine_programs").inc()
@@ -1069,31 +1094,35 @@ class Machine:
 
         workload = stage.workload
         ztb = stage.ztb
-        if workload is not None:
-            plan = plan_stage(self.cfg, workload, stage=stage.name)
-            if stage.x is None and stage.w is None:
-                x, w = synthesize_operands(
-                    workload, seed=seed, ztb_sparsity=stage.ztb_sparsity,
-                    k_window=(plan.assignments[0].k_window
-                              if plan.assignments else 0),
-                )
-                if ztb is None and stage.ztb_sparsity > 0.0:
-                    ztb = True
+        with TraceAnnotation(PLAN_SPAN):
+            if workload is not None:
+                plan = plan_stage(self.cfg, workload, stage=stage.name)
+                if stage.x is None and stage.w is None:
+                    x, w = synthesize_operands(
+                        workload, seed=seed, ztb_sparsity=stage.ztb_sparsity,
+                        k_window=(plan.assignments[0].k_window
+                                  if plan.assignments else 0),
+                    )
+                    if ztb is None and stage.ztb_sparsity > 0.0:
+                        ztb = True
+                else:
+                    x, w = stage.x, stage.w
             else:
+                plan = stage.plan
                 x, w = stage.x, stage.w
-        else:
-            plan = stage.plan
-            x, w = stage.x, stage.w
-        if isinstance(x, Ref):
-            x = x.resolve(produced)
-        if isinstance(w, Ref):
-            w = w.resolve(produced)
+            if isinstance(x, Ref):
+                x = x.resolve(produced)
+            if isinstance(w, Ref):
+                w = w.resolve(produced)
 
-        ctx = prepare_context(
-            self.cfg, plan, x, w, mode=stage.mode, ztb=ztb,
-            granularity=self.granularity, kernel_backend=self.kernel_backend,
-            emulate_cores=self.emulate_cores, accumulators=self.accumulators,
-        )
+        with TraceAnnotation(PREPARE_SPAN):
+            ctx = prepare_context(
+                self.cfg, plan, x, w, mode=stage.mode, ztb=ztb,
+                granularity=self.granularity,
+                kernel_backend=self.kernel_backend,
+                emulate_cores=self.emulate_cores,
+                accumulators=self.accumulators,
+            )
         instruments = caller_instruments
         # Per-run instruments (fresh pair, or the caller's) come first; the
         # report's trace/cycles bind to them, never to session-lifetime
@@ -1109,7 +1138,8 @@ class Machine:
         emit = per_run + self.instruments
 
         _each(emit, "on_plan_begin", plan, ctx.mode, ctx)
-        outputs = self.backend.execute(ctx, emit)
+        with TraceAnnotation(EXECUTE_SPAN):
+            outputs = self.backend.execute(ctx, emit)
         _each(emit, "on_plan_end", outputs)
 
         tracer = counter = None
@@ -1125,25 +1155,27 @@ class Machine:
         # zeros) keeps the check meaningful.
         caller_books = ztb not in (None, False, True)
         if check_outputs and not caller_books:
-            x_arr, w_arr = np.asarray(x), np.asarray(w)
-            for inst in range(ctx.count):
-                if ctx.int_path:
-                    xi = _instance_view(x_arr, inst, 2).astype(np.int64)
-                    wi = _instance_view(w_arr, inst, 2).astype(np.int64)
-                    got = outputs[inst].astype(np.int64)
-                    mismatch = got != xi @ wi
-                else:
-                    xi = _instance_view(x_arr, inst, 2).astype(np.float64)
-                    wi = _instance_view(w_arr, inst, 2).astype(np.float64)
-                    got = outputs[inst]
-                    mismatch = ~np.isclose(got, xi @ wi, rtol=1e-5,
-                                           atol=1e-5)
-                if mismatch.any():
-                    raise AssertionError(
-                        f"{plan.stage} instance {inst}: runtime output != "
-                        f"x @ w reference at {int(mismatch.sum())} positions "
-                        f"(mode {ctx.mode.name}, backend {self.backend.name})"
-                    )
+            with TraceAnnotation(CHECK_SPAN):
+                x_arr, w_arr = np.asarray(x), np.asarray(w)
+                for inst in range(ctx.count):
+                    if ctx.int_path:
+                        xi = _instance_view(x_arr, inst, 2).astype(np.int64)
+                        wi = _instance_view(w_arr, inst, 2).astype(np.int64)
+                        got = outputs[inst].astype(np.int64)
+                        mismatch = got != xi @ wi
+                    else:
+                        xi = _instance_view(x_arr, inst, 2).astype(np.float64)
+                        wi = _instance_view(w_arr, inst, 2).astype(np.float64)
+                        got = outputs[inst]
+                        mismatch = ~np.isclose(got, xi @ wi, rtol=1e-5,
+                                               atol=1e-5)
+                    if mismatch.any():
+                        raise AssertionError(
+                            f"{plan.stage} instance {inst}: runtime output "
+                            f"!= x @ w reference at {int(mismatch.sum())} "
+                            f"positions (mode {ctx.mode.name}, backend "
+                            f"{self.backend.name})"
+                        )
 
         report = RunReport(
             outputs=outputs, plan=plan, mode=ctx.mode,
@@ -1192,16 +1224,17 @@ class Machine:
                     )
             if measurable and models_run and \
                     (validate or instruments is None):
-                sim = simulate_workload(
-                    self.cfg, workload, ztb=report.ztb_stats,
-                    mem_bw_bytes_per_cycle=self.mem_bw)
-                scale = workload.layers
-                br = counter.stage_breakdown().get(
-                    plan.stage, CycleBreakdown()).scaled(scale)
-                report.traffic_validation, report.cycle_validation = \
-                    _build_validations(plan.stage,
-                                       tracer.totals.scaled(scale), br, sim,
-                                       rtol)
+                with TraceAnnotation(VALIDATE_SPAN):
+                    sim = simulate_workload(
+                        self.cfg, workload, ztb=report.ztb_stats,
+                        mem_bw_bytes_per_cycle=self.mem_bw)
+                    scale = workload.layers
+                    br = counter.stage_breakdown().get(
+                        plan.stage, CycleBreakdown()).scaled(scale)
+                    report.traffic_validation, report.cycle_validation = \
+                        _build_validations(plan.stage,
+                                           tracer.totals.scaled(scale), br,
+                                           sim, rtol)
         return report
 
     # ------------------------------------------------------------------ #

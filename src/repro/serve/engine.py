@@ -16,6 +16,20 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+# Host spans on the profiler's clock (recorded only while a profiler trace
+# is on): one per engine phase, opened by the helper that runs the phase so
+# the legacy and in-flight steps share them.
+ADMIT_SPAN = "serve.admit"
+CHUNKS_SPAN = "serve.chunks"
+PREFILL_CHUNK_SPAN = "serve.prefill_chunk"
+ENSURE_KV_SPAN = "serve.ensure_kv"
+DECODE_SPAN = "serve.decode"
+OBSERVERS_SPAN = "serve.observers"
+SAMPLE_SPAN = "serve.sample"
+SYNC_SPAN = "serve.sync"
+FINISH_SPAN = "serve.finish"
 
 
 @dataclasses.dataclass
@@ -171,18 +185,20 @@ class ServeEngine:
         # Duck-typed metrics registry (see repro.obs.metrics
         # .MetricsRegistry); None disables serve_* metric emission.
         self.metrics = metrics
-        self._decode = jax.jit(
-            lambda params, tok, cache, pos: api.decode(params, tok, cache,
-                                                       pos)
-        )
+        # named functions, so the compiled modules are jit_serve_decode and
+        # jit_serve_prefill_chunk in a profiler trace
+        def serve_decode(params, tok, cache, pos):
+            return api.decode(params, tok, cache, pos)
+
+        def serve_prefill_chunk(params, toks, cache, pos0):
+            return api.prefill_chunk(params, toks, cache, pos0)
+
+        self._decode = jax.jit(serve_decode)
         self._prefill_chunk = None
         if prefill_chunk_tokens is not None:
             # jit caches by chunk shape; the fixed budget bounds the set of
             # chunk lengths (budget + the per-prompt remainders)
-            self._prefill_chunk = jax.jit(
-                lambda params, toks, cache, pos0: api.prefill_chunk(
-                    params, toks, cache, pos0)
-            )
+            self._prefill_chunk = jax.jit(serve_prefill_chunk)
 
     # ------------------------------------------------------------------ #
     def submit(self, prompt: np.ndarray, *, max_new_tokens: int = 16,
@@ -348,28 +364,29 @@ class ServeEngine:
         step's cache write (``pos + 1`` tokens), evicting lower-priority
         slots under pool pressure.  Returns ``active`` minus any slots
         preempted along the way."""
-        if self.paged_kv is None:
-            return active
-        for i in active:
-            slot = self.slots[i]
-            if slot.request is None:     # preempted by an earlier slot
-                continue
-            uid = slot.request.uid
-            while not self.paged_kv.extend(uid, slot.pos + 1):
-                victim = self._preempt_victim(uid)
-                if victim is None:
-                    raise RuntimeError(
-                        f"request {uid} cannot grow its KV pages with "
-                        f"nothing left to evict (pool too small?)"
-                    )
-                self._preempt(victim)
-        if self.metrics is not None:
-            st = self.paged_kv.stats()
-            self.metrics.gauge("serve_page_pinned").set(st.pinned_pages)
-            self.metrics.gauge("serve_page_free").set(st.free_pages)
-            self.metrics.gauge("serve_page_waste_tokens").set(
-                st.waste_tokens)
-        return [i for i in active if self.slots[i].request is not None]
+        with TraceAnnotation(ENSURE_KV_SPAN):
+            if self.paged_kv is None:
+                return active
+            for i in active:
+                slot = self.slots[i]
+                if slot.request is None:     # preempted by an earlier slot
+                    continue
+                uid = slot.request.uid
+                while not self.paged_kv.extend(uid, slot.pos + 1):
+                    victim = self._preempt_victim(uid)
+                    if victim is None:
+                        raise RuntimeError(
+                            f"request {uid} cannot grow its KV pages with "
+                            f"nothing left to evict (pool too small?)"
+                        )
+                    self._preempt(victim)
+            if self.metrics is not None:
+                st = self.paged_kv.stats()
+                self.metrics.gauge("serve_page_pinned").set(st.pinned_pages)
+                self.metrics.gauge("serve_page_free").set(st.free_pages)
+                self.metrics.gauge("serve_page_waste_tokens").set(
+                    st.waste_tokens)
+            return [i for i in active if self.slots[i].request is not None]
 
     def _admit(self):
         """Fill free slots from the queue; prefill each admitted request.
@@ -381,51 +398,52 @@ class ServeEngine:
         first (a shortfall leaves the request queued) and re-prefill
         ``prompt + output[:-1]`` for requests resuming after preemption.
         """
-        for i, slot in enumerate(self.slots):
-            if slot.request is not None:
-                continue
-            while True:
-                req = self._next_admittable()
-                if req is None:
-                    return
-                tokens = self._resume_tokens(req)
-                plen = len(tokens)
-                if not self._page_admit(req, plen):
-                    return
-                resume = bool(req.output)
-                # single-request prefill into this slot's cache lane
-                single_cache = self.api.init_cache(1, self.max_seq)
-                logits, single_cache = self.api.prefill(
-                    self.params,
-                    {"tokens": jnp.asarray(tokens[None, :])},
-                    single_cache,
-                )
-                finished = False
-                if resume:
-                    # the re-prefill's sampled token is the one already at
-                    # output[-1] (same cache prefix) — drop it, resume the
-                    # decode loop where the eviction cut it off
-                    pass
-                else:
-                    tok = self._sample(logits[:, -1])
-                    finished = self._first_token(req, int(tok[0]), plen)
-                if not finished:
-                    if self.paged_kv is not None:
-                        self.cache = self.paged_kv.write_slot(
-                            self.cache, single_cache, i, uid=req.uid,
-                            tokens=plen)
+        with TraceAnnotation(ADMIT_SPAN):
+            for i, slot in enumerate(self.slots):
+                if slot.request is not None:
+                    continue
+                while True:
+                    req = self._next_admittable()
+                    if req is None:
+                        return
+                    tokens = self._resume_tokens(req)
+                    plen = len(tokens)
+                    if not self._page_admit(req, plen):
+                        return
+                    resume = bool(req.output)
+                    # single-request prefill into this slot's cache lane
+                    single_cache = self.api.init_cache(1, self.max_seq)
+                    logits, single_cache = self.api.prefill(
+                        self.params,
+                        {"tokens": jnp.asarray(tokens[None, :])},
+                        single_cache,
+                    )
+                    finished = False
+                    if resume:
+                        # the re-prefill's sampled token is the one already
+                        # at output[-1] (same cache prefix) — drop it, resume
+                        # the decode loop where the eviction cut it off
+                        pass
                     else:
-                        self.cache = _write_slot(self.cache, single_cache,
-                                                 i)
-                    slot.request = req
-                    slot.pos = plen
-                else:
-                    self._page_release(req)
-                self._log_prefill(req, plen)
-                self._notify({"kind": "prefill", "uid": req.uid,
-                              "tokens": plen, "done": finished})
-                if not finished:
-                    break          # slot taken; move to the next free one
+                        tok = self._sample(logits[:, -1])
+                        finished = self._first_token(req, int(tok[0]), plen)
+                    if not finished:
+                        if self.paged_kv is not None:
+                            self.cache = self.paged_kv.write_slot(
+                                self.cache, single_cache, i, uid=req.uid,
+                                tokens=plen)
+                        else:
+                            self.cache = _write_slot(self.cache, single_cache,
+                                                     i)
+                        slot.request = req
+                        slot.pos = plen
+                    else:
+                        self._page_release(req)
+                    self._log_prefill(req, plen)
+                    self._notify({"kind": "prefill", "uid": req.uid,
+                                  "tokens": plen, "done": finished})
+                    if not finished:
+                        break          # slot taken; move to the next free one
 
     def _sample(self, logits: jnp.ndarray) -> jnp.ndarray:
         if self.greedy:
@@ -439,8 +457,16 @@ class ServeEngine:
         return [i for i, s in enumerate(self.slots) if s.request is not None]
 
     def _notify(self, event: dict) -> None:
-        for fn in self.step_observers:
-            fn(event)
+        with TraceAnnotation(OBSERVERS_SPAN):
+            for fn in self.step_observers:
+                fn(event)
+
+    def _sample_decoded(self, logits: jnp.ndarray) -> np.ndarray:
+        """The decode step's next token per slot, on the host."""
+        with TraceAnnotation(SAMPLE_SPAN):
+            tok = self._sample(logits[:, -1])
+            with TraceAnnotation(SYNC_SPAN):
+                return np.asarray(tok)
 
     # ------------------------------------------------------------------ #
     def step(self):
@@ -459,15 +485,16 @@ class ServeEngine:
     def _decode_step(self, active: List[int]):
         """Run the batched decode over ``active`` slot indices; returns
         the step logits (sampling happens after observers fire)."""
-        tokens = np.zeros((self.max_slots,), np.int32)
-        pos = np.zeros((self.max_slots,), np.int32)
-        for i in active:
-            slot = self.slots[i]
-            tokens[i] = slot.request.output[-1]
-            pos[i] = slot.pos
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(tokens), self.cache, jnp.asarray(pos)
-        )
+        with TraceAnnotation(DECODE_SPAN):
+            tokens = np.zeros((self.max_slots,), np.int32)
+            pos = np.zeros((self.max_slots,), np.int32)
+            for i in active:
+                slot = self.slots[i]
+                tokens[i] = slot.request.output[-1]
+                pos[i] = slot.pos
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(tokens), self.cache,
+                jnp.asarray(pos))
         self.decode_batch_sizes.append(len(active))
         self.step_log.append({"phase": "decode", "tokens": len(active),
                               "slots": len(self._active())})
@@ -483,21 +510,22 @@ class ServeEngine:
         """Append sampled tokens and retire finished slots — EOS and
         token-budget completions, plus window truncations
         (``Request.truncated``) when ``slot.pos`` hits the cache edge."""
-        for i in active:
-            slot = self.slots[i]
-            req = slot.request
-            req.output.append(int(next_tok[i]))
-            slot.pos += 1
-            hit_eos = req.eos_id is not None and next_tok[i] == req.eos_id
-            full = slot.pos >= self.max_seq - 1
-            if len(req.output) >= req.max_new_tokens or hit_eos or full:
-                req.done = True
-                req.truncated = (full and not hit_eos
-                                 and len(req.output) < req.max_new_tokens)
-                self.finished.append(req)
-                self._page_release(req)
-                slot.request = None
-                slot.pos = 0
+        with TraceAnnotation(FINISH_SPAN):
+            for i in active:
+                slot = self.slots[i]
+                req = slot.request
+                req.output.append(int(next_tok[i]))
+                slot.pos += 1
+                hit_eos = req.eos_id is not None and next_tok[i] == req.eos_id
+                full = slot.pos >= self.max_seq - 1
+                if len(req.output) >= req.max_new_tokens or hit_eos or full:
+                    req.done = True
+                    req.truncated = (full and not hit_eos
+                                     and len(req.output) < req.max_new_tokens)
+                    self.finished.append(req)
+                    self._page_release(req)
+                    slot.request = None
+                    slot.pos = 0
 
     def _step_legacy(self):
         """One batched decode step across all active slots."""
@@ -509,7 +537,7 @@ class ServeEngine:
         self._notify({"kind": "decode", "tokens": 1,
                       "uids": [self.slots[i].request.uid for i in active],
                       "positions": [int(self.slots[i].pos) for i in active]})
-        next_tok = np.asarray(self._sample(logits[:, -1]))
+        next_tok = self._sample_decoded(logits)
         self._finish_decoded(active, next_tok)
         return True
 
@@ -520,32 +548,44 @@ class ServeEngine:
         """Assign free slots to queued requests (admission-gated) without
         running any prefill — chunks advance inside the merged step.
         Paged engines pin the whole (re-)prefill's pages up front."""
-        for slot in self.slots:
-            if slot.request is not None:
-                continue
-            req = self._next_admittable()
-            if req is None:
-                return
-            tokens = self._resume_tokens(req)
-            if not self._page_admit(req, len(tokens)):
-                return
-            slot.request = req
-            slot.pos = 0
-            slot.filled = 0
-            slot.tokens = tokens
-            slot.staging = self.api.init_cache(1, self.max_seq)
+        with TraceAnnotation(ADMIT_SPAN):
+            for slot in self.slots:
+                if slot.request is not None:
+                    continue
+                req = self._next_admittable()
+                if req is None:
+                    return
+                tokens = self._resume_tokens(req)
+                if not self._page_admit(req, len(tokens)):
+                    return
+                slot.request = req
+                slot.pos = 0
+                slot.filled = 0
+                slot.tokens = tokens
+                slot.staging = self.api.init_cache(1, self.max_seq)
 
     def _advance_chunks(self) -> List[dict]:
         """Advance every prefilling slot by one chunk, oldest slot first,
         until the step's ``prefill_chunk_tokens`` budget is spent."""
         budget = self.prefill_chunk_tokens
         chunks: List[dict] = []
-        for i, slot in enumerate(self.slots):
-            if budget <= 0:
-                break
-            req = slot.request
-            if req is None or slot.staging is None:
-                continue
+        with TraceAnnotation(CHUNKS_SPAN):
+            for i, slot in enumerate(self.slots):
+                if budget <= 0:
+                    break
+                if slot.request is None or slot.staging is None:
+                    continue
+                chunk = self._advance_chunk(i, slot, budget)
+                budget -= chunk["tokens"]
+                chunks.append(chunk)
+        return chunks
+
+    def _advance_chunk(self, i: int, slot: _Slot, budget: int) -> dict:
+        """Prefill slot ``i``'s next chunk of at most ``budget`` tokens; the
+        prompt's last chunk samples its first token and lands the staged
+        lane in the batch cache.  Returns the chunk's step-event entry."""
+        req = slot.request
+        with TraceAnnotation(PREFILL_CHUNK_SPAN, uid=req.uid):
             fill = slot.tokens if slot.tokens is not None else req.prompt
             plen = len(fill)
             c = min(budget, plen - slot.filled)
@@ -554,7 +594,6 @@ class ServeEngine:
             logits, slot.staging = self._prefill_chunk(
                 self.params, toks, slot.staging, pos0)
             slot.filled += c
-            budget -= c
             self.step_log.append({"phase": "prefill_chunk", "uid": req.uid,
                                   "tokens": c,
                                   "slots": len(self._active())})
@@ -589,9 +628,8 @@ class ServeEngine:
                 slot.filled = 0
                 slot.tokens = None
                 self._log_prefill(req, plen, count_tokens=False)
-            chunks.append({"uid": req.uid, "tokens": c, "pos0": pos0,
-                           "last": last, "done": done})
-        return chunks
+            return {"uid": req.uid, "tokens": c, "pos0": pos0,
+                    "last": last, "done": done}
 
     def _step_inflight(self):
         """One in-flight step: admit, advance prefill chunks under the
@@ -610,7 +648,7 @@ class ServeEngine:
             "positions": [int(self.slots[i].pos) for i in active],
         })
         if active:
-            next_tok = np.asarray(self._sample(logits[:, -1]))
+            next_tok = self._sample_decoded(logits)
             self._finish_decoded(active, next_tok)
         return True
 
