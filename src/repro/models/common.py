@@ -8,7 +8,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
-from repro.quant.bitnet import fake_quant_act, fake_quant_weight
+from repro.quant.bitnet import (
+    TernaryWeight,
+    fake_quant_act,
+    fake_quant_weight,
+    ternary_dot,
+)
 
 
 def dtype_of(cfg) -> jnp.dtype:
@@ -21,13 +26,17 @@ def dense_init(key, d_in: int, d_out: int, dtype) -> jnp.ndarray:
 
 
 def dense(
-    x: jnp.ndarray, w: jnp.ndarray, *, quantize: bool = False,
+    x: jnp.ndarray, w, *, quantize: bool = False,
 ) -> jnp.ndarray:
-    """Linear layer; ``quantize`` applies BitNet QAT fake-quant (STE).
+    """Linear layer.  A :class:`TernaryWeight` (served weights, quantized
+    once by ``serve.engine.prepare_params``) runs the integer BitLinear;
+    on a plain array ``quantize`` applies BitNet QAT fake-quant (STE).
 
     BitLinear = absmax-int8 activations x absmean-ternary weights.  The
     caller normalizes ``x`` first (BitNet wraps RMSNorm around quant).
     """
+    if isinstance(w, TernaryWeight):
+        return ternary_dot(x, w)
     if quantize:
         x = fake_quant_act(x)
         w = fake_quant_weight(w)

@@ -13,7 +13,12 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
 from repro.models.common import dense_init
-from repro.quant.bitnet import fake_quant_act, fake_quant_weight
+from repro.quant.bitnet import (
+    TernaryWeight,
+    fake_quant_act,
+    fake_quant_weight,
+    ternary_dot,
+)
 
 
 def init_moe_params(key, cfg, dtype) -> dict:
@@ -33,8 +38,14 @@ def init_moe_params(key, cfg, dtype) -> dict:
     }
 
 
-def _quant_w(w, quantize):
-    return fake_quant_weight(w) if quantize else w
+def _expert_dot(spec, x, w, quantize):
+    """Per-expert einsum: the integer BitLinear on a served
+    :class:`TernaryWeight`, else fake-quant (STE) when ``quantize``."""
+    if isinstance(w, TernaryWeight):
+        return ternary_dot(x, w, spec)
+    if quantize:
+        x, w = fake_quant_act(x), fake_quant_weight(w)
+    return jnp.einsum(spec, x, w)
 
 
 def moe_block(p, cfg, x: jnp.ndarray) -> jnp.ndarray:
@@ -84,15 +95,11 @@ def moe_block(p, cfg, x: jnp.ndarray) -> jnp.ndarray:
     # constrain boundary is where XLA inserts the dispatch all-to-all
     buf = constrain(buf, "batch", "experts", None, None)
 
-    if quantize:
-        buf = fake_quant_act(buf)
     h = jax.nn.silu(
-        jnp.einsum("becd,edf->becf", buf, _quant_w(p["w1"], quantize))
-    ) * jnp.einsum("becd,edf->becf", buf, _quant_w(p["w3"], quantize))
+        _expert_dot("becd,edf->becf", buf, p["w1"], quantize)
+    ) * _expert_dot("becd,edf->becf", buf, p["w3"], quantize)
     h = constrain(h, "batch", "experts", None, "ff")
-    if quantize:
-        h = fake_quant_act(h)
-    out_buf = jnp.einsum("becf,efd->becd", h, _quant_w(p["w2"], quantize))
+    out_buf = _expert_dot("becf,efd->becd", h, p["w2"], quantize)
     out_buf = constrain(out_buf, "batch", "experts", None, None)
 
     # combine: gather each (token, slot)'s result, weight by its gate

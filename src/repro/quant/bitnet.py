@@ -2,13 +2,17 @@
 
 Training uses fake-quant with straight-through estimators (QAT, the BitNet
 recipe [5]): ternary absmean weights + per-token absmax int8 activations.
-Serving materializes the real packed-ternary weights consumed by the
+Serving quantizes each projection once into a :class:`TernaryWeight` (int8
+codes and one scale per stacked layer) that :func:`ternary_dot` consumes;
+:class:`QuantizedTensor` holds the packed-ternary weights of the
 ``kernels/bitlinear`` Pallas kernel.
 
 All functions are pure and differentiable where it matters.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -56,6 +60,32 @@ def pack_weight_ternary(w: jnp.ndarray) -> QuantizedTensor:
     )
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class TernaryWeight:
+    """Served BitLinear weight, quantized once from a latent weight.
+
+    ``q``: int8 codes in {-1, 0, 1} with the latent shape.  ``gamma``:
+    float32 absmean scales, one per slice of the leading ``gamma.ndim``
+    axes of ``q`` (``[L]`` for a ``[L, ...]`` stacked leaf, a scalar once
+    ``lax.scan`` has taken a layer).  Dequant = ``q * gamma``.
+    """
+
+    q: jnp.ndarray
+    gamma: jnp.ndarray
+
+
+@functools.partial(jax.jit, static_argnames="stacked")
+def ternary_weight(w: jnp.ndarray, *, stacked: bool = True) -> TernaryWeight:
+    """:func:`quantize_weight_ternary` on each layer slice of ``w`` (the
+    leading axis when ``stacked``, else the whole leaf): the codes and
+    gamma the fake-quant step computes from the same slice, gamma widened
+    to float32 exactly."""
+    q, gamma = (jax.lax.map(quantize_weight_ternary, w) if stacked
+                else quantize_weight_ternary(w))
+    return TernaryWeight(q, gamma.astype(jnp.float32))
+
+
 def fake_quant_weight(w: jnp.ndarray) -> jnp.ndarray:
     """Ternary fake-quant with STE: forward = dequant(quant(w)), grad = 1."""
     q, gamma = quantize_weight_ternary(w)
@@ -88,6 +118,7 @@ def fake_quant_act(x: jnp.ndarray) -> jnp.ndarray:
 
 # --------------------------------------------------------------------------- #
 # BitLinear: y = act_fq(x) @ weight_fq(W)    (QAT path)
+#            y = int8(x) @ codes * xs*gamma  (served TernaryWeight)
 #            y = int8(x) @ unpack(W_packed) * scales (serving path)
 # --------------------------------------------------------------------------- #
 
@@ -98,6 +129,20 @@ def bit_linear_train(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     done by the caller in ``models.layers``).
     """
     return fake_quant_act(x) @ fake_quant_weight(w)
+
+
+def ternary_dot(
+    x: jnp.ndarray, w: TernaryWeight, spec: str = "...k,kn->...n",
+) -> jnp.ndarray:
+    """BitLinear on a served weight: per-token absmax int8 activations times
+    the ternary codes, contracted by the einsum ``spec`` and summed exactly
+    in int32 (|sum| <= 128 * K < 2**31), scaled by ``xs * gamma`` in float32
+    and cast to ``x.dtype``.  ``w.gamma`` must be a scalar (one layer's
+    weight)."""
+    xq, xs = quantize_act_int8(x)
+    acc = jnp.einsum(spec, xq, w.q, preferred_element_type=jnp.int32)
+    scale = xs.astype(jnp.float32) * w.gamma
+    return (acc.astype(jnp.float32) * scale).astype(x.dtype)
 
 
 def bit_linear_serve(

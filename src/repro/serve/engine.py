@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.quant.bitnet import TernaryWeight, ternary_weight
+
 # Host spans on the profiler's clock (recorded only while a profiler trace
 # is on): one per engine phase, opened by the helper that runs the phase so
 # the legacy and in-flight steps share them.
@@ -65,25 +67,32 @@ class _Slot:
     tokens: Optional[np.ndarray] = None
 
 
-def prepare_params(params, *, ternary: bool = True):
-    """Offline weight transform for serving: apply the BitNet ternary
-    quantization ONCE (quantize -> dequantize), so the serve graph runs
-    plain matmuls over already-quantized values — no per-step quant math
-    (the packed-int8 variant goes further via kernels/bitlinear)."""
-    if not ternary:
-        return params
-    from repro.quant.bitnet import quantize_weight_ternary
+def _is_ternary(x) -> bool:
+    return isinstance(x, TernaryWeight)
 
+
+def _is_projection(name: str) -> bool:
+    return name.startswith("in_proj") or name in (
+        "wq", "wk", "wv", "wo", "w1", "w2", "w3", "out_proj")
+
+
+def prepare_params(params):
+    """Offline weight transform for serving: each BitNet projection becomes
+    a :class:`~repro.quant.bitnet.TernaryWeight` (int8 codes, one absmean
+    gamma per stacked layer), quantized ONCE here so the serve step runs the
+    integer BitLinear and never re-quantizes a weight.  Leaves already in
+    that form pass through, so the transform is idempotent (the packed
+    2-bit variant goes further via kernels/bitlinear)."""
     def q(path, leaf):
-        name = str(getattr(path[-1], "key", ""))
-        if leaf.ndim >= 2 and (name.startswith("in_proj") or name in (
-            "wq", "wk", "wv", "wo", "w1", "w2", "w3", "out_proj",
-        )):
-            qv, gamma = quantize_weight_ternary(leaf)
-            return (qv.astype(leaf.dtype) * gamma.astype(leaf.dtype))
+        keys = [str(getattr(p, "key", "")) for p in path]
+        if (not _is_ternary(leaf) and leaf.ndim >= 2
+                and _is_projection(keys[-1])):
+            # layer params are stacked [L, ...] under "blocks"; a shared
+            # block (the hybrid's) is one weight applied whole
+            return ternary_weight(leaf, stacked="blocks" in keys)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(q, params)
+    return jax.tree_util.tree_map_with_path(q, params, is_leaf=_is_ternary)
 
 
 class ServeEngine:
@@ -110,7 +119,15 @@ class ServeEngine:
                 )
         self.api = api
         self.cfg = api.cfg
+        if self.cfg.quantization == "bitnet":
+            # quantize the projections once, here; the engine keeps only
+            # the prepared tree, so the latent weights are not held
+            params = prepare_params(params)
         self.params = params
+        # projection leaves served as int8 ternary codes (7 for a BitNet
+        # transformer, 0 for a model that is not quantized)
+        self.ternary_projection_leaves = sum(
+            map(_is_ternary, jax.tree.leaves(params, is_leaf=_is_ternary)))
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.greedy = greedy
@@ -185,6 +202,9 @@ class ServeEngine:
         # Duck-typed metrics registry (see repro.obs.metrics
         # .MetricsRegistry); None disables serve_* metric emission.
         self.metrics = metrics
+        if metrics is not None:
+            metrics.gauge("serve_ternary_projection_leaves").set(
+                self.ternary_projection_leaves)
         # named functions, so the compiled modules are jit_serve_decode and
         # jit_serve_prefill_chunk in a profiler trace
         def serve_decode(params, tok, cache, pos):

@@ -18,7 +18,7 @@ workloads.  Pass ``executor=`` (any
 or ``PipelinedExecutor``) to choose how the step programs run.
 
 One representative layer executes numerically (the weights are the engine's
-actual ternary-quantized matrices, re-extracted to int8); tallies scale by
+actual ternary codes, taken as int8); tallies scale by
 the model's layer count — the same one-layer-times-L convention as
 ``repro.legion.trace.cross_validate``.  The streamed input and the KV cache
 are synthetic int8 (the engine's real activations live inside the jitted
@@ -67,6 +67,7 @@ from repro.legion.program import (
     softmax_int8,
 )
 from repro.legion.trace import StageValidation, TrafficTotals
+from repro.quant.bitnet import TernaryWeight, ternary_weight
 
 # Serve-side stage names beyond the paper's four attention stages (the
 # SwiGLU MLP projections are GEMMs too, and at decode they dominate bytes)
@@ -174,13 +175,13 @@ class RequestTally:
         self.cycles += t.cycles
 
 
-def _ternary_int8(w) -> np.ndarray:
-    """Engine weights -> int8 ternary.  ``prepare_params`` serves values in
-    {-gamma, 0, +gamma}; re-quantizing recovers the exact {-1, 0, 1} grid."""
-    from repro.quant.bitnet import quantize_weight_ternary
-
-    q, _gamma = quantize_weight_ternary(np.asarray(w, np.float32))
-    return np.asarray(q, np.int8)
+def _ternary_int8(w, layer: int) -> np.ndarray:
+    """Layer ``layer`` of a stacked engine weight as int8 ternary codes:
+    the served :class:`~repro.quant.bitnet.TernaryWeight`'s own (a latent
+    weight is quantized first, as the engine would)."""
+    if not isinstance(w, TernaryWeight):
+        w = ternary_weight(w)
+    return np.asarray(w.q[layer], np.int8)
 
 
 def extract_projection_ops(
@@ -211,9 +212,9 @@ def extract_projection_ops(
     heads, kv_heads = model_cfg.n_heads, model_cfg.kv_heads
     d_model, d_ff, layers = model_cfg.d_model, model_cfg.d_ff, model_cfg.layers
 
-    attn = {k: _ternary_int8(blocks["attn"][k][layer])
+    attn = {k: _ternary_int8(blocks["attn"][k], layer)
             for k in ("wq", "wk", "wv", "wo")}
-    mlp = {k: _ternary_int8(blocks["mlp"][k][layer])
+    mlp = {k: _ternary_int8(blocks["mlp"][k], layer)
            for k in ("w1", "w2", "w3")}
 
     def split_heads(w: np.ndarray, n: int) -> List[np.ndarray]:

@@ -102,15 +102,158 @@ def test_serve_engine_matches_manual_decode():
 
 
 def test_prepare_params_quantizes_matrices():
+    """Each projection becomes int8 codes in {-1, 0, 1} with one f32 gamma
+    per stacked layer; norms and embeddings stay as they are, and a second
+    pass changes nothing."""
+    from repro.quant.bitnet import TernaryWeight
+
     cfg = reduced(get_config("smollm-360m"))
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
     qp = prepare_params(params)
-    w = np.asarray(qp["blocks"]["attn"]["wq"], np.float32)
-    vals = np.unique(np.round(w / (np.abs(w)[w != 0].min() + 1e-12)))
-    # ternary x scale: at most 3 distinct magnitudes per layer slice
-    per_layer = np.asarray(qp["blocks"]["attn"]["wq"][0], np.float32)
-    assert len(np.unique(per_layer)) <= 3
+    for group, names in (("attn", ("wq", "wk", "wv", "wo")),
+                         ("mlp", ("w1", "w2", "w3"))):
+        for name in names:
+            tw = qp["blocks"][group][name]
+            latent = params["blocks"][group][name]
+            assert isinstance(tw, TernaryWeight), name
+            assert tw.q.dtype == jnp.int8 and tw.q.shape == latent.shape
+            assert set(np.unique(np.asarray(tw.q))) <= {-1, 0, 1}
+            assert tw.gamma.dtype == jnp.float32
+            assert tw.gamma.shape == (cfg.layers,)
+    assert qp["blocks"]["ln1"] is params["blocks"]["ln1"]
+    assert qp["embed"]["tokens"] is params["embed"]["tokens"]
+    again = prepare_params(qp)
+    assert again["blocks"]["attn"]["wq"] is qp["blocks"]["attn"]["wq"]
+
+
+class _LogitRecorder(ServeEngine):
+    """Keeps the logits of slot 0 at every sample."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.sampled = []
+
+    def _sample(self, logits):
+        self.sampled.append(np.asarray(logits[0], np.float32))
+        return super()._sample(logits)
+
+
+_PROMPT = np.array([3, 1, 4, 1, 5, 9, 2, 6], np.int32)
+
+
+def _serve_one(api, params, new_tokens=8):
+    eng = _LogitRecorder(api, params, max_slots=1, max_seq=64)
+    req = eng.submit(_PROMPT, max_new_tokens=new_tokens)
+    eng.run_until_done()
+    return req.output, np.stack(eng.sampled)
+
+
+def _bitnet_api(dtype="bfloat16"):
+    cfg = reduced(get_config("bitnet-1.58b-kv")).replace(dtype=dtype)
+    api = build_model(cfg)
+    return api, api.init(jax.random.PRNGKey(0))
+
+
+def test_serving_latent_equals_prepared():
+    """Latent weights and ``prepare_params`` of them serve the same model:
+    identical tokens and logits (prepared weights were once re-quantized in
+    the step and served 0.69 times too small)."""
+    api, params = _bitnet_api()
+    toks_latent, lg_latent = _serve_one(api, params)
+    toks_prep, lg_prep = _serve_one(api, prepare_params(params))
+    assert toks_latent == toks_prep
+    np.testing.assert_array_equal(lg_latent, lg_prep)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 8 * 2.0 ** -8)])
+def test_served_codes_match_per_step_fake_quant(dtype, tol):
+    """The engine's integer BitLinear over codes made once matches the old
+    step, which fake-quantizes the latent weights every call (teacher-
+    forced with the engine's tokens): within f32 summation order, or
+    within bf16 rounding (unit roundoff 2**-8) carried through two layers
+    and the head, relative to the largest logit."""
+    api, params = _bitnet_api(dtype)
+    toks, lg_engine = _serve_one(api, params)
+    cache = api.init_cache(1, 64)
+    lg, cache = api.prefill(params, {"tokens": jnp.asarray(_PROMPT[None])},
+                            cache)
+    rows = [lg[0, -1]]
+    for i, tok in enumerate(toks[:-1]):
+        lg, cache = api.decode(params, jnp.array([tok]), cache,
+                               jnp.int32(len(_PROMPT) + i))
+        rows.append(lg[0, -1])
+    lg_old = np.asarray(jnp.stack(rows), np.float32)
+    scale = np.abs(lg_old).max()
+    np.testing.assert_allclose(lg_engine, lg_old, rtol=0, atol=tol * scale)
+    if dtype == "float32":
+        assert toks == [int(t) for t in np.argmax(lg_old, axis=-1)]
+
+
+def _weight_quant_ops(jaxpr, shapes):
+    """abs / reductions / rounding whose operand has a weight's shape,
+    through every nested jaxpr (the jit, the scan over layers)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("abs", "round") or name.startswith("reduce"):
+            found += [name for v in eqn.invars
+                      if tuple(getattr(v.aval, "shape", ())) in shapes]
+        for sub in eqn.params.values():
+            for j in (sub if isinstance(sub, (list, tuple)) else [sub]):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    found += _weight_quant_ops(inner, shapes)
+    return found
+
+
+def test_decode_does_not_quantize_weights():
+    """The engine's decode program reads codes: no abs, reduce or round over
+    a weight-shaped operand (the latent per-step path has them)."""
+    from repro.quant.bitnet import TernaryWeight
+
+    api, params = _bitnet_api()
+    eng = ServeEngine(api, params, max_slots=2, max_seq=64)
+    is_tw = lambda x: isinstance(x, TernaryWeight)
+    served = [x for x in jax.tree.leaves(eng.params, is_leaf=is_tw)
+              if is_tw(x)]
+    shapes = {s for tw in served for s in (tw.q.shape, tw.q.shape[1:])}
+    tok = jnp.zeros((2,), jnp.int32)
+    args = (tok, eng.cache, jnp.zeros((2,), jnp.int32))
+    prog = jax.make_jaxpr(eng._decode)(eng.params, *args)
+    assert _weight_quant_ops(prog.jaxpr, shapes) == []
+    old = jax.make_jaxpr(api.decode)(params, *args)
+    assert "abs" in _weight_quant_ops(old.jaxpr, shapes)
+
+
+@pytest.mark.parametrize("arch,quantization,leaves", [
+    ("bitnet-1.58b-kv", "bitnet", 7),
+    ("bitnet-1.58b-kv", "none", 0),
+    ("granite-moe-1b-a400m", "bitnet", 7),
+    ("mamba2-130m", "bitnet", 4),
+    ("zamba2-7b", "bitnet", 11),
+])
+def test_ternary_projection_gauge(arch, quantization, leaves):
+    """The engine reports the projection leaves it serves as codes (the
+    attention and MLP projections, an SSM's in/out projections, a hybrid's
+    shared block besides); a model that is not quantized is served with
+    the very params it was given."""
+    from repro.obs.metrics import MetricsRegistry
+
+    cfg = reduced(get_config(arch)).replace(quantization=quantization)
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    reg = MetricsRegistry()
+    eng = ServeEngine(api, params, max_slots=2, max_seq=32, metrics=reg)
+    assert eng.ternary_projection_leaves == leaves
+    assert reg.get("serve_ternary_projection_leaves").value() == leaves
+    if not leaves:
+        assert eng.params is params
+    req = eng.submit(np.arange(1, 6), max_new_tokens=3)
+    eng.run_until_done()
+    assert req.done and len(req.output) == 3
+    assert all(0 <= t < cfg.vocab for t in req.output)
 
 
 def test_prompt_boundary_completions():

@@ -111,3 +111,68 @@ def test_packed_dequantize_roundtrip(rng):
         np.asarray(qt.dequantize()),
         np.asarray(q.astype(jnp.float32) * gamma), rtol=1e-6,
     )
+
+
+@pytest.mark.parametrize("shape", [(3, 64, 48), (2, 4, 32, 16)],
+                         ids=["stacked", "moe"])
+def test_ternary_weight_matches_per_layer_quantization(shape):
+    """Codes and gamma of the served form are exactly what
+    ``quantize_weight_ternary`` gives on each bf16 layer slice (the
+    fake-quant step's own numbers), gamma widened to f32."""
+    rng = np.random.default_rng(7)
+    w = jnp.asarray(rng.standard_normal(shape) / 8, jnp.bfloat16)
+    tw = bitnet.ternary_weight(w)
+    assert tw.q.dtype == jnp.int8 and tw.q.shape == shape
+    assert tw.gamma.dtype == jnp.float32 and tw.gamma.shape == shape[:1]
+    assert set(np.unique(np.asarray(tw.q))) <= {-1, 0, 1}
+    for layer in range(shape[0]):
+        q, gamma = bitnet.quantize_weight_ternary(w[layer])
+        np.testing.assert_array_equal(np.asarray(tw.q[layer]), np.asarray(q))
+        assert np.asarray(tw.gamma[layer]) == np.float32(gamma)
+    # a leaf that is not stacked gets one gamma over the whole of it
+    whole = bitnet.ternary_weight(w[0], stacked=False)
+    np.testing.assert_array_equal(np.asarray(whole.q), np.asarray(tw.q[0]))
+    assert whole.gamma.shape == ()
+
+
+@pytest.mark.parametrize("x_shape,w_shape,spec", [
+    ((16, 256), (256, 192), None),
+    ((2, 5, 256), (256, 192), None),
+    ((2, 4, 3, 64), (4, 64, 32), "becd,edf->becf"),
+], ids=["tokens", "batch-seq", "experts"])
+def test_ternary_dot_is_exact_bitlinear(x_shape, w_shape, spec):
+    """The integer BitLinear on a served weight agrees with the bf16
+    fake-quant path within bf16 rounding (unit roundoff u = 2**-8: two
+    rounded operands and a rounded output, 3u of the magnitude sum), and
+    lies closer than it to the f32 BitLinear (the same int8 x ternary codes
+    and scales, summed at ``highest``)."""
+    from repro.models.common import dense
+
+    u = 2.0 ** -8
+    rng = np.random.default_rng(11)
+    for _ in range(4):
+        x = jnp.asarray(rng.standard_normal(x_shape) * 2, jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal(w_shape) / 16, jnp.bfloat16)
+        tw = bitnet.ternary_weight(w, stacked=False)
+        if spec is None:
+            got, fq = dense(x, tw), dense(x, w, quantize=True)
+            spec_ = "...d,df->...f"
+        else:
+            got = bitnet.ternary_dot(x, tw, spec)
+            fq = jnp.einsum(spec, bitnet.fake_quant_act(x),
+                            bitnet.fake_quant_weight(w))
+            spec_ = spec
+        assert got.dtype == x.dtype
+        xq, xs = bitnet.quantize_act_int8(x)
+        q, gamma = bitnet.quantize_weight_ternary(w)
+        a = xq.astype(jnp.float32) * xs.astype(jnp.float32)
+        b = q.astype(jnp.float32) * gamma.astype(jnp.float32)
+        ref = np.asarray(jnp.einsum(spec_, a, b, precision="highest"))
+        mag = np.asarray(jnp.einsum(spec_, jnp.abs(a), jnp.abs(b),
+                                    precision="highest"))
+        got = np.asarray(got, np.float32)
+        fq = np.asarray(fq, np.float32)
+        assert (np.abs(got - fq) <= 3 * u * mag).all()
+        # only the output's rounding to bf16 (plus f32 scaling) remains
+        assert (np.abs(got - ref) <= u * np.abs(ref) + 2.0 ** -12 * mag).all()
+        assert np.linalg.norm(got - ref) <= np.linalg.norm(fq - ref)

@@ -55,6 +55,21 @@ def test_extract_projection_ops_shapes(served):
         assert op.workload.weight_bits == 2
 
 
+def test_extract_projection_ops_takes_served_codes(served):
+    """The backend's weights are the served codes themselves; latent
+    weights give the same codes, quantized as the engine would."""
+    cfg, api, params = served
+    layer = cfg.layers - 1
+    codes = extract_projection_ops(cfg, params, layer=layer)
+    latent = extract_projection_ops(
+        cfg, api.init(jax.random.PRNGKey(0)), layer=layer)
+    by_stage = {op.workload.stage: op.weights for op in codes}
+    wo = np.asarray(params["blocks"]["attn"]["wo"].q[layer])
+    np.testing.assert_array_equal(by_stage["out_proj"][0], wo)
+    for a, b in zip(codes, latent):
+        np.testing.assert_array_equal(a.weights, b.weights)
+
+
 def test_decode_step_cross_validates_traffic_and_cycles(served):
     """A decode step at context 16: projections + act-to-act attention
     (KV-cache matrices as stationary operands), all six stage families
