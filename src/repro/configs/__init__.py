@@ -12,6 +12,7 @@ from repro.configs import (
     hubert_xlarge,
     internvl2_76b,
     mamba2_130m,
+    moonlight_16b_a3b,
     qwen3_1_7b,
     smollm_360m,
     starcoder2_3b,
@@ -41,6 +42,7 @@ _MODULES = {
     "internvl2-76b": internvl2_76b,
     "bitnet-1.58b": bitnet_1_58b,
     "bitnet-1.58b-kv": bitnet_1_58b_kv,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
 ASSIGNED_ARCHS: List[str] = [
@@ -82,6 +84,13 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         )
     if cfg.n_experts:
         kw.update(n_experts=8, top_k=2, d_ff=64, n_experts_padded=0)
+    if cfg.experts_held:
+        kw.update(experts_held=4, expert_offset=0)
+    if cfg.first_k_dense:
+        kw.update(layers=3, dense_d_ff=256)
+    if cfg.kv_lora_rank:
+        kw.update(kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                  v_head_dim=32)
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_state=16, ssm_head_dim=16, ssd_chunk=32)
     if cfg.family == "hybrid":

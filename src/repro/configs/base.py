@@ -18,6 +18,7 @@ class ModelConfig:
     head_dim: int = 0            # 0 => d_model // n_heads
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-6       # RMSNorm epsilon
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
@@ -25,6 +26,28 @@ class ModelConfig:
     # pad the expert dim to a mesh-divisible count (dummy experts hold zero
     # weights and receive no tokens); 0 = no padding
     n_experts_padded: int = 0
+    # shared experts: one always-on SwiGLU of width n_shared_experts * d_ff
+    n_shared_experts: int = 0
+    # router scores: softmax over every expert, or sigmoid per expert with
+    # a correction bias that steers the top-k choice only (DeepSeek-V3)
+    router_scoring: str = "softmax"   # softmax | sigmoid
+    norm_topk_prob: bool = True       # renormalize the chosen gates
+    routed_scaling: float = 1.0       # times the routed experts' sum
+    # the expert-parallel share this chip holds: experts
+    # [expert_offset, expert_offset + experts_held) of every expert layer,
+    # routed over all n_experts, dispatched dropless; 0 = every expert,
+    # capacity-dispatched
+    experts_held: int = 0
+    expert_offset: int = 0
+    # leading dense layers (SwiGLU of width dense_d_ff) before the expert
+    # layers (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    # --- latent attention (MLA); kv_lora_rank 0 = multi-head attention ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (Mamba-2 / SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -88,21 +111,49 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     # ------------------------------------------------------------------ #
+    @property
+    def n_experts_held(self) -> int:
+        """Experts of each expert layer whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def moe_layers(self) -> int:
+        return self.layers - self.first_k_dense if self.family == "moe" else 0
+
+    def attn_params(self) -> int:
+        """One attention sub-layer: its projections (and MLA's latent norm)."""
+        d, h = self.d_model, self.n_heads
+        if self.kv_lora_rank:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            nope, v = self.qk_nope_head_dim, self.v_head_dim
+            return (d * h * (nope + rope) + d * (r + rope) + r
+                    + r * h * (nope + v) + h * v * d)
+        hd = self.head_dim_
+        return d * h * hd + 2 * d * self.kv_heads * hd + h * hd * d
+
+    def expert_params(self) -> int:
+        """One routed expert (a SwiGLU of width d_ff)."""
+        return 3 * self.d_model * self.d_ff
+
     def param_count(self) -> int:
-        """Analytic parameter count (used for 6*N*D model-FLOPs)."""
+        """Analytic parameter count of what this chip holds (used for
+        6*N*D model-FLOPs): every layer, and of each expert layer its held
+        experts."""
         d, l = self.d_model, self.layers
-        n = self.vocab * d                       # embeddings
+        n = self.vocab * d + d                   # embeddings, final norm
         if not self.tie_embeddings:
             n += self.vocab * d
         per_layer = 0
         if self.family in ("dense", "moe", "encoder", "vlm"):
-            hd = self.head_dim_
-            per_layer += d * self.n_heads * hd + 2 * d * self.kv_heads * hd
-            per_layer += self.n_heads * hd * d   # out proj
-            per_layer += 2 * d                   # norms
+            per_layer += self.attn_params() + 2 * d   # + norms
             if self.family == "moe":
-                per_layer += d * self.n_experts  # router
-                per_layer += self.n_experts * 3 * d * self.d_ff
+                n += self.first_k_dense * 3 * d * self.dense_d_ff
+                router = d * self.n_experts
+                if self.router_scoring == "sigmoid":
+                    router += self.n_experts     # selection bias
+                experts = self.n_experts_held + self.n_shared_experts
+                n += self.moe_layers * (router
+                                        + experts * self.expert_params())
             else:
                 per_layer += 3 * d * self.d_ff   # swiglu
         elif self.family in ("ssm", "hybrid"):
@@ -119,12 +170,13 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k of n_experts)."""
+        """Active params per token (MoE: at most top_k of the held
+        experts)."""
         if self.family != "moe":
             return self.param_count()
-        d, l = self.d_model, self.layers
-        inactive = (self.n_experts - self.top_k) * 3 * d * self.d_ff * l
-        return self.param_count() - inactive
+        idle = self.n_experts_held - min(self.top_k, self.n_experts_held)
+        return (self.param_count()
+                - idle * self.expert_params() * self.moe_layers)
 
 
 @dataclasses.dataclass(frozen=True)

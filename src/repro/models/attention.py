@@ -1,4 +1,5 @@
-"""Multi-head attention with GQA/MQA, RoPE, qk-norm and KV caching.
+"""Multi-head attention with GQA/MQA, RoPE, qk-norm and KV caching, and
+multi-head latent attention (MLA, DeepSeek-V2/V3) over a latent cache.
 
 Head-over-"model"-axis sharding mirrors the paper's head-per-Legion mapping;
 replicated KV (kv_heads < model-axis size) mirrors the KV multicast.
@@ -12,12 +13,22 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
-from repro.models.common import apply_rope, dense, dense_init, rms_norm, rope_angles
+from repro.models.common import (
+    apply_rope,
+    bitlinear_codes,
+    bitlinear_weight,
+    dense,
+    dense_init,
+    rms_norm,
+    rope_angles,
+)
 
 
 class KVCache(NamedTuple):
-    k: jnp.ndarray   # [B, Hkv, S_max, hd]
-    v: jnp.ndarray   # [B, Hkv, S_max, hd]
+    """K and V per head, or for MLA the latent and the rotary key."""
+
+    k: jnp.ndarray   # [B, Hkv, S_max, hd]; MLA: latent c [B, 1, S_max, r]
+    v: jnp.ndarray   # [B, Hkv, S_max, hd]; MLA: roped key [B, 1, S_max, rope]
 
 
 def init_attn_params(key, cfg, dtype) -> dict:
@@ -236,8 +247,8 @@ FLASH_THRESHOLD = 2048
 def _sdpa(q, k, v, *, causal: bool, q_offset=None, kv_len: Optional[int] = None):
     """q [B,S,H,hd], k/v [B,T,Hkv,hd] — einsum attention, GQA via reshape."""
     if (q.shape[1] > FLASH_THRESHOLD and q.shape[1] == k.shape[1]
-            and kv_len is None and q.shape[1] % 1024 == 0
-            and k.shape[1] % 512 == 0):
+            and kv_len is None and v.shape[-1] == q.shape[-1]
+            and q.shape[1] % 1024 == 0 and k.shape[1] % 512 == 0):
         from repro.distributed.sharding import active_rules
         rules = active_rules()
         if rules is not None and rules.table.get("seq") is not None:
@@ -270,7 +281,7 @@ def _sdpa(q, k, v, *, causal: bool, q_offset=None, kv_len: Optional[int] = None)
         scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, s, h, hd).astype(q.dtype)
+    return out.reshape(b, s, h, v.shape[-1]).astype(q.dtype)
 
 
 def attention(
@@ -342,5 +353,123 @@ def attention(
 
 
 def init_kv_cache(cfg, batch: int, max_seq: int, dtype) -> KVCache:
+    if cfg.kv_lora_rank:
+        return KVCache(
+            jnp.zeros((batch, 1, max_seq, cfg.kv_lora_rank), dtype),
+            jnp.zeros((batch, 1, max_seq, cfg.qk_rope_head_dim), dtype))
     shape = (batch, cfg.kv_heads, max_seq, cfg.head_dim_)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+# --------------------------------------------------------------------------- #
+# Multi-head latent attention (MLA)
+# --------------------------------------------------------------------------- #
+
+def init_mla_params(key, cfg, dtype) -> dict:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, h * (nope + rope), dtype),
+        "wkv_a": dense_init(ks[1], d, r + rope, dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": dense_init(ks[2], r, h * (nope + vd), dtype),
+        "wo": dense_init(ks[3], h * vd, d, dtype),
+    }
+
+
+def _write_positions(cache_leaf, new, cache_pos):
+    """Write ``new`` [B, 1, s, w] into a [B, 1, S_max, w] cache leaf at
+    ``cache_pos`` (a scalar, or one position per slot)."""
+    if jnp.ndim(cache_pos) == 1:
+        return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
+            c, n, (0, p, 0)))(cache_leaf, new, cache_pos)
+    return jax.lax.dynamic_update_slice(cache_leaf, new,
+                                        (0, 0, cache_pos, 0))
+
+
+def _mla_expanded(p, cfg, q_nope, q_pe, c, k_pe, quant):
+    """Attention over the sequence's own positions: ``kv_b_proj(c)`` gives
+    each head its K_nope and V; the rotary key is shared by the heads."""
+    b, s, h, _ = q_nope.shape
+    nope = cfg.qk_nope_head_dim
+    kv = dense(c, p["wkv_b"], quantize=quant).reshape(b, s, h, -1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_pe[:, :, None], (b, s, h, k_pe.shape[-1]))], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    return _sdpa(q, k, kv[..., nope:], causal=cfg.causal)
+
+
+def _mla_absorbed(p, cfg, q_nope, q_pe, c_all, pe_all, positions, quant):
+    """Attention over the cached latent itself: ``kv_b_proj``'s K half is
+    folded into each query and its V half into each head's output, so no
+    head's K or V is ever formed.  Query positions ``positions`` [B, s]
+    attend every cached position at or below them."""
+    h, nope = cfg.n_heads, cfg.qk_nope_head_dim
+    f32 = jnp.float32
+    w = bitlinear_weight(p["wkv_b"], quant).reshape(cfg.kv_lora_rank, h, -1)
+    # the latent as kv_b's BitLinear multiplies it: integer codes and one
+    # scale per position
+    cq, cs = bitlinear_codes(c_all, p["wkv_b"], quant)        # [B,T,r] [B,T,1]
+    cs = cs[..., 0][:, None, None, :]                          # [B,1,1,T]
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope.astype(f32), w[..., :nope])
+    sc = jnp.einsum("bshr,btr->bhst", q_lat, cq) * cs + jnp.einsum(
+        "bshe,bte->bhst", q_pe.astype(f32), pe_all.astype(f32))
+    sc = sc / ((nope + cfg.qk_rope_head_dim) ** 0.5)
+    t = c_all.shape[1]
+    mask = jnp.arange(t)[None, None, :] <= positions[:, :, None]   # [B,s,T]
+    probs = jax.nn.softmax(jnp.where(mask[:, None], sc, -1e30), axis=-1)
+    o_lat = jnp.einsum("bhst,btr->bshr", probs * cs, cq)
+    out = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., nope:])
+    return out.astype(q_nope.dtype)
+
+
+def mla_attention(
+    p: dict, cfg, x: jnp.ndarray, *, positions: jnp.ndarray,
+    cache: Optional[KVCache] = None, cache_pos=None,
+) -> tuple:
+    """Multi-head latent attention sub-layer (DeepSeek-V3, ``q_lora_rank``
+    null: a plain ``q_proj``).
+
+    ``[c, k_pe] = kv_a_proj_with_mqa(x)``, ``c`` normalised by
+    ``kv_a_layernorm``; the cache holds ``c`` and the roped ``k_pe``.
+    Scores scale by ``1/sqrt(qk_nope + qk_rope)``.  Rotary positions are
+    rotate-half on the rope dims (HF's interleaved layout is the same up
+    to a fixed permutation of the rope weights).
+
+    Two forms of one arithmetic: attention over the sequence's own
+    positions (training, a whole-prompt prefill) expands ``kv_b_proj(c)``;
+    attention over the cache (a decode step, a prefill chunk) absorbs
+    ``kv_b_proj`` into the query and the output and reads the latent.
+    ``kv_b_proj``'s BitLinear quantizes ``c`` per position, so the two
+    agree up to summation order.  Returns (out [B, s, d], new_cache).
+    """
+    b, s, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope = cfg.qk_nope_head_dim
+    quant = cfg.quantization == "bitnet"
+    with jax.named_scope("mla"):
+        q = dense(x, p["wq"], quantize=quant).reshape(b, s, h, -1)
+        kv = dense(x, p["wkv_a"], quantize=quant)
+        c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)  # [B, s, r]
+        cos, sin = rope_angles(positions, cfg.qk_rope_head_dim,
+                               cfg.rope_theta)
+        q_nope, q_pe = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+        k_pe = apply_rope(kv[..., None, r:], cos, sin)[:, :, 0]  # [B, s, e]
+        new_cache = None
+        if cache is None:
+            out = _mla_expanded(p, cfg, q_nope, q_pe, c, k_pe, quant)
+        elif cache_pos is None:
+            # whole-prompt prefill: fill [0, s), attend the prompt itself
+            new_cache = KVCache(_write_positions(cache.k, c[:, None], 0),
+                                _write_positions(cache.v, k_pe[:, None], 0))
+            out = _mla_expanded(p, cfg, q_nope, q_pe, c, k_pe, quant)
+        else:
+            new_cache = KVCache(
+                _write_positions(cache.k, c[:, None], cache_pos),
+                _write_positions(cache.v, k_pe[:, None], cache_pos))
+            out = _mla_absorbed(p, cfg, q_nope, q_pe, new_cache.k[:, 0],
+                                new_cache.v[:, 0], positions, quant)
+        out = out.reshape(b, s, h * cfg.v_head_dim)
+        return dense(out, p["wo"], quantize=quant), new_cache

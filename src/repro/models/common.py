@@ -12,6 +12,7 @@ from repro.quant.bitnet import (
     TernaryWeight,
     fake_quant_act,
     fake_quant_weight,
+    quantize_act_int8,
     ternary_dot,
 )
 
@@ -41,6 +42,30 @@ def dense(
         x = fake_quant_act(x)
         w = fake_quant_weight(w)
     return x @ w
+
+
+def bitlinear_weight(w, quantize: bool) -> jnp.ndarray:
+    """The values :func:`dense` multiplies by, in float32: a served
+    weight's codes times gamma, the fake-quantized latent weight, or the
+    plain weight."""
+    if isinstance(w, TernaryWeight):
+        return w.q.astype(jnp.float32) * w.gamma
+    if quantize:
+        w = fake_quant_weight(w)
+    return w.astype(jnp.float32)
+
+
+def bitlinear_codes(x, w, quantize: bool):
+    """The activations :func:`dense` multiplies ``w`` by, as float32 codes
+    and a per-token scale [..., 1] (values = codes * scale): a served
+    weight's int8 codes and absmax scale, else the fake-quantized or plain
+    values with scale 1."""
+    if isinstance(w, TernaryWeight):
+        xq, xs = quantize_act_int8(x)
+        return xq.astype(jnp.float32), xs.astype(jnp.float32)
+    if quantize:
+        x = fake_quant_act(x)
+    return x.astype(jnp.float32), jnp.ones(x.shape[:-1] + (1,), jnp.float32)
 
 
 def rms_norm(x: jnp.ndarray, gamma: jnp.ndarray, eps: float = 1e-6):

@@ -1,10 +1,16 @@
-"""Token-choice top-k MoE with capacity-based dispatch (expert parallelism).
+"""Token-choice top-k MoE: capacity-based dispatch (expert parallelism
+over a mesh), or one chip's held share of the experts, dropless.
 
 Experts shard over the "model" mesh axis (EP) — each expert is a Legion-like
 independent worker; tokens route via scatter/gather, which XLA SPMD turns
 into the expected all-to-all pattern.  The ZTB analogy: an expert with no
 routed tokens is a fully-sparse window — XLA still executes the (empty)
 GEMM, but the simulator and sparse serving path skip it.
+
+A config with ``experts_held`` is one chip's share of an expert-parallel
+deployment (:func:`held_experts`): it routes over every expert and computes
+only its own experts' part of the result, for every token routed to them.
+A shared expert (``n_shared_experts``) is added for every token.
 """
 from __future__ import annotations
 
@@ -12,16 +18,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
-from repro.models.common import dense_init
+from repro.models.common import dense_init, swiglu
 from repro.quant.bitnet import (
     TernaryWeight,
     fake_quant_act,
     fake_quant_weight,
+    quantize_act_int8,
     ternary_dot,
 )
 
 
 def init_moe_params(key, cfg, dtype) -> dict:
+    if cfg.experts_held:
+        return _init_held(key, cfg, dtype)
     ks = jax.random.split(key, 4)
     # weights sized to the padded expert count (dummy experts: never routed
     # to — the router only emits real expert ids — but they make the expert
@@ -36,6 +45,111 @@ def init_moe_params(key, cfg, dtype) -> dict:
         "w3": (jax.random.normal(ks[2], (e, d, f)) * scale_in).astype(dtype),
         "w2": (jax.random.normal(ks[3], (e, f, d)) * scale_out).astype(dtype),
     }
+
+
+def _init_held(key, cfg, dtype) -> dict:
+    ks = jax.random.split(key, 5)
+    e, d, f = cfg.n_experts_held, cfg.d_model, cfg.d_ff
+    normal = lambda k, shape, fan_in: (
+        jax.random.normal(k, shape) / jnp.sqrt(fan_in)).astype(dtype)
+    p = {
+        # the router spans every expert of the layer, held here or not
+        "router": dense_init(ks[0], d, cfg.n_experts, jnp.float32),
+        "experts": {"w1": normal(ks[1], (e, d, f), d),
+                    "w3": normal(ks[2], (e, d, f), d),
+                    "w2": normal(ks[3], (e, f, d), f)},
+    }
+    if cfg.router_scoring == "sigmoid":
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        k1, k2, k3 = jax.random.split(ks[4], 3)
+        p["shared"] = {"w1": dense_init(k1, d, fs, dtype),
+                       "w2": dense_init(k2, fs, d, dtype),
+                       "w3": dense_init(k3, d, fs, dtype)}
+    return p
+
+
+def route(p, cfg, x: jnp.ndarray):
+    """Each token's top-k experts over all ``n_experts``: (gates [..., k]
+    float32, ids [..., k]).  The router's logits in float32; softmax
+    scores, or sigmoid scores whose ``router_bias`` steers the choice only
+    (DeepSeek-V3's ``noaux_tc`` with one group); the chosen scores
+    renormalised when ``norm_topk_prob``, times ``routed_scaling``."""
+    # float32 at full precision: a top-k choice turns on small gaps
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["router_bias"], cfg.top_k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                   cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if cfg.routed_scaling != 1.0:
+        gates = gates * cfg.routed_scaling
+    return gates, idx
+
+
+def _held_dot(spec, x, w, quantize):
+    """Every held expert's BitLinear at once, each expert with its own
+    gamma: the integer path on a served :class:`TernaryWeight` (gamma
+    [E]), else fake-quant (STE) per expert when ``quantize``."""
+    if isinstance(w, TernaryWeight):
+        xq, xs = quantize_act_int8(x)
+        acc = jnp.einsum(spec, xq, w.q, preferred_element_type=jnp.int32)
+        if xs.ndim < acc.ndim:          # one scale per token, every expert
+            xs = xs[..., None]
+        scale = xs.astype(jnp.float32) * w.gamma[:, None]
+        return (acc.astype(jnp.float32) * scale).astype(x.dtype)
+    if quantize:
+        x, w = fake_quant_act(x), jax.vmap(fake_quant_weight)(w)
+    return jnp.einsum(spec, x, w)
+
+
+def held_experts(p, cfg, x: jnp.ndarray):
+    """This chip's part of the routed experts' result, dropless.
+
+    Routes every token over all ``n_experts`` and computes the held
+    experts ``[expert_offset, expert_offset + experts_held)`` densely for
+    every token, each weighted by its gate (zero where the token did not
+    choose it): no capacity, so no token is dropped however skewed the
+    routing.  Experts held elsewhere add nothing here.  Returns (y [..., d],
+    routed [..., experts_held] bool: which held experts each token chose).
+    """
+    quantize = cfg.quantization == "bitnet"
+    with jax.named_scope("moe.router"):
+        gates, idx = route(p, cfg, x)
+        held = cfg.expert_offset + jnp.arange(cfg.n_experts_held)
+        chose = idx[..., None] == held                    # [..., k, held]
+        g = jnp.sum(jnp.where(chose, gates[..., None], 0.0), axis=-2)
+        routed = jnp.any(chose, axis=-2)
+    with jax.named_scope("moe.experts"):
+        w = p["experts"]
+        h = jax.nn.silu(_held_dot("...d,edf->...ef", x, w["w1"], quantize)
+                        ) * _held_dot("...d,edf->...ef", x, w["w3"], quantize)
+        out = _held_dot("...ef,efd->...ed", h, w["w2"], quantize)
+        y = jnp.einsum("...e,...ed->...d", g, out.astype(jnp.float32))
+    return y.astype(x.dtype), routed
+
+
+def moe_ffn(p, cfg, x: jnp.ndarray):
+    """The expert layer's FFN: (y [B, S, d], routed [B, S, held] or None).
+    Held experts (dropless) where the config states a share, else
+    capacity dispatch; plus the shared expert for every token."""
+    routed = None
+    if cfg.experts_held:
+        y, routed = held_experts(p, cfg, x)
+    else:
+        y = moe_block(p, cfg, x)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            s = p["shared"]
+            y = y + swiglu(x, s["w1"], s["w2"], s["w3"],
+                           quantize=cfg.quantization == "bitnet")
+    return y, routed
 
 
 def _expert_dot(spec, x, w, quantize):
@@ -63,10 +177,7 @@ def moe_block(p, cfg, x: jnp.ndarray) -> jnp.ndarray:
     cap = int(cfg.capacity_factor * k * s / cfg.n_experts + 1)
     quantize = cfg.quantization == "bitnet"
 
-    logits = x.astype(jnp.float32) @ p["router"]             # [B, S, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)                     # [B, S, k]
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    gates, idx = route(p, cfg, x)                            # [B, S, k]
 
     # position of each (token, slot) within its expert, per row
     e_flat = idx.reshape(b, s * k)                           # [B, S*k]

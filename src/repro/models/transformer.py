@@ -3,7 +3,10 @@ encoder families).
 
 Layer parameters are stacked on a leading [L] axis and consumed with
 ``jax.lax.scan`` so an 80-layer model traces exactly one block — mandatory
-for compiling the big dry-run cells and standard practice at scale.
+for compiling the big dry-run cells and standard practice at scale.  A
+model with leading dense layers (``first_k_dense``) keeps two stacks,
+``dense_blocks`` then ``blocks``, each scanned, and its cache is a tuple
+of one :class:`KVCache` per stack.
 """
 from __future__ import annotations
 
@@ -19,6 +22,8 @@ from repro.models.attention import (
     attention,
     init_attn_params,
     init_kv_cache,
+    init_mla_params,
+    mla_attention,
 )
 from repro.models.common import (
     best_grouping,
@@ -28,6 +33,7 @@ from repro.models.common import (
     embed_init,
     maybe_remat,
     rms_norm,
+    swiglu,
 )
 
 
@@ -35,22 +41,26 @@ from repro.models.common import (
 # Init
 # --------------------------------------------------------------------------- #
 
-def _init_block(cfg, key):
+def _init_block(cfg, key, d_ff=None):
+    """One layer: an expert layer for the MoE family, else (or with
+    ``d_ff``, a leading dense layer's width) a SwiGLU MLP."""
     dtype = dtype_of(cfg)
     k_attn, k_mlp = jax.random.split(key)
+    init_attn = init_mla_params if cfg.kv_lora_rank else init_attn_params
     p = {
         "ln1": jnp.ones((cfg.d_model,), dtype),
         "ln2": jnp.ones((cfg.d_model,), dtype),
-        "attn": init_attn_params(k_attn, cfg, dtype),
+        "attn": init_attn(k_attn, cfg, dtype),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and d_ff is None:
         p["moe"] = moe_mod.init_moe_params(k_mlp, cfg, dtype)
     else:
+        d_ff = d_ff or cfg.d_ff
         km = jax.random.split(k_mlp, 3)
         p["mlp"] = {
-            "w1": dense_init(km[0], cfg.d_model, cfg.d_ff, dtype),
-            "w2": dense_init(km[1], cfg.d_ff, cfg.d_model, dtype),
-            "w3": dense_init(km[2], cfg.d_model, cfg.d_ff, dtype),
+            "w1": dense_init(km[0], cfg.d_model, d_ff, dtype),
+            "w2": dense_init(km[1], d_ff, cfg.d_model, dtype),
+            "w3": dense_init(km[2], cfg.d_model, d_ff, dtype),
         }
     return p
 
@@ -59,12 +69,16 @@ def init_params(cfg, key) -> Dict:
     dtype = dtype_of(cfg)
     k_embed, k_layers, k_head, k_front = jax.random.split(key, 4)
     layer_keys = jax.random.split(k_layers, cfg.layers)
+    lead = cfg.first_k_dense
     params = {
         "embed": {"tokens": embed_init(k_embed, cfg.vocab, cfg.d_model,
                                        dtype)},
-        "blocks": jax.vmap(lambda k: _init_block(cfg, k))(layer_keys),
+        "blocks": jax.vmap(lambda k: _init_block(cfg, k))(layer_keys[lead:]),
         "ln_f": jnp.ones((cfg.d_model,), dtype),
     }
+    if lead:
+        params["dense_blocks"] = jax.vmap(
+            lambda k: _init_block(cfg, k, cfg.dense_d_ff))(layer_keys[:lead])
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, cfg.d_model, cfg.vocab, dtype)
     if cfg.frontend == "audio_frames":
@@ -75,43 +89,56 @@ def init_params(cfg, key) -> Dict:
     return params
 
 
+def _stacks(params) -> list:
+    """The stacks of like layers, in order."""
+    if "dense_blocks" in params:
+        return [params["dense_blocks"], params["blocks"]]
+    return [params["blocks"]]
+
+
 # --------------------------------------------------------------------------- #
 # Blocks
 # --------------------------------------------------------------------------- #
 
 def _block(cfg, p, x, positions, cache=None, cache_pos=None):
-    h = rms_norm(x, p["ln1"])
-    attn_out, new_cache = attention(
+    """One layer: (x, new_cache, routed) — ``routed`` [B, S, held] marks
+    the held experts each token chose (None without held experts)."""
+    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    attn = mla_attention if cfg.kv_lora_rank else attention
+    attn_out, new_cache = attn(
         p["attn"], cfg, h, positions=positions, cache=cache,
         cache_pos=cache_pos,
     )
     x = x + attn_out
-    h = rms_norm(x, p["ln2"])
-    if cfg.family == "moe":
-        x = x + moe_mod.moe_block(p["moe"], cfg, h)
+    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    routed = None
+    if "moe" in p:
+        y, routed = moe_mod.moe_ffn(p["moe"], cfg, h)
+        x = x + y
     else:
-        from repro.models.common import swiglu
         x = x + swiglu(h, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"],
                        quantize=cfg.quantization == "bitnet")
     x = constrain(x, "batch", "seq", "embed")
-    return x, new_cache
+    return x, new_cache, routed
 
 
 def _scan_blocks(cfg, blocks, x, positions, caches=None, cache_pos=None):
-    """lax.scan over stacked layer params (and stacked KV caches)."""
+    """lax.scan over stacked layer params (and stacked KV caches).
+    Returns (x, new caches, routed [L, B, S, held] or None)."""
+    layers = jax.tree.leaves(blocks)[0].shape[0]
 
     if caches is None:
         def body(carry, layer_p):
             layer_p = constrain_layer_params(layer_p, cfg)
-            y, _ = _block(cfg, layer_p, carry, positions)
+            y, _, _ = _block(cfg, layer_p, carry, positions)
             return y, None
 
-        groups = best_grouping(cfg.layers) if cfg.remat != "none" else 1
+        groups = best_grouping(layers) if cfg.remat != "none" else 1
         if groups > 1:
             # sqrt-remat: outer scan over G checkpointed groups, plain inner
             # scan over layers-per-group — G + L/G saved carries, not L
             grouped = jax.tree.map(
-                lambda a: a.reshape(groups, cfg.layers // groups,
+                lambda a: a.reshape(groups, layers // groups,
                                     *a.shape[1:]), blocks,
             )
 
@@ -124,22 +151,37 @@ def _scan_blocks(cfg, blocks, x, positions, caches=None, cache_pos=None):
             x, _ = jax.lax.scan(maybe_remat(group_body, cfg), x, grouped)
         else:
             x, _ = jax.lax.scan(maybe_remat(body, cfg), x, blocks)
-        return x, None
+        return x, None, None
 
     def body(carry, xs):
         layer_p, kc, vc = xs
-        y, new_cache = _block(
+        y, new_cache, routed = _block(
             cfg, layer_p, carry, positions, cache=KVCache(kc, vc),
             cache_pos=cache_pos,
         )
-        return y, (new_cache.k, new_cache.v)
+        return y, (new_cache.k, new_cache.v, routed)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (blocks, caches.k, caches.v))
-    return x, KVCache(ks, vs)
+    x, (ks, vs, routed) = jax.lax.scan(body, x,
+                                       (blocks, caches.k, caches.v))
+    return x, KVCache(ks, vs), routed
+
+
+def _scan_stacks(cfg, params, x, positions, cache, cache_pos=None):
+    """Every stack in order over its part of the cache: (x, new cache,
+    routed of the last stack that routes, or None)."""
+    stacks = _stacks(params)
+    if len(stacks) == 1:
+        return _scan_blocks(cfg, stacks[0], x, positions, cache, cache_pos)
+    new, routed = [], None
+    for blocks, c in zip(stacks, cache):
+        x, c, r = _scan_blocks(cfg, blocks, x, positions, c, cache_pos)
+        new.append(c)
+        routed = r if r is not None else routed
+    return x, tuple(new), routed
 
 
 def _logits(cfg, params, x):
-    x = rms_norm(x, params["ln_f"])
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = x @ params["embed"]["tokens"].T
     else:
@@ -173,35 +215,33 @@ def forward_train(cfg, params, batch) -> jnp.ndarray:
     """Returns logits [B, S_total, V]."""
     x, positions = _embed_inputs(cfg, params, batch)
     x = constrain(x, "batch", "seq", "embed")
-    x, _ = _scan_blocks(cfg, params["blocks"], x, positions)
+    for blocks in _stacks(params):
+        x, _, _ = _scan_blocks(cfg, blocks, x, positions)
     return _logits(cfg, params, x)
 
 
-def init_cache(cfg, batch: int, max_seq: int) -> KVCache:
+def init_cache(cfg, batch: int, max_seq: int):
+    """A stacked :class:`KVCache` over the layers; with leading dense
+    layers, a tuple of one per stack."""
     dtype = dtype_of(cfg)
     single = init_kv_cache(cfg, batch, max_seq, dtype)
-    stack = lambda a: jnp.broadcast_to(a[None], (cfg.layers,) + a.shape)
-    return KVCache(stack(single.k), stack(single.v))
+
+    def stack(n):
+        return KVCache(*(jnp.broadcast_to(a[None], (n,) + a.shape)
+                         for a in single))
+
+    if cfg.first_k_dense:
+        return (stack(cfg.first_k_dense),
+                stack(cfg.layers - cfg.first_k_dense))
+    return stack(cfg.layers)
 
 
 def forward_prefill(cfg, params, batch, cache: KVCache):
     """Prompt pass: fills cache[:, :, :, :S), returns (last_logits, cache)."""
     x, positions = _embed_inputs(cfg, params, batch)
-    x, new_cache = _scan_blocks_prefill(cfg, params["blocks"], x, positions,
-                                        cache)
+    x, new_cache, _ = _scan_stacks(cfg, params, x, positions, cache)
     logits = _logits(cfg, params, x[:, -1:, :])
     return logits, new_cache
-
-
-def _scan_blocks_prefill(cfg, blocks, x, positions, caches):
-    def body(carry, xs):
-        layer_p, kc, vc = xs
-        y, new_cache = _block(cfg, layer_p, carry, positions,
-                              cache=KVCache(kc, vc), cache_pos=None)
-        return y, (new_cache.k, new_cache.v)
-
-    x, (ks, vs) = jax.lax.scan(body, x, (blocks, caches.k, caches.v))
-    return x, KVCache(ks, vs)
 
 
 def forward_prefill_chunk(cfg, params, tokens, cache: KVCache, pos0):
@@ -219,8 +259,7 @@ def forward_prefill_chunk(cfg, params, tokens, cache: KVCache, pos0):
     positions = jnp.broadcast_to(
         (pos0 + jnp.arange(c, dtype=jnp.int32))[None], (b, c)
     )
-    x, new_cache = _scan_blocks(cfg, params["blocks"], x, positions,
-                                caches=cache, cache_pos=pos0)
+    x, new_cache, _ = _scan_stacks(cfg, params, x, positions, cache, pos0)
     logits = _logits(cfg, params, x[:, -1:, :])
     return logits, new_cache
 
@@ -228,13 +267,17 @@ def forward_prefill_chunk(cfg, params, tokens, cache: KVCache, pos0):
 def forward_decode(cfg, params, token, cache: KVCache, pos):
     """One decode step. token [B] int32, pos scalar or per-slot [B] int32.
 
-    Returns (logits [B, 1, V], new_cache).
+    Returns (logits [B, 1, V], new_cache); a model with held experts adds
+    ``routed`` [B, expert layers, held] bool, the held experts each slot's
+    token chose in each expert layer.
     """
     x = params["embed"]["tokens"][token][:, None, :]     # [B, 1, d]
     if jnp.ndim(pos) == 1:
         positions = pos[:, None].astype(jnp.int32)
     else:
         positions = jnp.full((x.shape[0], 1), pos, jnp.int32)
-    x, new_cache = _scan_blocks(cfg, params["blocks"], x, positions,
-                                caches=cache, cache_pos=pos)
-    return _logits(cfg, params, x), new_cache
+    x, new_cache, routed = _scan_stacks(cfg, params, x, positions, cache,
+                                        pos)
+    if routed is None:
+        return _logits(cfg, params, x), new_cache
+    return _logits(cfg, params, x), new_cache, routed[:, :, 0].swapaxes(0, 1)

@@ -75,14 +75,18 @@ class TernaryWeight:
     gamma: jnp.ndarray
 
 
-@functools.partial(jax.jit, static_argnames="stacked")
-def ternary_weight(w: jnp.ndarray, *, stacked: bool = True) -> TernaryWeight:
+@functools.partial(jax.jit, static_argnames=("stacked", "experts"))
+def ternary_weight(w: jnp.ndarray, *, stacked: bool = True,
+                   experts: bool = False) -> TernaryWeight:
     """:func:`quantize_weight_ternary` on each layer slice of ``w`` (the
-    leading axis when ``stacked``, else the whole leaf): the codes and
-    gamma the fake-quant step computes from the same slice, gamma widened
-    to float32 exactly."""
-    q, gamma = (jax.lax.map(quantize_weight_ternary, w) if stacked
-                else quantize_weight_ternary(w))
+    leading axis when ``stacked``, else the whole leaf) and, with
+    ``experts``, on each expert of a layer (the next axis: every expert is
+    a BitLinear of its own): the codes and gamma the fake-quant step
+    computes from the same slice, gamma widened to float32 exactly."""
+    quant = quantize_weight_ternary
+    if experts:
+        quant = functools.partial(jax.lax.map, quant)
+    q, gamma = jax.lax.map(quant, w) if stacked else quant(w)
     return TernaryWeight(q, gamma.astype(jnp.float32))
 
 

@@ -73,23 +73,29 @@ def _is_ternary(x) -> bool:
 
 def _is_projection(name: str) -> bool:
     return name.startswith("in_proj") or name in (
-        "wq", "wk", "wv", "wo", "w1", "w2", "w3", "out_proj")
+        "wq", "wk", "wv", "wo", "w1", "w2", "w3", "out_proj", "wkv_a",
+        "wkv_b")
 
 
 def prepare_params(params):
     """Offline weight transform for serving: each BitNet projection becomes
     a :class:`~repro.quant.bitnet.TernaryWeight` (int8 codes, one absmean
-    gamma per stacked layer), quantized ONCE here so the serve step runs the
-    integer BitLinear and never re-quantizes a weight.  Leaves already in
-    that form pass through, so the transform is idempotent (the packed
-    2-bit variant goes further via kernels/bitlinear)."""
+    gamma per stacked layer, and per expert of a held-expert layer),
+    quantized ONCE here so the serve step runs the integer BitLinear and
+    never re-quantizes a weight.  Routers, norms, the embedding and an
+    untied head stay as they are.  Leaves already in that form pass
+    through, so the transform is idempotent (the packed 2-bit variant goes
+    further via kernels/bitlinear)."""
     def q(path, leaf):
         keys = [str(getattr(p, "key", "")) for p in path]
         if (not _is_ternary(leaf) and leaf.ndim >= 2
                 and _is_projection(keys[-1])):
-            # layer params are stacked [L, ...] under "blocks"; a shared
-            # block (the hybrid's) is one weight applied whole
-            return ternary_weight(leaf, stacked="blocks" in keys)
+            # layer params are stacked [L, ...] under "blocks" (and
+            # "dense_blocks"); a shared block (the hybrid's) is one weight
+            # applied whole
+            return ternary_weight(
+                leaf, stacked=any(k.endswith("blocks") for k in keys),
+                experts="experts" in keys)
         return leaf
 
     return jax.tree_util.tree_map_with_path(q, params, is_leaf=_is_ternary)
@@ -188,7 +194,11 @@ class ServeEngine:
         # prompt-completing chunk and "done" a request that finished at
         # admission (EOS / budget / window) without taking a decode slot;
         # "uids"/"positions" are the step's batched decode exactly as in
-        # the legacy decode event.
+        # the legacy decode event.  A model with held experts adds to the
+        # decode (or step) event, once the step's tokens reach the host,
+        # "moe_routed" (tokens of the active slots routed to held experts,
+        # summed over the expert layers) and "moe_experts_hit" (the
+        # (layer, held expert) pairs they reached).
         self.step_observers: List[Callable[[dict], None]] = []
         # Batch occupancy per decode step (len(uids) of each event): how
         # full the continuous batch actually ran — the denominator behind
@@ -481,12 +491,34 @@ class ServeEngine:
             for fn in self.step_observers:
                 fn(event)
 
-    def _sample_decoded(self, logits: jnp.ndarray) -> np.ndarray:
-        """The decode step's next token per slot, on the host."""
+    def _sample_decoded(self, logits: jnp.ndarray, routed=None):
+        """The decode step's next token per slot, on the host, and the
+        held experts each slot's token chose ([slots, expert layers *
+        held], or None), both in the tokens' one copy."""
         with TraceAnnotation(SAMPLE_SPAN):
             tok = self._sample(logits[:, -1])
+            if routed is not None:
+                tok = _with_routed(tok, routed)
             with TraceAnnotation(SYNC_SPAN):
-                return np.asarray(tok)
+                host = np.asarray(tok)
+        if routed is None:
+            return host, None
+        return host[:, 0], host[:, 1:]
+
+    def _count_routed(self, event: dict, active: List[int], routed) -> None:
+        """Record in the step's event (and the metrics) the tokens routed
+        to held experts and the (layer, held expert) pairs they hit, over
+        the active slots."""
+        if routed is None:
+            return
+        hits = routed[active]
+        event["moe_routed"] = int(hits.sum())
+        event["moe_experts_hit"] = int(hits.any(axis=0).sum())
+        if self.metrics is not None:
+            self.metrics.counter("serve_moe_routed_tokens").inc(
+                event["moe_routed"])
+            self.metrics.counter("serve_moe_experts_hit").inc(
+                event["moe_experts_hit"])
 
     # ------------------------------------------------------------------ #
     def step(self):
@@ -504,7 +536,8 @@ class ServeEngine:
 
     def _decode_step(self, active: List[int]):
         """Run the batched decode over ``active`` slot indices; returns
-        the step logits (sampling happens after observers fire)."""
+        the step logits (sampling happens after observers fire) and, for
+        a model with held experts, the experts each slot chose."""
         with TraceAnnotation(DECODE_SPAN):
             tokens = np.zeros((self.max_slots,), np.int32)
             pos = np.zeros((self.max_slots,), np.int32)
@@ -512,7 +545,7 @@ class ServeEngine:
                 slot = self.slots[i]
                 tokens[i] = slot.request.output[-1]
                 pos[i] = slot.pos
-            logits, self.cache = self._decode(
+            logits, self.cache, *routed = self._decode(
                 self.params, jnp.asarray(tokens), self.cache,
                 jnp.asarray(pos))
         self.decode_batch_sizes.append(len(active))
@@ -524,7 +557,7 @@ class ServeEngine:
             self.metrics.histogram("serve_batch_size").observe(len(active))
             self.metrics.gauge("serve_slot_occupancy").set(
                 len(self._active()) / self.max_slots)
-        return logits
+        return logits, (routed[0] if routed else None)
 
     def _finish_decoded(self, active: List[int], next_tok) -> None:
         """Append sampled tokens and retire finished slots — EOS and
@@ -553,11 +586,13 @@ class ServeEngine:
         active = self._ensure_kv(self._active())
         if not active:
             return False
-        logits = self._decode_step(active)
-        self._notify({"kind": "decode", "tokens": 1,
-                      "uids": [self.slots[i].request.uid for i in active],
-                      "positions": [int(self.slots[i].pos) for i in active]})
-        next_tok = self._sample_decoded(logits)
+        logits, routed = self._decode_step(active)
+        event = {"kind": "decode", "tokens": 1,
+                 "uids": [self.slots[i].request.uid for i in active],
+                 "positions": [int(self.slots[i].pos) for i in active]}
+        self._notify(event)
+        next_tok, routed = self._sample_decoded(logits, routed)
+        self._count_routed(event, active, routed)
         self._finish_decoded(active, next_tok)
         return True
 
@@ -661,14 +696,17 @@ class ServeEngine:
                                   if self.slots[i].staging is None])
         if not chunks and not active:
             return False
-        logits = self._decode_step(active) if active else None
-        self._notify({
+        if active:
+            logits, routed = self._decode_step(active)
+        event = {
             "kind": "step", "chunks": chunks, "tokens": 1,
             "uids": [self.slots[i].request.uid for i in active],
             "positions": [int(self.slots[i].pos) for i in active],
-        })
+        }
+        self._notify(event)
         if active:
-            next_tok = self._sample_decoded(logits)
+            next_tok, routed = self._sample_decoded(logits, routed)
+            self._count_routed(event, active, routed)
             self._finish_decoded(active, next_tok)
         return True
 
@@ -678,6 +716,14 @@ class ServeEngine:
             self.step()
             steps += 1
         return self.finished
+
+
+@jax.jit
+def _with_routed(tok, routed):
+    """Sampled tokens [B] and each slot's held-expert choices [B, L, E] as
+    one int32 array [B, 1 + L * E], so that one copy brings both."""
+    flat = routed.reshape(routed.shape[0], -1).astype(jnp.int32)
+    return jnp.concatenate([tok[:, None], flat], axis=1)
 
 
 def _write_slot(cache, single_cache, slot: int):

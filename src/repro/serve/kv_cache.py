@@ -56,9 +56,13 @@ class CacheBudget:
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
-    """Dense/moe/vlm: 2 * kv_heads * head_dim * layers * dtype."""
+    """Dense/moe/vlm: 2 * kv_heads * head_dim * layers * dtype; latent
+    attention: (kv_lora_rank + qk_rope_head_dim) * layers * dtype."""
     if cfg.family in ("ssm",):
         return 0   # O(1) state
+    if cfg.kv_lora_rank:
+        return ((cfg.kv_lora_rank + cfg.qk_rope_head_dim) * cfg.layers
+                * dtype_bytes)
     layers = cfg.layers
     if cfg.family == "hybrid":
         import math
