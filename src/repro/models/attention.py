@@ -284,14 +284,52 @@ def _sdpa(q, k, v, *, causal: bool, q_offset=None, kv_len: Optional[int] = None)
     return out.reshape(b, s, h, v.shape[-1]).astype(q.dtype)
 
 
+def write_rows(leaf, rows, pos, layer=None):
+    """Write ``rows`` [B, s, H, w] into a cache leaf [B, H, S_max, w] at
+    sequence positions [pos, pos + s) and return the updated leaf.
+
+    ``pos`` is one scalar for every slot (a lockstep batch, a prefill
+    chunk: one ``dynamic_update_slice``) or one position per slot [B]
+    (continuous batching: one scatter of unique [w] rows, one per slot,
+    head and position; a row out of range is dropped).  Scattering whole
+    [H, w] windows instead made the TPU compiler hold the cache in another
+    layout and copy all of it in and out of every step.  With ``layer``,
+    ``leaf`` is the stacked [L, B, H, S_max, w] cache and the rows land in
+    that layer, so a cache carried through the layer scan is updated in
+    place."""
+    lead = () if layer is None else (layer,)
+    if jnp.ndim(pos) == 0:
+        block = rows.transpose(0, 2, 1, 3)
+        block = block.reshape((1,) * len(lead) + block.shape)
+        return jax.lax.dynamic_update_slice(leaf, block,
+                                            lead + (0, 0, pos, 0))
+    b, s, h = rows.shape[:3]
+    at = (pos[:, None] + jnp.arange(s))[:, None, :]           # [B, 1, s]
+    slots = jnp.arange(b)[:, None, None]
+    heads = jnp.arange(h)[None, :, None]
+    return leaf.at[lead + (slots, heads, at)].set(
+        rows.transpose(0, 2, 1, 3), unique_indices=True, mode="drop")
+
+
+def layer_lanes(leaf, layer=None):
+    """Layer ``layer``'s [B, H, S_max, w] lanes of a stacked cache leaf;
+    without ``layer``, the leaf itself."""
+    if layer is None:
+        return leaf
+    return jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
+
 def attention(
     p: dict, cfg, x: jnp.ndarray, *, positions: jnp.ndarray,
-    cache: Optional[KVCache] = None, cache_pos=None,
+    cache: Optional[KVCache] = None, cache_pos=None, layer=None,
 ) -> tuple:
     """Full attention sub-layer.
 
-    Training/prefill: ``cache=None`` (or a cache to fill at [0, S)).
-    Decode: x is [B, 1, d]; ``cache_pos`` scalar write index.
+    Training: ``cache=None``.  Prefill: a cache to fill at [0, S).
+    Decode or prefill chunk: ``cache_pos``, the write position, a scalar
+    (lockstep batch, chunk) or per slot [B] (continuous batching); the
+    step's K/V rows are written there and the step attends the cache.
+    ``layer`` indexes a stacked cache (see :func:`write_rows`).
     Returns (out [B, S, d], new_cache).
     """
     b, s, _ = x.shape
@@ -304,49 +342,26 @@ def attention(
     v = constrain(v, "batch", None, "kv_heads", None)
 
     new_cache = None
-    if cache is not None and cache_pos is not None:
-        # decode: append this step's K/V, attend over the full cache.
-        # cache_pos may be a scalar (lockstep batch) or a per-slot [B]
-        # vector (continuous batching).
-        if jnp.ndim(cache_pos) == 1:
-            upd = jax.vmap(
-                lambda ck, kk, p: jax.lax.dynamic_update_slice(
-                    ck, kk, (0, p, 0)
-                )
-            )
-            kc = upd(cache.k, k.transpose(0, 2, 1, 3), cache_pos)
-            vc = upd(cache.v, v.transpose(0, 2, 1, 3), cache_pos)
-            kv_len = (cache_pos + 1)[:, None, None, None, None]
-        else:
-            kc = jax.lax.dynamic_update_slice(
-                cache.k, k.transpose(0, 2, 1, 3), (0, 0, cache_pos, 0)
-            )
-            vc = jax.lax.dynamic_update_slice(
-                cache.v, v.transpose(0, 2, 1, 3), (0, 0, cache_pos, 0)
-            )
-            kv_len = cache_pos + 1
-        new_cache = KVCache(kc, vc)
-        kt = kc.transpose(0, 2, 1, 3)     # [B, S_max, Hkv, hd]
-        vt = vc.transpose(0, 2, 1, 3)
+    if cache is not None:
+        at = 0 if cache_pos is None else cache_pos
+        new_cache = KVCache(write_rows(cache.k, k, at, layer),
+                            write_rows(cache.v, v, at, layer))
+    if cache_pos is None or cache is None:
+        # training, or a prefill attending the prompt itself
+        out = _sdpa(q, k, v, causal=cfg.causal)
+    else:
+        kt = layer_lanes(new_cache.k, layer).transpose(0, 2, 1, 3)
+        vt = layer_lanes(new_cache.v, layer).transpose(0, 2, 1, 3)
         if s > 1 and jnp.ndim(cache_pos) == 0:
             # prefill chunk staged at [cache_pos, cache_pos + s): causal
             # masking against global positions — earlier chunks already
             # sit in the cache below cache_pos, later rows mask out.
             out = _sdpa(q, kt, vt, causal=True, q_offset=cache_pos)
         else:
+            kv_len = cache_pos + 1
+            if jnp.ndim(cache_pos) == 1:
+                kv_len = kv_len[:, None, None, None, None]
             out = _sdpa(q, kt, vt, causal=False, kv_len=kv_len)
-    elif cache is not None:
-        # prefill: fill cache [0, S), causal attention over the prompt
-        kc = jax.lax.dynamic_update_slice(
-            cache.k, k.transpose(0, 2, 1, 3), (0, 0, 0, 0)
-        )
-        vc = jax.lax.dynamic_update_slice(
-            cache.v, v.transpose(0, 2, 1, 3), (0, 0, 0, 0)
-        )
-        new_cache = KVCache(kc, vc)
-        out = _sdpa(q, k, v, causal=cfg.causal)
-    else:
-        out = _sdpa(q, k, v, causal=cfg.causal)
 
     out = out.reshape(b, s, cfg.n_heads * cfg.head_dim_)
     return dense(out, p["wo"], quantize=quant), new_cache
@@ -376,16 +391,6 @@ def init_mla_params(key, cfg, dtype) -> dict:
         "wkv_b": dense_init(ks[2], r, h * (nope + vd), dtype),
         "wo": dense_init(ks[3], h * vd, d, dtype),
     }
-
-
-def _write_positions(cache_leaf, new, cache_pos):
-    """Write ``new`` [B, 1, s, w] into a [B, 1, S_max, w] cache leaf at
-    ``cache_pos`` (a scalar, or one position per slot)."""
-    if jnp.ndim(cache_pos) == 1:
-        return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
-            c, n, (0, p, 0)))(cache_leaf, new, cache_pos)
-    return jax.lax.dynamic_update_slice(cache_leaf, new,
-                                        (0, 0, cache_pos, 0))
 
 
 def _mla_expanded(p, cfg, q_nope, q_pe, c, k_pe, quant):
@@ -427,7 +432,7 @@ def _mla_absorbed(p, cfg, q_nope, q_pe, c_all, pe_all, positions, quant):
 
 def mla_attention(
     p: dict, cfg, x: jnp.ndarray, *, positions: jnp.ndarray,
-    cache: Optional[KVCache] = None, cache_pos=None,
+    cache: Optional[KVCache] = None, cache_pos=None, layer=None,
 ) -> tuple:
     """Multi-head latent attention sub-layer (DeepSeek-V3, ``q_lora_rank``
     null: a plain ``q_proj``).
@@ -443,7 +448,8 @@ def mla_attention(
     attention over the cache (a decode step, a prefill chunk) absorbs
     ``kv_b_proj`` into the query and the output and reads the latent.
     ``kv_b_proj``'s BitLinear quantizes ``c`` per position, so the two
-    agree up to summation order.  Returns (out [B, s, d], new_cache).
+    agree up to summation order.  ``cache``, ``cache_pos`` and ``layer``
+    as :func:`attention` takes them.  Returns (out [B, s, d], new_cache).
     """
     b, s, _ = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
@@ -458,18 +464,18 @@ def mla_attention(
         q_nope, q_pe = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
         k_pe = apply_rope(kv[..., None, r:], cos, sin)[:, :, 0]  # [B, s, e]
         new_cache = None
-        if cache is None:
-            out = _mla_expanded(p, cfg, q_nope, q_pe, c, k_pe, quant)
-        elif cache_pos is None:
-            # whole-prompt prefill: fill [0, s), attend the prompt itself
-            new_cache = KVCache(_write_positions(cache.k, c[:, None], 0),
-                                _write_positions(cache.v, k_pe[:, None], 0))
+        if cache is not None:
+            at = 0 if cache_pos is None else cache_pos
+            new_cache = KVCache(write_rows(cache.k, c[:, :, None], at, layer),
+                                write_rows(cache.v, k_pe[:, :, None], at,
+                                           layer))
+        if cache_pos is None or cache is None:
+            # training, or a whole-prompt prefill attending the prompt
             out = _mla_expanded(p, cfg, q_nope, q_pe, c, k_pe, quant)
         else:
-            new_cache = KVCache(
-                _write_positions(cache.k, c[:, None], cache_pos),
-                _write_positions(cache.v, k_pe[:, None], cache_pos))
-            out = _mla_absorbed(p, cfg, q_nope, q_pe, new_cache.k[:, 0],
-                                new_cache.v[:, 0], positions, quant)
+            out = _mla_absorbed(p, cfg, q_nope, q_pe,
+                                layer_lanes(new_cache.k, layer)[:, 0],
+                                layer_lanes(new_cache.v, layer)[:, 0],
+                                positions, quant)
         out = out.reshape(b, s, h * cfg.v_head_dim)
         return dense(out, p["wo"], quantize=quant), new_cache

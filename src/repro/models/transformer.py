@@ -100,14 +100,15 @@ def _stacks(params) -> list:
 # Blocks
 # --------------------------------------------------------------------------- #
 
-def _block(cfg, p, x, positions, cache=None, cache_pos=None):
+def _block(cfg, p, x, positions, cache=None, cache_pos=None, layer=None):
     """One layer: (x, new_cache, routed) — ``routed`` [B, S, held] marks
-    the held experts each token chose (None without held experts)."""
+    the held experts each token chose (None without held experts).
+    ``layer`` indexes a stacked cache (``attention.write_rows``)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     attn = mla_attention if cfg.kv_lora_rank else attention
     attn_out, new_cache = attn(
         p["attn"], cfg, h, positions=positions, cache=cache,
-        cache_pos=cache_pos,
+        cache_pos=cache_pos, layer=layer,
     )
     x = x + attn_out
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -123,7 +124,8 @@ def _block(cfg, p, x, positions, cache=None, cache_pos=None):
 
 
 def _scan_blocks(cfg, blocks, x, positions, caches=None, cache_pos=None):
-    """lax.scan over stacked layer params (and stacked KV caches).
+    """lax.scan over stacked layer params (and a stacked KV cache, written
+    at ``cache_pos``, or from 0 without one).
     Returns (x, new caches, routed [L, B, S, held] or None)."""
     layers = jax.tree.leaves(blocks)[0].shape[0]
 
@@ -153,17 +155,19 @@ def _scan_blocks(cfg, blocks, x, positions, caches=None, cache_pos=None):
             x, _ = jax.lax.scan(maybe_remat(body, cfg), x, blocks)
         return x, None, None
 
+    # the stacked cache rides in the carry: each layer writes its new rows
+    # into it in place (a prefill from 0, a decode or chunk at cache_pos)
+    # and reads its own lanes from it, so no new cache is stacked up
     def body(carry, xs):
-        layer_p, kc, vc = xs
-        y, new_cache, routed = _block(
-            cfg, layer_p, carry, positions, cache=KVCache(kc, vc),
-            cache_pos=cache_pos,
-        )
-        return y, (new_cache.k, new_cache.v, routed)
+        y, cache = carry
+        layer_p, layer = xs
+        y, cache, routed = _block(cfg, layer_p, y, positions, cache=cache,
+                                  cache_pos=cache_pos, layer=layer)
+        return (y, cache), routed
 
-    x, (ks, vs, routed) = jax.lax.scan(body, x,
-                                       (blocks, caches.k, caches.v))
-    return x, KVCache(ks, vs), routed
+    (x, caches), routed = jax.lax.scan(body, (x, caches),
+                                       (blocks, jnp.arange(layers)))
+    return x, caches, routed
 
 
 def _scan_stacks(cfg, params, x, positions, cache, cache_pos=None):

@@ -11,6 +11,7 @@ attention).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -216,19 +217,22 @@ class ServeEngine:
             metrics.gauge("serve_ternary_projection_leaves").set(
                 self.ternary_projection_leaves)
         # named functions, so the compiled modules are jit_serve_decode and
-        # jit_serve_prefill_chunk in a profiler trace
+        # jit_serve_prefill_chunk in a profiler trace.  Each donates the
+        # cache it is given, which its successor then updates in place:
+        # nothing else may hold a cache passed to one of them.
         def serve_decode(params, tok, cache, pos):
             return api.decode(params, tok, cache, pos)
 
         def serve_prefill_chunk(params, toks, cache, pos0):
             return api.prefill_chunk(params, toks, cache, pos0)
 
-        self._decode = jax.jit(serve_decode)
+        self._decode = jax.jit(serve_decode, donate_argnums=(2,))
         self._prefill_chunk = None
         if prefill_chunk_tokens is not None:
             # jit caches by chunk shape; the fixed budget bounds the set of
             # chunk lengths (budget + the per-prompt remainders)
-            self._prefill_chunk = jax.jit(serve_prefill_chunk)
+            self._prefill_chunk = jax.jit(serve_prefill_chunk,
+                                          donate_argnums=(2,))
 
     # ------------------------------------------------------------------ #
     def submit(self, prompt: np.ndarray, *, max_new_tokens: int = 16,
@@ -458,13 +462,7 @@ class ServeEngine:
                         tok = self._sample(logits[:, -1])
                         finished = self._first_token(req, int(tok[0]), plen)
                     if not finished:
-                        if self.paged_kv is not None:
-                            self.cache = self.paged_kv.write_slot(
-                                self.cache, single_cache, i, uid=req.uid,
-                                tokens=plen)
-                        else:
-                            self.cache = _write_slot(self.cache, single_cache,
-                                                     i)
+                        self._land(i, single_cache, req.uid, plen)
                         slot.request = req
                         slot.pos = plen
                     else:
@@ -474,6 +472,32 @@ class ServeEngine:
                                   "tokens": plen, "done": finished})
                     if not finished:
                         break          # slot taken; move to the next free one
+
+    def _land(self, i: int, lane, uid: int, tokens: int) -> None:
+        """Write a prefilled 1-lane cache into lane ``i`` of the batch
+        cache, in place (through the page reservation in paged mode)."""
+        cache = self.cache
+        if self.paged_kv is not None:
+            self.cache = self.paged_kv.write_slot(cache, lane, i, uid=uid,
+                                                  tokens=tokens)
+        else:
+            self.cache = _write_slot(cache, lane, i)
+        self._count_in_place(cache, batch=True)
+
+    def _count_in_place(self, donated, *, batch: bool = False) -> None:
+        """Count a call that consumed ``donated`` in place: once the call is
+        dispatched every leaf is deleted, its buffer now the result's (a
+        leaf the program could not alias stays alive).  ``batch`` marks the
+        engine's batch cache, whose bytes the gauge holds.  No sync."""
+        if self.metrics is None:
+            return
+        leaves = jax.tree.leaves(donated)
+        if not all(leaf.is_deleted() for leaf in leaves):
+            return
+        self.metrics.counter("serve_cache_inplace_calls").inc()
+        if batch:
+            self.metrics.gauge("serve_cache_inplace_bytes").set(
+                sum(leaf.nbytes for leaf in leaves))
 
     def _sample(self, logits: jnp.ndarray) -> jnp.ndarray:
         if self.greedy:
@@ -545,9 +569,10 @@ class ServeEngine:
                 slot = self.slots[i]
                 tokens[i] = slot.request.output[-1]
                 pos[i] = slot.pos
+            cache = self.cache
             logits, self.cache, *routed = self._decode(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(pos))
+                self.params, jnp.asarray(tokens), cache, jnp.asarray(pos))
+            self._count_in_place(cache, batch=True)
         self.decode_batch_sizes.append(len(active))
         self.step_log.append({"phase": "decode", "tokens": len(active),
                               "slots": len(self._active())})
@@ -646,8 +671,10 @@ class ServeEngine:
             c = min(budget, plen - slot.filled)
             pos0 = slot.filled
             toks = jnp.asarray(fill[None, pos0:pos0 + c])
+            staging = slot.staging
             logits, slot.staging = self._prefill_chunk(
-                self.params, toks, slot.staging, pos0)
+                self.params, toks, staging, pos0)
+            self._count_in_place(staging)
             slot.filled += c
             self.step_log.append({"phase": "prefill_chunk", "uid": req.uid,
                                   "tokens": c,
@@ -671,13 +698,7 @@ class ServeEngine:
                     slot.request = None
                 else:
                     # decode-ready: land the staged lane in the batch cache
-                    if self.paged_kv is not None:
-                        self.cache = self.paged_kv.write_slot(
-                            self.cache, slot.staging, i, uid=req.uid,
-                            tokens=plen)
-                    else:
-                        self.cache = _write_slot(self.cache, slot.staging,
-                                                 i)
+                    self._land(i, slot.staging, req.uid, plen)
                     slot.pos = plen
                 slot.staging = None
                 slot.filled = 0
@@ -726,8 +747,11 @@ def _with_routed(tok, routed):
     return jnp.concatenate([tok[:, None], flat], axis=1)
 
 
-def _write_slot(cache, single_cache, slot: int):
-    """Copy a 1-lane prefilled cache into lane ``slot`` of the engine cache.
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_slot(cache, single_cache, slot):
+    """Copy a 1-lane prefilled cache into lane ``slot`` of the engine cache,
+    which is donated and updated in place; ``slot`` is traced, so one
+    program serves every lane.
 
     Works for KVCache / SSMCache / HybridCache: every leaf's batch axis is
     the second dim for stacked [L, B, ...] leaves.

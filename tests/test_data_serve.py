@@ -191,21 +191,28 @@ def test_served_codes_match_per_step_fake_quant(dtype, tol):
         assert toks == [int(t) for t in np.argmax(lg_old, axis=-1)]
 
 
-def _weight_quant_ops(jaxpr, shapes):
-    """abs / reductions / rounding whose operand has a weight's shape,
-    through every nested jaxpr (the jit, the scan over layers)."""
-    found = []
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, through every nested jaxpr (the jit,
+    the scan over layers)."""
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name in ("abs", "round") or name.startswith("reduce"):
-            found += [name for v in eqn.invars
-                      if tuple(getattr(v.aval, "shape", ())) in shapes]
+        yield eqn
         for sub in eqn.params.values():
             for j in (sub if isinstance(sub, (list, tuple)) else [sub]):
                 inner = getattr(j, "jaxpr", j)
                 if hasattr(inner, "eqns"):
-                    found += _weight_quant_ops(inner, shapes)
-    return found
+                    yield from _eqns(inner)
+
+
+def _shape(v):
+    return tuple(getattr(v.aval, "shape", ()))
+
+
+def _weight_quant_ops(jaxpr, shapes):
+    """abs / reductions / rounding whose operand has a weight's shape."""
+    return [eqn.primitive.name for eqn in _eqns(jaxpr)
+            if eqn.primitive.name in ("abs", "round")
+            or eqn.primitive.name.startswith("reduce")
+            for v in eqn.invars if _shape(v) in shapes]
 
 
 def test_decode_does_not_quantize_weights():
@@ -225,6 +232,40 @@ def test_decode_does_not_quantize_weights():
     assert _weight_quant_ops(prog.jaxpr, shapes) == []
     old = jax.make_jaxpr(api.decode)(params, *args)
     assert "abs" in _weight_quant_ops(old.jaxpr, shapes)
+
+
+@pytest.mark.parametrize("arch", ["bitnet-1.58b-kv", "moonlight-16b-a3b"])
+def test_decode_writes_rows_into_the_carried_cache(arch):
+    """The decode's layer scan carries the stacked cache and writes each
+    step's rows into it: the only writes on a cache-shaped operand are
+    scatters of unique one-row windows into the whole stack, none into one
+    layer's lanes (the per-slot write a vmapped ``dynamic_update_slice``
+    made, which left the compiler slicing out and restacking every lane),
+    and the cache is no scan's per-layer input or output."""
+    cfg = reduced(get_config(arch))
+    api = build_model(cfg)
+    eng = ServeEngine(api, api.init(jax.random.PRNGKey(0)), max_slots=2,
+                      max_seq=64)
+    stacks = {tuple(leaf.shape) for leaf in jax.tree.leaves(eng.cache)}
+    lanes = {s[1:] for s in stacks}
+    slots = jnp.zeros((2,), jnp.int32)
+    prog = jax.make_jaxpr(eng._decode)(eng.params, slots, eng.cache, slots)
+    writes = [e for e in _eqns(prog.jaxpr)
+              if e.primitive.name in ("scatter", "dynamic_update_slice")
+              and _shape(e.invars[0]) in stacks | lanes]
+    assert len(writes) == 2 * (1 + bool(cfg.first_k_dense))
+    for e in writes:
+        assert e.primitive.name == "scatter"
+        assert _shape(e.invars[0]) in stacks
+        assert e.params["unique_indices"]
+        assert len(e.params["dimension_numbers"].update_window_dims) == 1
+    scans = [e for e in _eqns(prog.jaxpr) if e.primitive.name == "scan"]
+    assert scans
+    for e in scans:
+        first_x = e.params["num_consts"] + e.params["num_carry"]
+        assert not {_shape(v) for v in e.invars[first_x:]} & stacks
+        ys = e.outvars[e.params["num_carry"]:]
+        assert not {_shape(v) for v in ys} & stacks
 
 
 @pytest.mark.parametrize("arch,quantization,leaves", [

@@ -358,6 +358,8 @@ def test_engine_counts_match_a_recount():
 
     def spy(*args):
         out = decode(*args)
+        # the donating decode consumes the cache it is given
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(args[2]))
         seen.append(np.asarray(out[2]))
         return out
 
