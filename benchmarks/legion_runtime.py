@@ -107,10 +107,10 @@ def _serve_path():
     # step executions cache by (rows, contexts) — time the cold execution
     context = 16
     t0 = time.perf_counter()
-    tally = backend.step_tally(1, (context,))
+    tally = backend.step_tally(decode_contexts=(context,))
     us = (time.perf_counter() - t0) * 1e6
     traffic_vals, cycle_vals = backend.cross_validate(
-        m=1, contexts=(context,), rtol=0.05)
+        decode_contexts=(context,), rtol=0.05)
     assert {v.stage for v in traffic_vals} >= {"attn_score", "attn_output"}
     for v in traffic_vals + cycle_vals:
         assert v.ok, f"serve decode: {v}"

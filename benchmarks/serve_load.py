@@ -102,7 +102,7 @@ def run():
     # (4 slots) costs this many overlapped cycles, so a mean interarrival
     # of ~1.25 steps keeps utilization high without unbounded queueing
     _, step_cycles = backend.step_pipeline(
-        MAX_SLOTS, tuple([8] * MAX_SLOTS))
+        decode_contexts=tuple([8] * MAX_SLOTS))
     trace = poisson_trace(
         POISSON_REQUESTS, mean_interarrival_cycles=1.25 * step_cycles,
         seed=0)
@@ -196,7 +196,7 @@ def run():
     bsum = backend.summary()
     assert bsum["page_fetch_bytes"] > 0   # pages really were priced
     assert 0 <= bsum["page_waste_frac"] < 1
-    tvals, cvals = backend.cross_validate(m=1, contexts=(MAX_SEQ,))
+    tvals, cvals = backend.cross_validate(decode_contexts=(MAX_SEQ,))
     xval = max([e for v in tvals for e in v.errors.values()]
                + [v.rel_err for v in cvals])
     rows.append(emit("serve_load/lognormal_120_paged", us, {
@@ -231,7 +231,7 @@ def run():
     assert st["preempted"] > 0, st        # the tight pool must evict
     assert 0 <= st["goodput"] <= st["completed"], st
     bsum = backend.summary()
-    tvals, cvals = backend.cross_validate(m=1, contexts=(MAX_SEQ,))
+    tvals, cvals = backend.cross_validate(decode_contexts=(MAX_SEQ,))
     xval = max([e for v in tvals for e in v.errors.values()]
                + [v.rel_err for v in cvals])
     rows.append(emit("serve_load/lognormal_120_paged_tight", us, {

@@ -79,12 +79,12 @@ def run():
 
     # ---- merged two-slot decode batch: xval + overlap ------------------- #
     contexts = (9, 17)
-    tvals, cvals = backend.cross_validate(m=len(contexts),
-                                          contexts=contexts, rtol=0.05)
+    tvals, cvals = backend.cross_validate(decode_contexts=contexts,
+                                          rtol=0.05)
     worst = max([e for v in tvals for e in v.errors.values()]
                 + [v.rel_err for v in cvals])
     assert worst <= 0.05, f"merged batch xval err {worst:.4f}"
-    serial, overlapped = backend.step_pipeline(len(contexts), contexts)
+    serial, overlapped = backend.step_pipeline(decode_contexts=contexts)
     assert overlapped <= serial, (serial, overlapped)
     assert overlapped < serial, "independent slots should overlap"
     rows.append(emit(
@@ -99,7 +99,8 @@ def run():
 
     # ---- multi-layer program: explicit cross-layer deps ----------------- #
     machine = Machine(accel, backend=PipelinedExecutor())
-    two_layer = backend.step_program(2, contexts, explicit_layers=2)
+    two_layer = backend.step_program(decode_contexts=contexts,
+                                     explicit_layers=2)
     t0 = time.perf_counter()
     rep = machine.run(two_layer)
     us2 = (time.perf_counter() - t0) * 1e6

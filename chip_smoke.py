@@ -204,7 +204,7 @@ def serve_phase(clock: CompileClock, cfg, seed: int, *, max_seq: int = 2048,
         eng.run_until_done()
         run_s = time.perf_counter() - t0
         steps = backend.prefill_steps + backend.decode_steps
-        tv, cv = backend.cross_validate(m=1, contexts=(16,))
+        tv, cv = backend.cross_validate(decode_contexts=(16,))
         worst = max([e for v in tv for e in v.errors.values()]
                     + [v.rel_err for v in cv])
         if not all(v.ok for v in tv + cv):

@@ -134,8 +134,9 @@ print("6. Serve-path Legion backend — one decode step through the Machine")
 from repro.serve.legion_backend import LegionServeBackend
 
 backend = LegionServeBackend(cfg_leg, cfg, params)   # SS4's served weights
-tally = backend.step_tally(1, (16,))   # one decode token at context 16
-tvals, cvals = backend.cross_validate(m=1, contexts=(16,))
+# one decode token at context 16
+tally = backend.step_tally(decode_contexts=(16,))
+tvals, cvals = backend.cross_validate(decode_contexts=(16,))
 assert all(v.ok for v in tvals + cvals)
 print(f"   {tally.gemms} GEMMs lowered to one Program and executed: "
       f"wq/wk/wv/wo + w1/w2/w3 projections AND the act-to-act attention "
@@ -210,7 +211,7 @@ print("=" * 70)
 print("9. Serve pipelining — multi-layer programs + merged decode batches")
 # Two EXPLICIT transformer layers: layer 1's QKV streams layer 0's MLP
 # output through a real cross-layer dependency (no `layers` scalar).
-two_layer = backend.step_program(1, (16,), explicit_layers=2)
+two_layer = backend.step_program(decode_contexts=(16,), explicit_layers=2)
 rep9 = piped.run(two_layer)
 assert rep9.ok
 print(f"   two-layer step program: {len(two_layer)} stages, "
@@ -219,14 +220,14 @@ print(f"   two-layer step program: {len(two_layer)} stages, "
 
 # One decode step's merged batch graph: two slots at different contexts,
 # per-slot attention interleaved as an antichain under shared projections.
-merged = backend.step_program(2, (12, 20))
+merged = backend.step_program(decode_contexts=(12, 20))
 pp9 = piped.run(merged).pipeline
 print(f"   merged 2-slot decode batch: serial={pp9.serial_cycles} -> "
       f"overlapped={pp9.overlapped_cycles} cycles "
       f"({pp9.speedup:.3f}x — slots hide each other's fill/pipeline)")
 
 # Engine view: the overlapped per-token cycles feed the KV-cache budget.
-serial9, overlapped9 = backend.step_pipeline(2, (12, 20))
+serial9, overlapped9 = backend.step_pipeline(decode_contexts=(12, 20))
 from repro.serve.kv_cache import plan as kv_plan
 budget = kv_plan(cfg, batch=2, max_seq=64, hbm_bytes_per_chip=16e9,
                  chips=1, cycles_per_token=overlapped9 / 2,

@@ -96,7 +96,7 @@ def main():
             print(f"  req {uid}: prefill[{t.prefill_tokens}] + "
                   f"decode[{t.decode_tokens}] -> {t.cycles} cycles, "
                   f"{t.mem_bytes / 1e3:.1f} KB moved")
-        tv, cv = backend.cross_validate(m=1, contexts=(16,))
+        tv, cv = backend.cross_validate(decode_contexts=(16,))
         worst = max([e for v in tv for e in v.errors.values()]
                     + [v.rel_err for v in cv])
         assert all(v.ok for v in tv + cv)

@@ -9,15 +9,16 @@ D-Legion (analytic simulator, orchestrator plans, Pallas kernels):
             program's dependency levels)
 - program:  `Program` stage graphs — named GEMM nodes with explicit data
             dependencies and operand sources (streamed act / stationary
-            weight / stationary act for K-V), attention + serve-step
-            lowering builders, the overlapped-round pipeline model, and a
-            pure-NumPy reference execution
-- lowering: the workload zoo's unified `lower(spec)` front door — the
-            `LoweringSpec` dataclass family covering attention, the
-            serve-step graphs, MoE expert-skip (`lower_moe`), the
-            Mamba-2 SSD scan (`lower_ssd`), and the Zamba2-style hybrid
-            (`lower_hybrid`), plus `zoo_spec` mapping any registry
-            ModelConfig to its family's spec
+            weight / stationary act for K-V), the int8 transforms, the
+            overlapped-round pipeline model, and a pure-NumPy reference
+            execution
+- lowering: every lowering builder behind one `lower(spec)` front door —
+            the `LoweringSpec` dataclass family covering attention
+            (`lower_attention`), the engine step (`ServeStepSpec`: prefill
+            chunks beside a batched decode), MoE expert-skip
+            (`lower_moe`), the Mamba-2 SSD scan (`lower_ssd`), and the
+            Zamba2-style hybrid (`lower_hybrid`), plus `zoo_spec` mapping
+            any registry ModelConfig to its family's spec
 - runtime:  plan coverage validation, operand synthesis
 - modes:    adaptive-precision mode selection (W1.58 / W4 / W8, +ZTB)
 - trace:    NoC-dedup traffic measurement + simulate() cross-validation
@@ -40,10 +41,9 @@ from repro.legion.lowering import (
     LoweringSpec,
     MoESpec,
     SSDSpec,
-    ServeBatchSpec,
-    ServeMixedSpec,
     ServeStepSpec,
     lower,
+    lower_attention,
     lower_hybrid,
     lower_moe,
     lower_ssd,
@@ -74,10 +74,6 @@ from repro.legion.program import (
     ProgramStage,
     Ref,
     compute_pipeline,
-    lower_attention,
-    lower_serve_batch,
-    lower_serve_mixed,
-    lower_serve_step,
     reference_outputs,
     requantize_int8,
     softmax_int8,
@@ -128,8 +124,6 @@ __all__ = [
     "Ref",
     "RunReport",
     "SSDSpec",
-    "ServeBatchSpec",
-    "ServeMixedSpec",
     "ServeStepSpec",
     "ShardedExecutor",
     "StageValidation",
@@ -145,9 +139,6 @@ __all__ = [
     "lower_attention",
     "lower_hybrid",
     "lower_moe",
-    "lower_serve_batch",
-    "lower_serve_mixed",
-    "lower_serve_step",
     "lower_ssd",
     "merge_round_criticals",
     "moe_stage_names",

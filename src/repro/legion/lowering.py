@@ -1,15 +1,26 @@
-"""The workload zoo's unified lowering front door — ``legion.lower(spec)``.
+"""Every Legion lowering builder, behind one front door: ``lower(spec)``.
 
-Every lowering the repo knows — the paper's BitNet attention block, the
-serve-step/batch/mixed graphs, and the two zoo additions below — now
-dispatches through one entry point on a :class:`LoweringSpec` dataclass
-family.  Specs validate at *construction* (``__post_init__``), so a bad
-combination (attention with page tables, zero experts, a chosen set wider
-than top-k) raises before any lowering work starts; the legacy
-``lower_attention`` / ``lower_serve_*`` call-site signatures remain as
-thin documented aliases in :mod:`repro.legion.program`.
+A :class:`LoweringSpec` dataclass names one block of work; ``lower``
+hands it to its family's builder and returns the validated
+:class:`~repro.legion.program.Program`.  Specs validate at
+*construction* (``__post_init__``), so a bad combination (attention with
+page tables, zero experts, a chosen set wider than top-k, a page table
+of the wrong length) raises before any lowering work starts, and the
+builders below check nothing twice.
 
-Zoo additions:
+* :func:`lower_attention` (:class:`AttentionLoweringSpec`) — the paper's
+  attention block: QKV -> act-to-act score (KV multicast across GQA
+  groups) -> softmax -> act-to-act output -> O-projection.  It keeps its
+  own ``AttentionSpec`` signature for the callers that lower a plain
+  workload spec.
+
+* :class:`ServeStepSpec` — one engine step: the step's projections AND
+  its act-to-act attention, each slot's KV-cache matrices as stationary
+  activation operands with position-dependent K/N.  A step without
+  prefill chunks is one graph (optionally spanning explicit transformer
+  layers); a step with chunks merges each chunk's graph and the batched
+  decode's with ``{p<i>}`` / ``{d}`` name tags — the in-flight batching
+  shape.
 
 * :func:`lower_moe` — a token-choice MoE FFN block (router + ``E``
   experts' SwiGLU pairs) where the router's top-k decision becomes
@@ -44,19 +55,30 @@ Zoo additions:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Sequence, Tuple
+import math
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.workloads import (
+    ATTN_OUTPUT,
+    ATTN_SCORE,
+    K_PROJ,
     MLP_DOWN,
     MLP_UP,
+    OUT_PROJ,
+    Q_PROJ,
+    QKV_PROJ,
     ROUTER,
     SSD_INTER,
     SSD_INTRA,
     SSD_SCORE,
     SSD_STATE,
+    V_PROJ,
     AttentionSpec,
+    GEMMWorkload,
+    attention_workloads,
+    decode_attention_workloads,
     moe_ffn_workloads,
     ssd_chunk_workloads,
 )
@@ -65,11 +87,8 @@ from repro.legion.program import (
     Program,
     ProgramStage,
     Ref,
-    lower_attention,
-    lower_serve_batch,
-    lower_serve_mixed,
-    lower_serve_step,
     requantize_int8,
+    softmax_int8,
     swiglu_int8,
 )
 
@@ -122,7 +141,7 @@ class LoweringSpec:
                 and not self._PAGED:
             raise ValueError(
                 f"{type(self).__name__} has no paged stationary operands; "
-                f"page_tokens/page_tables apply to the serve specs only"
+                f"page_tokens/page_tables apply to ServeStepSpec only"
             )
         if self.page_tables is not None and not self.page_tokens:
             raise ValueError(
@@ -140,10 +159,10 @@ class LoweringSpec:
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class AttentionLoweringSpec(LoweringSpec):
-    """One prefill attention block (:func:`lower_attention` front door).
+    """One prefill attention block, lowered by :func:`lower_attention`.
 
-    ``split_qkv`` is the normalized home of the old keyword flag: three
-    independent q/k/v projection stages instead of the fused qkv stage.
+    ``split_qkv``: three independent q/k/v projection stages instead of
+    the fused qkv stage.
     """
 
     heads: int = 0
@@ -178,39 +197,36 @@ class AttentionLoweringSpec(LoweringSpec):
         )
 
 
-def _check_serve_attention(spec: "LoweringSpec", contexts: Tuple[int, ...],
-                           m: int) -> None:
-    """Shared construction-time checks for the serve spec family."""
-    if contexts:
-        if not (spec.heads and spec.kv_heads and spec.head_dim):
-            raise ValueError(
-                f"{type(spec).__name__}: attention lowering needs "
-                f"heads/kv_heads/head_dim"
-            )
-        if spec.heads % spec.kv_heads:
-            raise ValueError(
-                f"{type(spec).__name__}: heads={spec.heads} not divisible "
-                f"by kv_heads={spec.kv_heads}"
-            )
-        if m % len(contexts):
-            raise ValueError(
-                f"{type(spec).__name__}: {m} step rows cannot split over "
-                f"{len(contexts)} slots"
-            )
-
-
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class ServeStepSpec(LoweringSpec):
-    """One serving step (:func:`lower_serve_step` front door).
+    """One engine step: in-flight prefill chunks beside a batched decode.
 
-    ``explicit_layers`` and ``operands`` are the normalized homes of the
-    old keyword flags; ``projections`` are the serve backend's
-    ``(workload, weights)`` ProjectionOp records.  The spec's own
-    ``weight_bits``/``seed`` fields: precision rides the projection
-    workloads; ``seed`` seeds the synthesized KV caches.
+    ``chunks`` gives each prefill chunk's ``(rows, context)``: ``rows``
+    prompt tokens written this step, attending ``context`` cache entries
+    (the chunk's start offset plus its rows).  A whole-prompt prefill is
+    the one chunk ``(tokens, tokens)``.  The decode part is ``m`` rows
+    split evenly over the slots of ``contexts`` (each slot's KV context
+    length), or ``m`` rows of projections alone when ``contexts`` is
+    empty; ``m=0`` leaves it out.  ``projections`` are the serve
+    backend's ``(workload, weights)`` ProjectionOp records, whose
+    template ``m`` each part replaces with its own rows; ``seed`` seeds
+    the synthesized activations and KV caches.
+
+    With ``heads=0`` the step has no attention: each part lowers to its
+    projections alone (decode ``contexts`` then need heads and raise).
+
+    ``explicit_layers`` spans a step without chunks over that many
+    *explicit* transformer layers; ``operands=False`` builds the
+    schedulable skeleton (no synthesized arrays, data edges as ``after``
+    control deps).  ``page_tokens`` prices each slot's stationary K/V
+    page-granularly; ``page_tables`` pins the decode slots' physical page
+    ids and ``chunk_page_tables`` one table per chunk, each exactly
+    ``ceil(context / page_tokens)`` pages long (the allocator and the
+    lowered graph must agree on how many pages back each context).
     """
 
     projections: Sequence[Any] = ()
+    chunks: Sequence[Tuple[int, int]] = ()
     m: int = 0
     contexts: Sequence[int] = ()
     heads: int = 0
@@ -218,93 +234,113 @@ class ServeStepSpec(LoweringSpec):
     head_dim: int = 0
     explicit_layers: int = 1
     operands: bool = True
-
-    _PAGED = True
-
-    def __post_init__(self) -> None:
-        super().__post_init__()
-        if not self.projections:
-            raise ValueError("ServeStepSpec needs projection ops")
-        if self.m < 1:
-            raise ValueError(f"ServeStepSpec: m must be >= 1, got {self.m}")
-        if self.explicit_layers < 1:
-            raise ValueError(
-                f"ServeStepSpec: explicit_layers must be >= 1, got "
-                f"{self.explicit_layers}"
-            )
-        _check_serve_attention(self, tuple(self.contexts), self.m)
-
-
-@dataclasses.dataclass(frozen=True, kw_only=True)
-class ServeBatchSpec(LoweringSpec):
-    """One decode step's merged batch graph (:func:`lower_serve_batch`)."""
-
-    projections: Sequence[Any] = ()
-    contexts: Sequence[int] = ()
-    heads: int = 0
-    kv_heads: int = 0
-    head_dim: int = 0
-    rows_per_slot: int = 1
-    explicit_layers: int = 1
-
-    _PAGED = True
-
-    def __post_init__(self) -> None:
-        super().__post_init__()
-        if not self.projections:
-            raise ValueError("ServeBatchSpec needs projection ops")
-        if not self.contexts:
-            raise ValueError("ServeBatchSpec needs at least one slot "
-                             "context")
-        if self.rows_per_slot < 1:
-            raise ValueError(
-                f"ServeBatchSpec: rows_per_slot must be >= 1, got "
-                f"{self.rows_per_slot}"
-            )
-        _check_serve_attention(
-            self, tuple(self.contexts),
-            len(self.contexts) * self.rows_per_slot,
-        )
-
-
-@dataclasses.dataclass(frozen=True, kw_only=True)
-class ServeMixedSpec(LoweringSpec):
-    """One mixed-phase engine step (:func:`lower_serve_mixed`)."""
-
-    projections: Sequence[Any] = ()
-    chunks: Sequence[Tuple[int, int]] = ()
-    decode_contexts: Sequence[int] = ()
-    heads: int = 0
-    kv_heads: int = 0
-    head_dim: int = 0
-    operands: bool = True
     chunk_page_tables: Optional[Sequence[Sequence[int]]] = None
-    decode_page_tables: Optional[Sequence[Sequence[int]]] = None
 
     _PAGED = True
 
     def __post_init__(self) -> None:
         super().__post_init__()
-        if not self.projections:
-            raise ValueError("ServeMixedSpec needs projection ops")
-        if not self.chunks and not self.decode_contexts:
+        chunks = tuple((int(r), int(t)) for r, t in self.chunks)
+        contexts = tuple(int(t) for t in self.contexts)
+        object.__setattr__(self, "chunks", chunks)
+        object.__setattr__(self, "contexts", contexts)
+        stages = {op.workload.stage for op in self.projections}
+        if not stages:
+            raise ValueError("ServeStepSpec needs projection ops")
+        if self.m < 0 or not (chunks or self.m):
             raise ValueError(
-                "ServeMixedSpec needs at least one prefill chunk or decode "
-                "slot"
+                "ServeStepSpec needs at least one prefill chunk, or "
+                "m >= 1 decode rows over its slot contexts"
             )
-        for rows, t in self.chunks:
+        if contexts and (not self.m or self.m % len(contexts)):
+            raise ValueError(
+                f"ServeStepSpec: {self.m} step rows cannot split over "
+                f"{len(contexts)} slots"
+            )
+        for rows, t in chunks:
             if rows < 1 or t < rows:
                 raise ValueError(
-                    f"ServeMixedSpec: chunk ({rows}, {t}) needs rows >= 1 "
-                    f"and context >= rows"
+                    f"ServeStepSpec: chunk ({rows}, {t}) needs rows >= 1 "
+                    f"and context >= rows (a chunk attends its own rows)"
                 )
-        if (self.chunk_page_tables is not None
-                or self.decode_page_tables is not None) \
-                and not self.page_tokens:
+        if contexts or (chunks and self.heads):
+            if not (self.heads and self.kv_heads and self.head_dim):
+                raise ValueError(
+                    "ServeStepSpec: attention lowering needs "
+                    "heads/kv_heads/head_dim"
+                )
+            if self.heads % self.kv_heads:
+                raise ValueError(
+                    f"ServeStepSpec: heads={self.heads} not divisible by "
+                    f"kv_heads={self.kv_heads}"
+                )
+            if QKV_PROJ not in stages:
+                raise ValueError(
+                    "ServeStepSpec: attention lowering threads Q rows out "
+                    "of a qkv_proj projection; none among the given ops"
+                )
+        self._check_layers(stages)
+        self._check_page_tables(self.page_tables, contexts, "slot")
+        self._check_page_tables(
+            self.chunk_page_tables, tuple(t for _r, t in chunks), "chunk")
+
+    def _check_layers(self, stages) -> None:
+        n = self.explicit_layers
+        if n < 1:
             raise ValueError(
-                "ServeMixedSpec: per-phase page tables given without "
-                "page_tokens"
+                f"ServeStepSpec: explicit_layers must be >= 1, got {n}"
             )
+        if n == 1:
+            return
+        if self.chunks:
+            raise ValueError(
+                "ServeStepSpec: explicit_layers > 1 spans a step without "
+                "prefill chunks"
+            )
+        if MLP_DOWN not in stages and OUT_PROJ not in stages:
+            raise ValueError(
+                "explicit_layers > 1 chains layer l+1's qkv off layer l's "
+                "mlp_down (or out_proj) output; neither among the given ops"
+            )
+        if QKV_PROJ not in stages:
+            raise ValueError(
+                "explicit_layers > 1 needs a qkv_proj op to stream the "
+                "previous layer's output into"
+            )
+        if self.layers % n:
+            raise ValueError(
+                f"{self.layers} attention layers cannot split into {n} "
+                f"explicit layers"
+            )
+        for op in self.projections:
+            if op.workload.layers % n:
+                raise ValueError(
+                    f"{op.workload.stage}: {op.workload.layers} model "
+                    f"layers cannot split into {n} explicit layers"
+                )
+
+    def _check_page_tables(self, tables, contexts, part: str) -> None:
+        """One table per ``part`` (slot or chunk), each exactly as many
+        pages as its context needs."""
+        if tables is None:
+            return
+        if not self.page_tokens:
+            raise ValueError(
+                f"ServeStepSpec: {part} page tables given without "
+                f"page_tokens"
+            )
+        if len(tables) != len(contexts):
+            raise ValueError(
+                f"{len(tables)} {part} page tables for {len(contexts)} "
+                f"{part}s"
+            )
+        for j, (tab, t) in enumerate(zip(tables, contexts)):
+            need = -(-t // self.page_tokens)
+            if len(tab) != need:
+                raise ValueError(
+                    f"{part} {j}: page table has {len(tab)} pages, context "
+                    f"{t} at {self.page_tokens} tokens/page needs {need}"
+                )
 
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
@@ -442,6 +478,336 @@ class HybridSpec(LoweringSpec):
             raise ValueError(
                 "HybridSpec needs both an attention and an ssd sub-spec"
             )
+
+
+# --------------------------------------------------------------------------- #
+# Attention lowering
+# --------------------------------------------------------------------------- #
+
+def _grouped(arr: np.ndarray, heads: int, group_size: int) -> np.ndarray:
+    """Replicate per-KV-head matrices across their GQA group: instance i
+    (query head) uses group i // group_size — the data behind the KV
+    multicast (the tracer fetches each group's tiles once)."""
+    return arr[np.arange(heads) // max(group_size, 1)]
+
+
+def lower_attention(
+    spec: AttentionSpec,
+    *,
+    x: Optional[np.ndarray] = None,
+    seed: int = 0,
+    split_qkv: bool = False,
+) -> Program:
+    """Lower a full attention block to a Program: QKV projection(s) ->
+    act-to-act scores (Q @ K^T, KV multicast across GQA groups) ->
+    softmax -> act-to-act output (A @ V) -> output projection.
+
+    With ``split_qkv`` the projections become three independent stages
+    (q_proj / k_proj / v_proj) sharing the streamed input — V is not
+    needed until attn_output, so the graph's first level is a real
+    antichain and a pipelining executor has rounds to overlap.  The
+    default keeps the paper's fused qkv_proj stage, making the graph a
+    pure chain; even there the attn_output and out_proj boundaries hide
+    their fill as cross-level weight prefetch (their stationary operands
+    — V written back at qkv time, the O-weights — exist before the
+    streamed input does), while qkv -> attn_score hides nothing (its
+    stationary K IS qkv's output).
+    """
+    h, g, hd, s = spec.heads, spec.kv_heads, spec.head_dim, spec.seq_len
+    gs = spec.group_size
+    if h % max(g, 1):
+        raise ValueError(f"heads={h} not divisible by kv_heads={g}")
+    rng = np.random.default_rng(seed)
+    if x is None:
+        x = rng.integers(-8, 9, size=(s, spec.hidden)).astype(np.int8)
+    lo, hi = {2: (-1, 2), 4: (-8, 8)}.get(spec.weight_bits, (-8, 9))
+    wqkv = rng.integers(lo, hi, size=(h + 2 * g, spec.hidden, hd)) \
+        .astype(np.int8)
+    wo = rng.integers(lo, hi, size=(1, h * hd, spec.hidden)).astype(np.int8)
+    wl = attention_workloads(spec)   # [qkv, score, output, out_proj]
+
+    # int8 Q times int8 K^T: map raw scores into softmax's active range
+    score_scale = 1.0 / (127.0 * 127.0 * math.sqrt(hd))
+
+    def k_transposed(out: np.ndarray) -> np.ndarray:
+        """KV-head K outputs -> per-query-head stationary [h, hd, s]."""
+        kq = requantize_int8(out)
+        return _grouped(np.transpose(kq, (0, 2, 1)), h, gs)
+
+    def v_grouped(out: np.ndarray) -> np.ndarray:
+        return _grouped(requantize_int8(out), h, gs)
+
+    def concat_heads(out: np.ndarray) -> np.ndarray:
+        """[h, s, hd] -> requantized [s, h*hd] rows for the O-projection."""
+        return requantize_int8(
+            np.transpose(out, (1, 0, 2)).reshape(out.shape[1], h * hd)
+        )
+
+    prog = Program()
+    if split_qkv:
+        per = dict(m=s, k=spec.hidden, weight_bits=spec.weight_bits,
+                   shared_input=True, mapping=wl[0].mapping,
+                   layers=spec.layers)
+        prog.add(ProgramStage(
+            name=Q_PROJ, x=x, w=wqkv[:h],
+            workload=GEMMWorkload(stage=Q_PROJ, n=hd, count=h, **per),
+        ))
+        prog.add(ProgramStage(
+            name=K_PROJ, x=x, w=wqkv[h:h + g],
+            workload=GEMMWorkload(stage=K_PROJ, n=hd, count=g, **per),
+        ))
+        prog.add(ProgramStage(
+            name=V_PROJ, x=x, w=wqkv[h + g:],
+            workload=GEMMWorkload(stage=V_PROJ, n=hd, count=g, **per),
+        ))
+        q_src, k_src, v_src = Q_PROJ, K_PROJ, V_PROJ
+        q_of = requantize_int8
+        k_of, v_of = k_transposed, v_grouped
+    else:
+        prog.add(ProgramStage(name=QKV_PROJ, workload=wl[0], x=x, w=wqkv))
+        q_src = k_src = v_src = QKV_PROJ
+
+        def q_of(out):
+            return requantize_int8(out[:h])
+
+        def k_of(out):
+            return k_transposed(out[h:h + g])
+
+        def v_of(out):
+            return v_grouped(out[h + g:])
+
+    prog.add(ProgramStage(
+        name=ATTN_SCORE, workload=wl[1],
+        x=Ref(q_src, q_of), w=Ref(k_src, k_of), w_source=STATIONARY_ACT,
+    ))
+    prog.add(ProgramStage(
+        name=ATTN_OUTPUT, workload=wl[2],
+        x=Ref(ATTN_SCORE, lambda o: softmax_int8(o, scale=score_scale)),
+        w=Ref(v_src, v_of), w_source=STATIONARY_ACT,
+    ))
+    prog.add(ProgramStage(
+        name=OUT_PROJ, workload=wl[3],
+        x=Ref(ATTN_OUTPUT, concat_heads), w=wo,
+    ))
+    prog.validate()
+    return prog
+
+
+def _lower_attention_block(spec: AttentionLoweringSpec) -> Program:
+    return spec._finish(lower_attention(
+        spec.attention_spec(), x=spec.x, seed=spec.seed,
+        split_qkv=spec.split_qkv,
+    ))
+
+
+# --------------------------------------------------------------------------- #
+# Serve-step lowering
+# --------------------------------------------------------------------------- #
+
+def _next_layer_rows(out: np.ndarray) -> np.ndarray:
+    """The cross-layer link: layer ``l``'s final ``[1, m, d_model]``
+    output requantized into the int8 rows layer ``l+1``'s QKV streams."""
+    return requantize_int8(out[0])
+
+
+def _lower_step_layer(
+    spec: ServeStepSpec,
+    prog: Program,
+    by_stage: Dict[str, Any],
+    *,
+    m: int,
+    contexts: Tuple[int, ...],
+    layer: int,
+    x_link: Optional[str],
+) -> str:
+    """Add one explicit transformer layer of one step part to ``prog``.
+
+    Stage names carry the layer tag ``@l`` (none for layer 0); per-slot
+    attention subgraphs are built standalone and folded in via
+    :meth:`Program.merge`, hanging off the shared (batched) projection
+    stages.  ``x_link`` names the previous layer's final stage — its
+    requantized output rows stream into this layer's QKV (the explicit
+    cross-layer dependency).  Without ``spec.operands`` the graph is a
+    *skeleton*: no arrays are synthesized and every data edge becomes an
+    ``after`` control dependency — same names, workloads, levels, and
+    ancestry, but only schedulable, not executable (the serve backend's
+    per-step overlap computation needs nothing more).  Returns the name
+    of the layer's final stage (the next layer's link target).
+    """
+    heads, kv_heads, head_dim = spec.heads, spec.kv_heads, spec.head_dim
+    operands, seed = spec.operands, spec.seed
+    ltag = "" if layer == 0 else f"@{layer}"
+    rows = m // len(contexts) if contexts else m
+    gs = max(heads // max(kv_heads, 1), 1)
+    rng = np.random.default_rng(seed if layer == 0 else (seed, layer)) \
+        if operands else None
+
+    def synth_x(k: int) -> Optional[np.ndarray]:
+        if not operands:
+            return None
+        return rng.integers(-8, 9, size=(m, k)).astype(np.int8)
+
+    def sized(op) -> GEMMWorkload:
+        return dataclasses.replace(
+            op.workload, m=m,
+            layers=op.workload.layers // spec.explicit_layers,
+        )
+
+    def stage(name, workload, x, w, deps, **kw) -> ProgramStage:
+        """Concrete stage, or its skeleton twin (deps as ``after``)."""
+        if operands:
+            return ProgramStage(name=name, workload=workload, x=x, w=w,
+                                **kw)
+        return ProgramStage(name=name, workload=workload,
+                            after=tuple(deps), **kw)
+
+    qkv = by_stage.get(QKV_PROJ)
+    qkv_name = QKV_PROJ + ltag
+    if qkv is not None:
+        prog.add(stage(
+            qkv_name, sized(qkv),
+            (synth_x(qkv.workload.k) if x_link is None
+             else Ref(x_link, _next_layer_rows)),
+            qkv.weights,
+            (x_link,) if x_link is not None else (),
+        ))
+
+    out_names: List[str] = []
+    score_scale = 1.0 / (127.0 * 8.0 * math.sqrt(max(head_dim, 1)))
+    slot_progs: List[Program] = []
+    for j, t in enumerate(contexts):
+        # per-slot KV cache: one K/V matrix per KV head, synthetic int8
+        # (the engine's real cache lives inside the jitted graph)
+        if operands:
+            slot_rng = np.random.default_rng(
+                (seed, j, t) if layer == 0 else (seed, layer, j, t))
+            k_cache = slot_rng.integers(
+                -8, 9, size=(kv_heads, t, head_dim)).astype(np.int8)
+            v_cache = slot_rng.integers(
+                -8, 9, size=(kv_heads, t, head_dim)).astype(np.int8)
+        score_wl, out_wl = decode_attention_workloads(
+            heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            context=t, m=rows, layers=spec.layers // spec.explicit_layers,
+            page_tokens=spec.page_tokens,
+        )
+        lo_row, hi_row = j * rows, (j + 1) * rows
+
+        def q_rows(out: np.ndarray, lo=lo_row, hi=hi_row) -> np.ndarray:
+            return requantize_int8(out[:heads, lo:hi, :])
+
+        # standalone slot subgraph: bare stage names, external ref to the
+        # shared projection — Program.merge retags it into the batch graph
+        slot_progs.append(Program([
+            stage(
+                ATTN_SCORE, score_wl,
+                Ref(qkv_name, q_rows),
+                (_grouped(np.transpose(k_cache, (0, 2, 1)), heads, gs)
+                 if operands else None),
+                (qkv_name,), w_source=STATIONARY_ACT,
+            ),
+            stage(
+                ATTN_OUTPUT, out_wl,
+                Ref(ATTN_SCORE,
+                    lambda o, sc=score_scale: softmax_int8(o, scale=sc)),
+                _grouped(v_cache, heads, gs) if operands else None,
+                (ATTN_SCORE,), w_source=STATIONARY_ACT,
+            ),
+        ]))
+    if slot_progs:
+        tags = [ltag] if len(slot_progs) == 1 else \
+            [f"[{j}]{ltag}" for j in range(len(slot_progs))]
+        for st in Program.merge(slot_progs, tags=tags):
+            prog.add(st)
+        out_names = [ATTN_OUTPUT + tag for tag in tags]
+
+    def concat_slots(*outs: np.ndarray) -> np.ndarray:
+        rows_ = [np.transpose(o, (1, 0, 2)).reshape(o.shape[1],
+                                                    heads * head_dim)
+                 for o in outs]
+        return requantize_int8(np.concatenate(rows_, axis=0))
+
+    last = QKV_PROJ
+    o_proj = by_stage.get(OUT_PROJ)
+    if o_proj is not None:
+        prog.add(stage(
+            OUT_PROJ + ltag, sized(o_proj),
+            (Ref(tuple(out_names), concat_slots) if contexts
+             else synth_x(o_proj.workload.k)),
+            o_proj.weights,
+            tuple(out_names),
+        ))
+        last = OUT_PROJ
+
+    # SwiGLU MLP: up branches share the post-attention rows, down consumes
+    # the combined gate*value
+    mlp_up = by_stage.get(MLP_UP)
+    mlp_down = by_stage.get(MLP_DOWN)
+    if mlp_up is not None:
+        prog.add(stage(
+            MLP_UP + ltag, sized(mlp_up),
+            (Ref(OUT_PROJ + ltag, lambda o: requantize_int8(o[0]))
+             if o_proj is not None else synth_x(mlp_up.workload.k)),
+            mlp_up.weights,
+            (OUT_PROJ + ltag,) if o_proj is not None else (),
+        ))
+    if mlp_down is not None:
+        prog.add(stage(
+            MLP_DOWN + ltag, sized(mlp_down),
+            (Ref(MLP_UP + ltag, swiglu_int8) if mlp_up is not None
+             else synth_x(mlp_down.workload.k)),
+            mlp_down.weights,
+            (MLP_UP + ltag,) if mlp_up is not None else (),
+        ))
+        last = MLP_DOWN
+    return last + ltag
+
+
+def _lower_step_part(spec: ServeStepSpec, m: int,
+                     contexts: Tuple[int, ...]) -> Program:
+    """One group of ``m`` rows over its slot ``contexts`` (none: the
+    projections alone), spanning ``spec.explicit_layers`` layers, each
+    layer's QKV streaming the previous layer's final output."""
+    by_stage = {op.workload.stage: op for op in spec.projections}
+    prog = Program()
+    link: Optional[str] = None
+    for layer in range(spec.explicit_layers):
+        link = _lower_step_layer(spec, prog, by_stage, m=m,
+                                 contexts=contexts, layer=layer,
+                                 x_link=link)
+    return prog
+
+
+def _lower_engine_step(spec: ServeStepSpec) -> Program:
+    """Lower one engine step — projections AND attention — to a Program.
+
+    Per slot, the KV-cache matrices become *stationary activation*
+    operands with position-dependent K/N (score ``[rows, hd] @ [hd, t]``,
+    output ``[rows, t] @ [t, hd]``), shared across each GQA group.
+    Outputs thread through the graph: qkv -> score -> softmax -> output
+    -> O-proj -> SwiGLU mlp, so each part is one dependency graph.
+
+    A step without chunks is its decode part alone, with bare stage
+    names; explicit layers ``l >= 1`` add an ``@l`` suffix, and every
+    stage workload's ``layers`` multiplier divides by
+    ``explicit_layers``, so whole-model tallies are unchanged while the
+    graph exposes the layer structure to a
+    :class:`~repro.legion.machine.PipelinedExecutor`.  A step with
+    chunks lowers each chunk as one slot attending its context, merged
+    with the decode part via :meth:`Program.merge` under ``{p<i>}`` /
+    ``{d}`` tags — the TensorRT-LLM in-flight batching shape: context-
+    and generation-phase work share one scheduled graph, so
+    :func:`~repro.legion.program.compute_pipeline` overlaps chunk rounds
+    against decode rounds exactly as it does across decode slots.
+    """
+    if not spec.chunks:
+        return spec._finish(_lower_step_part(spec, spec.m, spec.contexts))
+    parts = [_lower_step_part(spec, rows, (t,) if spec.heads else ())
+             for rows, t in spec.chunks]
+    tags = [f"{{p{i}}}" for i in range(len(parts))]
+    if spec.m:
+        parts.append(_lower_step_part(spec, spec.m, spec.contexts))
+        tags.append("{d}")
+    return spec._finish(Program.merge(parts, tags=tags))
 
 
 # --------------------------------------------------------------------------- #
@@ -617,7 +983,7 @@ def lower_ssd(spec: SSDSpec) -> Program:
 
 
 # --------------------------------------------------------------------------- #
-# Hybrid lowering + the unified dispatcher
+# Hybrid lowering + the front door
 # --------------------------------------------------------------------------- #
 
 def lower_hybrid(spec: HybridSpec) -> Program:
@@ -638,54 +1004,24 @@ def lower_hybrid(spec: HybridSpec) -> Program:
     return spec._finish(prog)
 
 
+_BUILDERS = {
+    AttentionLoweringSpec: _lower_attention_block,
+    ServeStepSpec: _lower_engine_step,
+    MoESpec: lower_moe,
+    SSDSpec: lower_ssd,
+    HybridSpec: lower_hybrid,
+}
+
+
 def lower(spec: LoweringSpec) -> Program:
-    """THE lowering entry point: dispatch any :class:`LoweringSpec` to its
-    builder.  ``lower_attention`` / ``lower_serve_step`` /
-    ``lower_serve_batch`` / ``lower_serve_mixed`` / ``lower_moe`` /
-    ``lower_ssd`` remain as thin aliases for existing call sites."""
-    if isinstance(spec, AttentionLoweringSpec):
-        prog = lower_attention(spec.attention_spec(), x=spec.x,
-                               seed=spec.seed, split_qkv=spec.split_qkv)
-        return spec._finish(prog)
-    if isinstance(spec, ServeStepSpec):
-        prog = lower_serve_step(
-            spec.projections, m=spec.m, contexts=tuple(spec.contexts),
-            heads=spec.heads, kv_heads=spec.kv_heads,
-            head_dim=spec.head_dim, layers=spec.layers, seed=spec.seed,
-            explicit_layers=spec.explicit_layers, operands=spec.operands,
-            page_tokens=spec.page_tokens, page_tables=spec.page_tables,
+    """THE lowering entry point: hand any :class:`LoweringSpec` to its
+    family's builder, which returns the validated (and tagged) Program."""
+    builder = _BUILDERS.get(type(spec))
+    if builder is None:
+        raise TypeError(
+            f"lower() takes a LoweringSpec, got {type(spec).__name__}"
         )
-        return spec._finish(prog)
-    if isinstance(spec, ServeBatchSpec):
-        prog = lower_serve_batch(
-            spec.projections, contexts=tuple(spec.contexts),
-            heads=spec.heads, kv_heads=spec.kv_heads,
-            head_dim=spec.head_dim, layers=spec.layers,
-            rows_per_slot=spec.rows_per_slot, seed=spec.seed,
-            explicit_layers=spec.explicit_layers,
-            page_tokens=spec.page_tokens, page_tables=spec.page_tables,
-        )
-        return spec._finish(prog)
-    if isinstance(spec, ServeMixedSpec):
-        prog = lower_serve_mixed(
-            spec.projections, chunks=tuple(spec.chunks),
-            decode_contexts=tuple(spec.decode_contexts), heads=spec.heads,
-            kv_heads=spec.kv_heads, head_dim=spec.head_dim,
-            layers=spec.layers, seed=spec.seed, operands=spec.operands,
-            page_tokens=spec.page_tokens,
-            chunk_page_tables=spec.chunk_page_tables,
-            decode_page_tables=spec.decode_page_tables,
-        )
-        return spec._finish(prog)
-    if isinstance(spec, MoESpec):
-        return lower_moe(spec)
-    if isinstance(spec, SSDSpec):
-        return lower_ssd(spec)
-    if isinstance(spec, HybridSpec):
-        return lower_hybrid(spec)
-    raise TypeError(
-        f"lower() takes a LoweringSpec, got {type(spec).__name__}"
-    )
+    return builder(spec)
 
 
 def zoo_spec(cfg, *, seq_len: int = 64, tokens: int = 16, chunks: int = 2,

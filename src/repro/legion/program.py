@@ -21,17 +21,8 @@ This module makes the graph first-class:
   :meth:`repro.legion.machine.Machine.run`.  :meth:`Program.merge` folds
   *independent* programs into one batch graph (per-slot decode attention
   interleaved as an antichain — the continuous-batching shape vLLM-style
-  schedulers produce);
-
-* :func:`lower_attention` / :func:`lower_serve_step` /
-  :func:`lower_serve_batch` — lowering builders producing the paper's
-  attention block (QKV -> score -> softmax -> output -> O-proj), a full
-  serving step (projections AND attention, KV-cache matrices as per-slot
-  stationary operands with position-dependent K/N), and one decode step's
-  merged batch graph.  ``explicit_layers`` spans the program over several
-  *explicit* transformer layers — layer ``l+1``'s QKV streams layer
-  ``l``'s MLP output through a real cross-layer dependency instead of the
-  ``layers``-scalar shortcut;
+  schedulers produce).  The builders that lower attention blocks and
+  serving steps to Programs live in :mod:`repro.legion.lowering`;
 
 * :func:`compute_pipeline` — the overlapped-round timing model behind
   :class:`~repro.legion.machine.PipelinedExecutor`: rounds of
@@ -78,19 +69,7 @@ from repro.core.analytical import (
 )
 from repro.core.scheduler import StagePlan
 from repro.core.sparsity import ZeroTileBook
-from repro.core.workloads import (
-    ATTN_OUTPUT,
-    ATTN_SCORE,
-    K_PROJ,
-    OUT_PROJ,
-    Q_PROJ,
-    QKV_PROJ,
-    V_PROJ,
-    AttentionSpec,
-    GEMMWorkload,
-    attention_workloads,
-    decode_attention_workloads,
-)
+from repro.core.workloads import GEMMWorkload
 from repro.legion.latency import CycleBreakdown
 from repro.legion.modes import ModeSpec
 
@@ -746,535 +725,3 @@ class ProgramReport:
         if self.pipeline is not None:
             lines.append(f"  {self.pipeline}")
         return "\n".join(lines)
-
-
-# --------------------------------------------------------------------------- #
-# Lowering builders
-# --------------------------------------------------------------------------- #
-
-def _grouped(arr: np.ndarray, heads: int, group_size: int) -> np.ndarray:
-    """Replicate per-KV-head matrices across their GQA group: instance i
-    (query head) uses group i // group_size — the data behind the KV
-    multicast (the tracer fetches each group's tiles once)."""
-    return arr[np.arange(heads) // max(group_size, 1)]
-
-
-def lower_attention(
-    spec: AttentionSpec,
-    *,
-    x: Optional[np.ndarray] = None,
-    seed: int = 0,
-    split_qkv: bool = False,
-) -> Program:
-    """Lower a full attention block to a Program: QKV projection(s) ->
-    act-to-act scores (Q @ K^T, KV multicast across GQA groups) ->
-    softmax -> act-to-act output (A @ V) -> output projection.
-
-    With ``split_qkv`` the projections become three independent stages
-    (q_proj / k_proj / v_proj) sharing the streamed input — V is not
-    needed until attn_output, so the graph's first level is a real
-    antichain and a pipelining executor has rounds to overlap.  The
-    default keeps the paper's fused qkv_proj stage, making the graph a
-    pure chain; even there the attn_output and out_proj boundaries hide
-    their fill as cross-level weight prefetch (their stationary operands
-    — V written back at qkv time, the O-weights — exist before the
-    streamed input does), while qkv -> attn_score hides nothing (its
-    stationary K IS qkv's output).
-    """
-    h, g, hd, s = spec.heads, spec.kv_heads, spec.head_dim, spec.seq_len
-    gs = spec.group_size
-    if h % max(g, 1):
-        raise ValueError(f"heads={h} not divisible by kv_heads={g}")
-    rng = np.random.default_rng(seed)
-    if x is None:
-        x = rng.integers(-8, 9, size=(s, spec.hidden)).astype(np.int8)
-    lo, hi = {2: (-1, 2), 4: (-8, 8)}.get(spec.weight_bits, (-8, 9))
-    wqkv = rng.integers(lo, hi, size=(h + 2 * g, spec.hidden, hd)) \
-        .astype(np.int8)
-    wo = rng.integers(lo, hi, size=(1, h * hd, spec.hidden)).astype(np.int8)
-    wl = attention_workloads(spec)   # [qkv, score, output, out_proj]
-
-    # int8 Q times int8 K^T: map raw scores into softmax's active range
-    score_scale = 1.0 / (127.0 * 127.0 * math.sqrt(hd))
-
-    def k_transposed(out: np.ndarray) -> np.ndarray:
-        """KV-head K outputs -> per-query-head stationary [h, hd, s]."""
-        kq = requantize_int8(out)
-        return _grouped(np.transpose(kq, (0, 2, 1)), h, gs)
-
-    def v_grouped(out: np.ndarray) -> np.ndarray:
-        return _grouped(requantize_int8(out), h, gs)
-
-    def concat_heads(out: np.ndarray) -> np.ndarray:
-        """[h, s, hd] -> requantized [s, h*hd] rows for the O-projection."""
-        return requantize_int8(
-            np.transpose(out, (1, 0, 2)).reshape(out.shape[1], h * hd)
-        )
-
-    prog = Program()
-    if split_qkv:
-        per = dict(m=s, k=spec.hidden, weight_bits=spec.weight_bits,
-                   shared_input=True, mapping=wl[0].mapping,
-                   layers=spec.layers)
-        prog.add(ProgramStage(
-            name=Q_PROJ, x=x, w=wqkv[:h],
-            workload=GEMMWorkload(stage=Q_PROJ, n=hd, count=h, **per),
-        ))
-        prog.add(ProgramStage(
-            name=K_PROJ, x=x, w=wqkv[h:h + g],
-            workload=GEMMWorkload(stage=K_PROJ, n=hd, count=g, **per),
-        ))
-        prog.add(ProgramStage(
-            name=V_PROJ, x=x, w=wqkv[h + g:],
-            workload=GEMMWorkload(stage=V_PROJ, n=hd, count=g, **per),
-        ))
-        q_src, k_src, v_src = Q_PROJ, K_PROJ, V_PROJ
-        q_of = requantize_int8
-        k_of, v_of = k_transposed, v_grouped
-    else:
-        prog.add(ProgramStage(name=QKV_PROJ, workload=wl[0], x=x, w=wqkv))
-        q_src = k_src = v_src = QKV_PROJ
-
-        def q_of(out):
-            return requantize_int8(out[:h])
-
-        def k_of(out):
-            return k_transposed(out[h:h + g])
-
-        def v_of(out):
-            return v_grouped(out[h + g:])
-
-    prog.add(ProgramStage(
-        name=ATTN_SCORE, workload=wl[1],
-        x=Ref(q_src, q_of), w=Ref(k_src, k_of), w_source=STATIONARY_ACT,
-    ))
-    prog.add(ProgramStage(
-        name=ATTN_OUTPUT, workload=wl[2],
-        x=Ref(ATTN_SCORE, lambda o: softmax_int8(o, scale=score_scale)),
-        w=Ref(v_src, v_of), w_source=STATIONARY_ACT,
-    ))
-    prog.add(ProgramStage(
-        name=OUT_PROJ, workload=wl[3],
-        x=Ref(ATTN_OUTPUT, concat_heads), w=wo,
-    ))
-    prog.validate()
-    return prog
-
-
-def _next_layer_rows(out: np.ndarray) -> np.ndarray:
-    """The cross-layer link: layer ``l``'s final ``[1, m, d_model]``
-    output requantized into the int8 rows layer ``l+1``'s QKV streams."""
-    return requantize_int8(out[0])
-
-
-def _lower_step_layer(
-    by_stage: Dict[str, object],
-    *,
-    m: int,
-    contexts: Tuple[int, ...],
-    heads: int,
-    kv_heads: int,
-    head_dim: int,
-    attn_layers: int,
-    proj_layer_div: int,
-    seed: int,
-    layer: int,
-    ltag: str,
-    x_link: Optional[str],
-    operands: bool,
-    page_tokens: int = 0,
-) -> Tuple[Program, str]:
-    """One explicit transformer layer of a serve-step graph.
-
-    Stage names carry ``ltag`` (empty for layer 0); per-slot attention
-    subgraphs are built standalone and folded in via
-    :meth:`Program.merge`, hanging off the shared (batched) projection
-    stages.  ``x_link`` names the previous layer's final stage — its
-    requantized output rows stream into this layer's QKV (the explicit
-    cross-layer dependency).  With ``operands=False`` the graph is a
-    *skeleton*: no arrays are synthesized and every data edge becomes an
-    ``after`` control dependency — same names, workloads, levels, and
-    ancestry, but only schedulable, not executable (the serve backend's
-    per-step overlap computation needs nothing more).  Returns the layer
-    program and the bare name of its final stage (the next layer's link
-    target, before ``ltag``).
-    """
-    rows = m // len(contexts) if contexts else m
-    gs = max(heads // max(kv_heads, 1), 1)
-    rng = np.random.default_rng(seed if layer == 0 else (seed, layer)) \
-        if operands else None
-
-    def synth_x(k: int) -> Optional[np.ndarray]:
-        if not operands:
-            return None
-        return rng.integers(-8, 9, size=(m, k)).astype(np.int8)
-
-    def sized(op) -> GEMMWorkload:
-        return dataclasses.replace(
-            op.workload, m=m, layers=op.workload.layers // proj_layer_div,
-        )
-
-    def stage(name, workload, x, w, deps, **kw) -> ProgramStage:
-        """Concrete stage, or its skeleton twin (deps as ``after``)."""
-        if operands:
-            return ProgramStage(name=name, workload=workload, x=x, w=w,
-                                **kw)
-        return ProgramStage(name=name, workload=workload,
-                            after=tuple(deps), **kw)
-
-    prog = Program()
-    qkv = by_stage.get(QKV_PROJ)
-    attended = bool(contexts)
-    qkv_name = QKV_PROJ + ltag
-    if qkv is not None:
-        prog.add(stage(
-            qkv_name, sized(qkv),
-            (synth_x(qkv.workload.k) if x_link is None
-             else Ref(x_link, _next_layer_rows)),
-            qkv.weights,
-            (x_link,) if x_link is not None else (),
-        ))
-
-    if contexts and qkv is None:
-        raise ValueError(
-            "attention lowering threads Q rows out of a qkv_proj "
-            "projection; none among the given ops"
-        )
-    out_names: List[str] = []
-    score_scale = 1.0 / (127.0 * 8.0 * math.sqrt(max(head_dim, 1)))
-    slot_progs: List[Program] = []
-    for j, t in enumerate(contexts):
-        # per-slot KV cache: one K/V matrix per KV head, synthetic int8
-        # (the engine's real cache lives inside the jitted graph)
-        if operands:
-            slot_rng = np.random.default_rng(
-                (seed, j, t) if layer == 0 else (seed, layer, j, t))
-            k_cache = slot_rng.integers(
-                -8, 9, size=(kv_heads, t, head_dim)).astype(np.int8)
-            v_cache = slot_rng.integers(
-                -8, 9, size=(kv_heads, t, head_dim)).astype(np.int8)
-        score_wl, out_wl = decode_attention_workloads(
-            heads=heads, kv_heads=kv_heads, head_dim=head_dim,
-            context=t, m=rows, layers=attn_layers,
-            page_tokens=page_tokens,
-        )
-        lo_row, hi_row = j * rows, (j + 1) * rows
-
-        def q_rows(out: np.ndarray, lo=lo_row, hi=hi_row) -> np.ndarray:
-            return requantize_int8(out[:heads, lo:hi, :])
-
-        # standalone slot subgraph: bare stage names, external ref to the
-        # shared projection — Program.merge retags it into the batch graph
-        slot_progs.append(Program([
-            stage(
-                ATTN_SCORE, score_wl,
-                Ref(qkv_name, q_rows),
-                (_grouped(np.transpose(k_cache, (0, 2, 1)), heads, gs)
-                 if operands else None),
-                (qkv_name,), w_source=STATIONARY_ACT,
-            ),
-            stage(
-                ATTN_OUTPUT, out_wl,
-                Ref(ATTN_SCORE,
-                    lambda o, sc=score_scale: softmax_int8(o, scale=sc)),
-                _grouped(v_cache, heads, gs) if operands else None,
-                (ATTN_SCORE,), w_source=STATIONARY_ACT,
-            ),
-        ]))
-    if slot_progs:
-        single = len(slot_progs) == 1
-        tags = [ltag] if single else \
-            [f"[{j}]{ltag}" for j in range(len(slot_progs))]
-        for st in Program.merge(slot_progs, tags=tags):
-            prog.add(st)
-        out_names = [ATTN_OUTPUT + tag for tag in tags]
-
-    def concat_slots(*outs: np.ndarray) -> np.ndarray:
-        rows_ = [np.transpose(o, (1, 0, 2)).reshape(o.shape[1],
-                                                    heads * head_dim)
-                 for o in outs]
-        return requantize_int8(np.concatenate(rows_, axis=0))
-
-    last = QKV_PROJ
-    o_proj = by_stage.get(OUT_PROJ)
-    if o_proj is not None:
-        prog.add(stage(
-            OUT_PROJ + ltag, sized(o_proj),
-            (Ref(tuple(out_names), concat_slots) if attended
-             else synth_x(o_proj.workload.k)),
-            o_proj.weights,
-            tuple(out_names) if attended else (),
-        ))
-        last = OUT_PROJ
-
-    # SwiGLU MLP: up branches share the post-attention rows, down consumes
-    # the combined gate*value — serve-side stage names from legion_backend.
-    mlp_up = by_stage.get("mlp_up")
-    mlp_down = by_stage.get("mlp_down")
-    if mlp_up is not None:
-        prog.add(stage(
-            "mlp_up" + ltag, sized(mlp_up),
-            (Ref(OUT_PROJ + ltag, lambda o: requantize_int8(o[0]))
-             if o_proj is not None else synth_x(mlp_up.workload.k)),
-            mlp_up.weights,
-            (OUT_PROJ + ltag,) if o_proj is not None else (),
-        ))
-    if mlp_down is not None:
-        prog.add(stage(
-            "mlp_down" + ltag, sized(mlp_down),
-            (Ref("mlp_up" + ltag, swiglu_int8) if mlp_up is not None
-             else synth_x(mlp_down.workload.k)),
-            mlp_down.weights,
-            ("mlp_up" + ltag,) if mlp_up is not None else (),
-        ))
-        last = "mlp_down"
-    return prog, last
-
-
-def lower_serve_step(
-    projections: Sequence,
-    *,
-    m: int,
-    contexts: Sequence[int] = (),
-    heads: int = 0,
-    kv_heads: int = 0,
-    head_dim: int = 0,
-    layers: int = 1,
-    seed: int = 0,
-    explicit_layers: int = 1,
-    operands: bool = True,
-    page_tokens: int = 0,
-    page_tables: Optional[Sequence[Sequence[int]]] = None,
-) -> Program:
-    """Lower one serving step — projections AND attention — to a Program.
-
-    ``projections`` are ``(workload, weights)`` records (duck-typed
-    ``repro.serve.legion_backend.ProjectionOp``); their template ``m`` is
-    replaced with the step's row count.  ``contexts`` gives each slot's KV
-    context length: one entry per decode slot (``m`` slots x 1 row), or a
-    single entry ``(m,)`` for prefill (one slot x ``m`` rows).  Per slot,
-    the KV-cache matrices become *stationary activation* operands with
-    position-dependent K/N (score ``[rows, hd] @ [hd, t]``, output
-    ``[rows, t] @ [t, hd]``), shared across each GQA group.  Outputs
-    thread through the graph: qkv -> score -> softmax -> output ->
-    O-proj -> SwiGLU mlp, so the whole step is one dependency graph.
-
-    ``explicit_layers`` spans the program over that many *explicit*
-    transformer layers (stage names gain an ``@l`` suffix for layers
-    ``l >= 1``): layer ``l+1``'s QKV streams layer ``l``'s requantized
-    mlp_down (or out_proj) output through a real :class:`Ref` — the
-    cross-layer data dependency the ``layers``-scalar shortcut elides.
-    Every stage workload's ``layers`` multiplier divides by
-    ``explicit_layers`` (must divide evenly), so whole-model tallies are
-    unchanged while the graph exposes the layer structure to a
-    :class:`~repro.legion.machine.PipelinedExecutor`.
-
-    ``operands=False`` builds the *skeleton* graph only — identical
-    names, workloads, levels, and ancestry, but no synthesized arrays
-    (data edges become ``after`` control deps).  Schedulable (the serve
-    backend's per-decode-step overlap computation), not executable.
-
-    With ``page_tokens > 0`` each slot's stationary K/V operands are
-    annotated as block-allocated in ``page_tokens``-token pages: the
-    runtime fetches them page-granularly (per-page ``on_page_fetch``
-    events, last-page padding accounted as traffic waste) instead of as
-    idealized contiguous reads.  ``page_tables`` optionally pins the
-    engine's physical page ids — one table per slot, whose length must be
-    exactly ``ceil(context / page_tokens)`` (the allocator and the
-    lowered graph must agree on how many pages back each context).
-    """
-    by_stage = {op.workload.stage: op for op in projections}
-    contexts = tuple(int(t) for t in contexts)
-    if page_tokens < 0:
-        raise ValueError(f"page_tokens must be >= 0, got {page_tokens}")
-    if page_tables is not None:
-        if not page_tokens:
-            raise ValueError("page_tables given without page_tokens")
-        if len(page_tables) != len(contexts):
-            raise ValueError(
-                f"{len(page_tables)} page tables for {len(contexts)} slots"
-            )
-        for j, (tab, t) in enumerate(zip(page_tables, contexts)):
-            need = -(-int(t) // page_tokens)
-            if len(tab) != need:
-                raise ValueError(
-                    f"slot {j}: page table has {len(tab)} pages, context "
-                    f"{t} at {page_tokens} tokens/page needs {need}"
-                )
-    if explicit_layers < 1:
-        raise ValueError(
-            f"explicit_layers must be >= 1, got {explicit_layers}"
-        )
-    if explicit_layers > 1:
-        if "mlp_down" not in by_stage and OUT_PROJ not in by_stage:
-            raise ValueError(
-                "explicit_layers > 1 chains layer l+1's qkv off layer l's "
-                "mlp_down (or out_proj) output; neither among the given ops"
-            )
-        if QKV_PROJ not in by_stage:
-            raise ValueError(
-                "explicit_layers > 1 needs a qkv_proj op to stream the "
-                "previous layer's output into"
-            )
-        if layers % explicit_layers:
-            raise ValueError(
-                f"{layers} attention layers cannot split into "
-                f"{explicit_layers} explicit layers"
-            )
-        for op in projections:
-            if op.workload.layers % explicit_layers:
-                raise ValueError(
-                    f"{op.workload.stage}: {op.workload.layers} model "
-                    f"layers cannot split into {explicit_layers} explicit "
-                    f"layers"
-                )
-    if contexts:
-        if not (heads and kv_heads and head_dim):
-            raise ValueError(
-                "attention lowering needs heads/kv_heads/head_dim"
-            )
-        if m % len(contexts):
-            raise ValueError(
-                f"{m} step rows cannot split over {len(contexts)} slots"
-            )
-        if heads % kv_heads:
-            raise ValueError(
-                f"heads={heads} not divisible by kv_heads={kv_heads}"
-            )
-
-    prog = Program()
-    link: Optional[str] = None
-    for layer in range(explicit_layers):
-        ltag = "" if layer == 0 else f"@{layer}"
-        layer_prog, last = _lower_step_layer(
-            by_stage, m=m, contexts=contexts, heads=heads,
-            kv_heads=kv_heads, head_dim=head_dim,
-            attn_layers=layers // explicit_layers,
-            proj_layer_div=explicit_layers, seed=seed, layer=layer,
-            ltag=ltag, x_link=link, operands=operands,
-            page_tokens=page_tokens,
-        )
-        for st in layer_prog:
-            prog.add(st)
-        link = last + ltag
-    prog.validate()
-    return prog
-
-
-def lower_serve_batch(
-    projections: Sequence,
-    *,
-    contexts: Sequence[int],
-    heads: int,
-    kv_heads: int,
-    head_dim: int,
-    layers: int = 1,
-    rows_per_slot: int = 1,
-    seed: int = 0,
-    explicit_layers: int = 1,
-    page_tokens: int = 0,
-    page_tables: Optional[Sequence[Sequence[int]]] = None,
-) -> Program:
-    """One decode step's merged batch graph: every active slot's attention
-    program interleaved as an antichain under shared projection stages.
-
-    The continuous-batching shape: ``len(contexts)`` slots decode together
-    (``rows_per_slot`` rows each — 1 for decode), the projections run once
-    batched over all ``m = slots * rows_per_slot`` rows, and each slot's
-    score/output pair attends its own KV context — dependency-independent
-    of every other slot's, so a
-    :class:`~repro.legion.machine.PipelinedExecutor` hides fill/pipeline
-    ramps across slots.  Thin, named front door over
-    :func:`lower_serve_step` (which accepts the same shapes): this is the
-    builder :class:`~repro.serve.legion_backend.LegionServeBackend` uses
-    for its engine-view overlapped latency.
-    """
-    contexts = tuple(int(t) for t in contexts)
-    if not contexts:
-        raise ValueError("lower_serve_batch needs at least one slot context")
-    if rows_per_slot < 1:
-        raise ValueError(f"rows_per_slot must be >= 1, got {rows_per_slot}")
-    return lower_serve_step(
-        projections, m=len(contexts) * rows_per_slot, contexts=contexts,
-        heads=heads, kv_heads=kv_heads, head_dim=head_dim, layers=layers,
-        seed=seed, explicit_layers=explicit_layers,
-        page_tokens=page_tokens, page_tables=page_tables,
-    )
-
-
-def lower_serve_mixed(
-    projections: Sequence,
-    *,
-    chunks: Sequence[Tuple[int, int]],
-    decode_contexts: Sequence[int] = (),
-    heads: int,
-    kv_heads: int,
-    head_dim: int,
-    layers: int = 1,
-    seed: int = 0,
-    operands: bool = True,
-    page_tokens: int = 0,
-    chunk_page_tables: Optional[Sequence[Sequence[int]]] = None,
-    decode_page_tables: Optional[Sequence[Sequence[int]]] = None,
-) -> Program:
-    """One *mixed-phase* engine step: in-flight prefill chunks merged with
-    the batched decode slots into a single step graph.
-
-    ``chunks`` gives each active prefill chunk's ``(rows, context)`` shape
-    — ``rows`` prompt tokens written this step, attending ``context``
-    cache entries (the chunk's start offset plus its rows; earlier chunks
-    of the same prompt already sit in the cache).  ``decode_contexts`` is
-    the usual per-slot context tuple of the step's batched decode (empty
-    when every slot is still prefilling).  Each phase lowers through
-    :func:`lower_serve_step` and the parts merge via :meth:`Program.merge`
-    with ``{p<i>}`` / ``{d}`` name tags — the TensorRT-LLM in-flight
-    batching shape: context-phase and generation-phase work share one
-    scheduled graph, so :func:`compute_pipeline` overlaps chunk rounds
-    against decode rounds exactly as it does across decode slots.
-
-    ``operands=False`` builds the schedulable skeleton (the serve
-    backend's per-step hot path); with operands the merged graph is
-    executable and its measured traffic/cycles equal the sum of its
-    phase parts.
-    """
-    chunks = tuple((int(r), int(t)) for r, t in chunks)
-    decode_contexts = tuple(int(t) for t in decode_contexts)
-    if not chunks and not decode_contexts:
-        raise ValueError(
-            "lower_serve_mixed needs at least one prefill chunk or decode "
-            "slot"
-        )
-    for rows, t in chunks:
-        if rows < 1 or t < rows:
-            raise ValueError(
-                f"chunk ({rows}, {t}): need rows >= 1 and context >= rows "
-                f"(a chunk attends at least its own rows)"
-            )
-    if chunk_page_tables is not None and \
-            len(chunk_page_tables) != len(chunks):
-        raise ValueError(
-            f"{len(chunk_page_tables)} chunk page tables for "
-            f"{len(chunks)} chunks"
-        )
-    parts: List[Program] = []
-    tags: List[str] = []
-    for i, (rows, t) in enumerate(chunks):
-        parts.append(lower_serve_step(
-            projections, m=rows, contexts=(t,), heads=heads,
-            kv_heads=kv_heads, head_dim=head_dim, layers=layers,
-            seed=seed, operands=operands, page_tokens=page_tokens,
-            page_tables=(None if chunk_page_tables is None
-                         else (chunk_page_tables[i],)),
-        ))
-        tags.append(f"{{p{i}}}")
-    if decode_contexts:
-        parts.append(lower_serve_step(
-            projections, m=len(decode_contexts), contexts=decode_contexts,
-            heads=heads, kv_heads=kv_heads, head_dim=head_dim,
-            layers=layers, seed=seed, operands=operands,
-            page_tokens=page_tokens, page_tables=decode_page_tables,
-        ))
-        tags.append("{d}")
-    merged = Program.merge(parts, tags=tags)
-    merged.validate()
-    return merged

@@ -14,9 +14,9 @@ per-token latencies in model cycles (and microseconds at the
 accelerator's clock), with occupancy-over-time and rejected/deferred
 admission counts alongside.  In-flight engines
 (``prefill_chunk_tokens=``) emit one merged ``step`` event per engine
-step, priced by the merged mixed-phase Program's overlapped cycles
-(:meth:`~repro.serve.legion_backend.LegionServeBackend
-.step_pipeline_mixed`); window-truncated completions and
+step, priced by the overlapped cycles of the merged prefill-chunk +
+decode Program (the same :meth:`~repro.serve.legion_backend
+.LegionServeBackend.step_pipeline`); window-truncated completions and
 admission-refused requests surface in :meth:`LoadReport.summary` as
 ``truncated`` / ``refused`` (with ``goodput`` excluding truncations).
 
@@ -306,7 +306,7 @@ def run_load(
     def observe(event: dict) -> None:
         if event["kind"] == "prefill":
             tokens = event["tokens"]
-            cost = backend.step_tally(tokens, (tokens,)).cycles
+            cost = backend.step_tally(chunks=((tokens, tokens),)).cycles
             state["clock"] += cost
             rec = by_uid[event["uid"]]
             # a re-prefill after preemption costs clock like any prefill,
@@ -324,7 +324,8 @@ def run_load(
         elif event["kind"] == "decode":
             uids = event["uids"]
             contexts = tuple(sorted(p + 1 for p in event["positions"]))
-            _serial, overlapped = backend.step_pipeline(len(uids), contexts)
+            _serial, overlapped = backend.step_pipeline(
+                decode_contexts=contexts)
             state["clock"] += overlapped
             for uid in uids:
                 rec = by_uid[uid]
@@ -344,8 +345,7 @@ def run_load(
             contexts = tuple(sorted(p + 1 for p in event["positions"]))
             shapes = tuple((c["tokens"], c["pos0"] + c["tokens"])
                            for c in chunks)
-            _serial, overlapped = backend.step_pipeline_mixed(
-                shapes, decode_m=len(uids), decode_contexts=contexts)
+            _serial, overlapped = backend.step_pipeline(shapes, contexts)
             state["clock"] += overlapped
             for c in chunks:
                 if not c.get("last"):

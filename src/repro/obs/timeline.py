@@ -34,9 +34,9 @@ Byte counts in slice args are raw per-pass bytes (pre NoC-dedup — the
 
 Both parity guarantees hold for ANY program shape the scheduler accepts
 — including the in-flight serve path's *mixed-phase* steps
-(:meth:`~repro.serve.legion_backend.LegionServeBackend
-.step_program_mixed`: prefill-chunk subgraphs merged with a batched
-decode graph), whose serial/overlapped makespans the tracer reproduces
+(:meth:`~repro.serve.legion_backend.LegionServeBackend.step_program`
+with prefill chunks: chunk subgraphs merged with a batched decode
+graph), whose serial/overlapped makespans the tracer reproduces
 exactly like pure decode batches (pinned by
 ``tests/test_obs.py::test_mixed_step_program_trace_parity``).
 

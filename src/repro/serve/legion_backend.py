@@ -55,6 +55,7 @@ from repro.legion.latency import (
     CycleValidation,
     merge_round_criticals,
 )
+from repro.legion.lowering import ServeStepSpec, lower
 from repro.legion.machine import ExecutorBackend, Machine
 from repro.legion.program import (
     STATIONARY_ACT,
@@ -62,8 +63,6 @@ from repro.legion.program import (
     ProgramStage,
     Ref,
     compute_pipeline,
-    lower_serve_mixed,
-    lower_serve_step,
     softmax_int8,
 )
 from repro.legion.trace import StageValidation, TrafficTotals
@@ -74,9 +73,7 @@ from repro.quant.bitnet import TernaryWeight, ternary_weight
 # now live in core.workloads — MLP_UP / MLP_DOWN are imported above and
 # stay re-exported here for existing call sites.
 
-PREFILL = "prefill"
-DECODE = "decode"
-STEP = "step"            # in-flight: prefill chunks + decode, one event
+PREFILL = "prefill"      # the other engine events: "decode" and "step"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,6 +170,11 @@ class RequestTally:
         self.act_bytes += t.act_bytes
         self.psum_bytes += t.psum_bytes
         self.cycles += t.cycles
+
+
+def _round_key(w: GEMMWorkload) -> Tuple[str, int, int, int, int]:
+    """A node's rounds depend only on its workload's shape."""
+    return (w.stage, w.m, w.k, w.n, w.count)
 
 
 def _ternary_int8(w, layer: int) -> np.ndarray:
@@ -275,24 +277,30 @@ class LegionServeBackend:
       batch — that headroom is exactly the batching win, not
       double-counted hardware work.
 
-    A third, **engine view** rides on the batched one: every decode step's
-    merged batch graph (shared projections, per-slot attention antichain —
-    ``repro.legion.program.lower_serve_batch``'s shape) is scheduled
-    through the pipelined overlap model, composed from the cached
-    sub-program round criticals without re-executing anything.
-    ``summary()`` reports ``overlapped_cycles_per_step`` (<= the serial
-    sum, asserted) and the per-token overlapped cycles that
-    :meth:`cache_budget` feeds into ``serve.kv_cache.plan``.
+    A third, **engine view** rides on the batched one: every engine
+    step's merged graph (shared projections, per-slot attention
+    antichain, prefill chunks beside the decode — a
+    :class:`~repro.legion.lowering.ServeStepSpec`) is scheduled through
+    the pipelined overlap model, composed from the cached sub-program
+    round criticals without re-executing anything.  ``summary()``
+    reports ``overlapped_cycles_per_step`` (<= the serial sum, asserted)
+    and the per-token overlapped cycles that :meth:`cache_budget` feeds
+    into ``serve.kv_cache.plan``.
 
-    Step tallies are cached compositionally: the context-independent
+    Every pricing method (:meth:`workloads`, :meth:`step_program`,
+    :meth:`step_tally`, :meth:`step_pipeline`, :meth:`cross_validate`)
+    takes the step's shape: its prefill ``chunks`` as ``(rows,
+    context)`` and its ``decode_contexts``.  A decode-only step has no
+    chunks; a whole-prompt prefill is the one chunk ``(tokens, tokens)``.
+
+    Tallies are cached compositionally: the context-independent
     projection part by row count ``m``, the attention pair by
-    ``(rows, context)``, and the composed step by ``(m, contexts)`` —
-    byte/cycle identical to executing the step's single Program (fresh
-    per-stage instruments mean nothing dedups across stages), but a
-    decode stream whose context advances every token re-executes only
-    the two attention GEMMs, not the dominant projection/MLP stages.
-    :meth:`step_program` still lowers the whole step to one graph (for
-    the pipelined executor, or any caller wanting the full DAG).
+    ``(rows, context)``, and each part (one chunk, or the batched decode)
+    by ``(m, contexts)`` — byte/cycle identical to executing the step's
+    single Program (fresh per-stage instruments mean nothing dedups
+    across stages), but a decode stream whose context advances every
+    token re-executes only the two attention GEMMs, not the dominant
+    projection/MLP stages.
     """
 
     def __init__(
@@ -342,19 +350,18 @@ class LegionServeBackend:
         self.totals = StepTally(m=0)     # batch-accurate engine totals
         self.prefill_steps = 0
         self.decode_steps = 0
-        self._step_cache: Dict[Tuple[int, Tuple[int, ...]], StepTally] = {}
+        # by part: (m, contexts)
+        self._part_cache: Dict[Tuple[int, Tuple[int, ...]], StepTally] = {}
         self._proj_cache: Dict[int, StepTally] = {}          # by m
         self._attn_cache: Dict[Tuple[int, int], StepTally] = {}  # (rows, t)
         self._decode_cycles = 0          # standalone per-token accumulation
         self._decode_tokens = 0
         # Engine-view pipelining: per-node round criticals captured from the
         # cached sub-program executions (keyed by workload shape), and the
-        # merged batch graph's serial/overlapped cycles per step shape.
+        # merged step graph's serial/overlapped cycles per step shape.
         self._rounds: Dict[Tuple[str, int, int, int, int],
                            List[CycleBreakdown]] = {}
-        self._pipeline_cache: Dict[Tuple[int, Tuple[int, ...]],
-                                   Tuple[int, int]] = {}
-        self._mixed_cache: Dict[tuple, Tuple[int, int]] = {}
+        self._pipeline_cache: Dict[tuple, Tuple[int, int]] = {}
         # Engine-view accumulators.  ``engine_steps`` counts the steps the
         # merged-graph schedule priced: every batched decode step in
         # legacy mode, every mixed (chunks + decode) step in in-flight
@@ -374,89 +381,65 @@ class LegionServeBackend:
         return self
 
     def on_step(self, event: dict) -> None:
-        if event["kind"] == PREFILL:
-            self.prefill_steps += 1
+        """Price one engine event.  Each event becomes one step shape —
+        its prefill chunks and its batched decode — priced on one path:
+        a ``prefill`` event is the one chunk ``(tokens, tokens)`` and
+        counts no engine step, a ``decode`` event is a step without
+        chunks, and a ``step`` event is itself."""
+        kind = event["kind"]
+        if kind == PREFILL:
             tokens = event["tokens"]
-            # prefill attends over its own prompt: one slot, context = m
-            tally = self.step_tally(tokens, self._ctx((tokens,)))
+            chunks = ((event["uid"], tokens, tokens),)
+        else:
+            # a chunk attends its prefix too: context = offset + rows
+            chunks = tuple((ch["uid"], ch["tokens"], ch["pos0"] + ch["tokens"])
+                           for ch in event.get("chunks", ()))
+        uids = event.get("uids", ())
+        positions = event.get("positions", ())
+        # context at this step: the cache holds pos entries and the
+        # step writes + attends position pos -> t = pos + 1
+        contexts = tuple(p + 1 for p in positions) \
+            if len(positions) == len(uids) else (1,) * len(uids)
+        # canonical slot order so permuted batches share a cache entry
+        batch_ctx = tuple(sorted(contexts))
+        # totals and per-request tallies accumulate part by part (the
+        # parts' caches also hold every round the merged schedule needs)
+        for uid, rows, t in chunks:
+            self.prefill_steps += 1
+            tally = self._part_tally(rows, self._ctx((t,)))
             self.totals.merge(tally)
-            req = self._request(event["uid"])
-            req.prefill_tokens += tokens
+            req = self._request(uid)
+            req.prefill_tokens += rows
             req.add(tally)
             if self.metrics is not None:
                 self.metrics.counter("serve_backend_prefill_cycles") \
                     .inc(tally.cycles)
-        elif event["kind"] == DECODE:
+        if uids:
             self.decode_steps += 1
-            uids = event["uids"]
-            positions = event.get("positions", ())
-            # context at this step: the cache holds pos entries and the
-            # step writes + attends position pos -> t = pos + 1
-            contexts = tuple(p + 1 for p in positions) \
-                if len(positions) == len(uids) else (1,) * len(uids)
-            # engine view: one batched m=len(uids) step (canonical slot
-            # order so permuted batches share a cache entry)
-            batch_ctx = tuple(sorted(contexts))
             self.totals.merge(
-                self.step_tally(len(uids), self._ctx(batch_ctx))
-            )
-            # ... and the same step as a merged batch graph through the
-            # pipelined schedule: per-slot attention rounds interleave, so
-            # the engine-view latency is the overlapped one
-            serial, overlapped = self.step_pipeline(len(uids), batch_ctx)
-            self.engine_steps += 1
-            self._engine_serial_cycles += serial
-            self._engine_overlapped_cycles += overlapped
+                self._part_tally(len(uids), self._ctx(batch_ctx)))
+            serial, overlapped = self.step_pipeline(
+                decode_contexts=batch_ctx)
             self._decode_serial_cycles += serial
             self._decode_overlapped_cycles += overlapped
-            self._record_step_metrics(serial, overlapped)
             self._attribute_decode(uids, contexts)
-        elif event["kind"] == STEP:
-            # in-flight: prefill chunks + the batched decode, one merged
-            # step.  Tallies accumulate part-wise (the parts' caches also
-            # hold every round the merged schedule needs); the engine view
-            # prices the step as ONE merged mixed-phase graph.
-            chunks = event.get("chunks", ())
-            uids = event.get("uids", ())
-            positions = event.get("positions", ())
-            contexts = tuple(p + 1 for p in positions) \
-                if len(positions) == len(uids) else (1,) * len(uids)
-            batch_ctx = tuple(sorted(contexts))
-            shapes = []
-            for ch in chunks:
-                rows = ch["tokens"]
-                t = ch["pos0"] + rows        # chunk attends its prefix too
-                shapes.append((rows, t))
-                self.prefill_steps += 1
-                tally = self.step_tally(rows, self._ctx((t,)))
-                self.totals.merge(tally)
-                req = self._request(ch["uid"])
-                req.prefill_tokens += rows
-                req.add(tally)
-                if self.metrics is not None:
-                    self.metrics.counter("serve_backend_prefill_cycles") \
-                        .inc(tally.cycles)
-            if uids:
-                self.decode_steps += 1
-                self.totals.merge(
-                    self.step_tally(len(uids), self._ctx(batch_ctx)))
-                d_serial, d_overlapped = self.step_pipeline(
-                    len(uids), batch_ctx)
-                self._decode_serial_cycles += d_serial
-                self._decode_overlapped_cycles += d_overlapped
-                self._attribute_decode(uids, contexts)
-            serial, overlapped = self.step_pipeline_mixed(
-                shapes, decode_m=len(uids), decode_contexts=batch_ctx)
-            self.engine_steps += 1
-            self._engine_serial_cycles += serial
-            self._engine_overlapped_cycles += overlapped
-            self._record_step_metrics(serial, overlapped)
+        if kind == PREFILL:
+            return
+        # engine view: the whole step as ONE merged graph through the
+        # pipelined schedule — per-slot attention and chunk rounds
+        # interleave, so the engine-view latency is the overlapped one
+        serial, overlapped = self.step_pipeline(
+            tuple((rows, t) for _uid, rows, t in chunks), batch_ctx)
+        self.engine_steps += 1
+        self._engine_serial_cycles += serial
+        self._engine_overlapped_cycles += overlapped
+        self._record_step_metrics(serial, overlapped)
 
     def _attribute_decode(self, uids, contexts) -> None:
         """Per-request standalone attribution: each decode token's own
         m=1 step cost at its context."""
         for uid, t in zip(uids, contexts):
-            tally = self.step_tally(1, self._ctx((t,)))
+            tally = self._part_tally(1, self._ctx((t,)))
             req = self._request(uid)
             req.decode_tokens += 1
             req.add(tally)
@@ -482,40 +465,67 @@ class LegionServeBackend:
         return contexts if self.attention else ()
 
     # ------------------------------------------------------------------ #
+    # One step's shape: ``chunks`` are the prefill chunks' ``(rows,
+    # context)`` (context = the chunk's offset + its rows; a whole-prompt
+    # prefill is the one chunk ``(tokens, tokens)``), ``decode_contexts``
+    # the batched decode slots' contexts, one row each.
+    def _parts(
+        self, chunks: Sequence[Tuple[int, int]],
+        decode_contexts: Sequence[int],
+    ) -> List[Tuple[int, Tuple[int, ...]]]:
+        """The step's row groups ``(m, contexts)``: each chunk, then the
+        batched decode — attention contexts dropped when the backend
+        prices projections only."""
+        parts = [(int(rows), self._ctx((int(t),))) for rows, t in chunks]
+        if decode_contexts:
+            parts.append((len(decode_contexts),
+                          self._ctx(tuple(int(t) for t in decode_contexts))))
+        return parts
+
+    def _spec(self, chunks, decode_contexts, **kw) -> ServeStepSpec:
+        """The step as a :class:`~repro.legion.lowering.ServeStepSpec`
+        (no attention heads when the backend prices projections only)."""
+        return ServeStepSpec(
+            projections=self.ops, chunks=chunks, m=len(decode_contexts),
+            contexts=self._ctx(tuple(decode_contexts)),
+            heads=self.heads if self.attention else 0,
+            kv_heads=self.kv_heads, head_dim=self.head_dim,
+            layers=self.layers, seed=self.seed,
+            page_tokens=self.page_tokens, **kw,
+        )
+
     def workloads(
-        self, m: int, contexts: Sequence[int] = (),
+        self, chunks: Sequence[Tuple[int, int]] = (),
+        decode_contexts: Sequence[int] = (),
     ) -> List[GEMMWorkload]:
-        """The step's GEMM workloads (projections + per-slot attention) —
-        what :meth:`cross_validate` simulates."""
-        out = [dataclasses.replace(op.workload, m=m) for op in self.ops]
-        contexts = tuple(contexts)
-        if contexts and m % len(contexts):
-            # same constraint lower_serve_step enforces — the analytic
-            # workloads must correspond to an executable step program
-            raise ValueError(
-                f"{m} step rows cannot split over {len(contexts)} slots"
-            )
-        rows = m // len(contexts) if contexts else m
-        for t in contexts:
-            out.extend(decode_attention_workloads(
-                heads=self.heads, kv_heads=self.kv_heads,
-                head_dim=self.head_dim, context=t, m=rows,
-                layers=self.layers, page_tokens=self.page_tokens,
-            ))
+        """The step's GEMM workloads (projections + per-slot attention,
+        part by part) — what :meth:`cross_validate` simulates."""
+        out: List[GEMMWorkload] = []
+        for m, contexts in self._parts(chunks, decode_contexts):
+            out.extend(dataclasses.replace(op.workload, m=m)
+                       for op in self.ops)
+            rows = m // len(contexts) if contexts else m
+            for t in contexts:
+                out.extend(decode_attention_workloads(
+                    heads=self.heads, kv_heads=self.kv_heads,
+                    head_dim=self.head_dim, context=t, m=rows,
+                    layers=self.layers, page_tokens=self.page_tokens,
+                ))
         return out
 
-    def step_program(self, m: int, contexts: Sequence[int] = (), *,
-                     explicit_layers: int = 1) -> Program:
-        """Lower one serving step (``m`` rows, per-slot KV contexts) to a
-        Program: projections and attention as one dependency graph —
-        ``explicit_layers > 1`` spans it over explicit transformer layers
-        (layer ``l+1``'s QKV streams layer ``l``'s MLP output)."""
-        return lower_serve_step(
-            self.ops, m=m, contexts=self._ctx(tuple(contexts)),
-            heads=self.heads, kv_heads=self.kv_heads,
-            head_dim=self.head_dim, layers=self.layers, seed=self.seed,
-            explicit_layers=explicit_layers, page_tokens=self.page_tokens,
-        )
+    def step_program(
+        self, chunks: Sequence[Tuple[int, int]] = (),
+        decode_contexts: Sequence[int] = (), *, explicit_layers: int = 1,
+    ) -> Program:
+        """Lower one serving step to an executable Program: projections
+        and attention as one dependency graph (chunk parts merged beside
+        the decode part) — what a
+        :class:`~repro.legion.machine.PipelinedExecutor` runs and a
+        TimelineTracer measures.  ``explicit_layers > 1`` spans a step
+        without chunks over explicit transformer layers (layer ``l+1``'s
+        QKV streams layer ``l``'s MLP output)."""
+        return lower(self._spec(chunks, decode_contexts,
+                                explicit_layers=explicit_layers))
 
     def _tally_program(self, program: Program, m: int) -> StepTally:
         """Execute a (sub-)program and fold its stage reports into a tally."""
@@ -529,8 +539,7 @@ class LegionServeBackend:
             # — step_pipeline composes merged-graph schedules from these
             # without re-executing (rounds depend only on plan geometry);
             # cycle cells key by the node name (plan_stage stage= override)
-            self._rounds[(w.stage, w.m, w.k, w.n, w.count)] = \
-                rep.cycles.round_criticals()[name]
+            self._rounds[_round_key(w)] = rep.cycles.round_criticals()[name]
             cycles = rep.cycles.total_cycles * w.layers
             traffic = rep.trace.totals.scaled(w.layers)
             tally.gemms += 1
@@ -583,27 +592,19 @@ class LegionServeBackend:
             ),
         ])
 
-    def step_tally(
-        self, m: int, contexts: Sequence[int] = (),
-    ) -> StepTally:
-        """One serving step's measured tally for ``m`` activation rows.
-
-        Composed from cached sub-program executions (projections by ``m``,
+    def _part_tally(self, m: int, contexts: Tuple[int, ...]) -> StepTally:
+        """One group of ``m`` rows over its slot ``contexts``, composed
+        from cached sub-program executions (projections by ``m``,
         attention by ``(rows, context)``) — identical bytes/cycles to
-        running :meth:`step_program`'s single graph, without re-executing
-        the context-independent stages every time a context advances.
-        """
-        contexts = self._ctx(tuple(contexts))
-        if contexts and m % len(contexts):
-            raise ValueError(
-                f"{m} step rows cannot split over {len(contexts)} slots"
-            )
+        running the part's single graph, without re-executing the
+        context-independent stages every time a context advances."""
         key = (m, contexts)
-        if key in self._step_cache:
-            return self._step_cache[key]
+        if key in self._part_cache:
+            return self._part_cache[key]
         if m not in self._proj_cache:
             self._proj_cache[m] = self._tally_program(
-                lower_serve_step(self.ops, m=m, seed=self.seed), m)
+                lower(ServeStepSpec(projections=self.ops, m=m,
+                                    seed=self.seed)), m)
         parts = [self._proj_cache[m]]
         rows = m // len(contexts) if contexts else m
         for t in contexts:
@@ -616,42 +617,52 @@ class LegionServeBackend:
         for part in parts:
             tally.merge(part)
         tally.m = m
-        self._step_cache[key] = tally
+        self._part_cache[key] = tally
+        return tally
+
+    def step_tally(
+        self, chunks: Sequence[Tuple[int, int]] = (),
+        decode_contexts: Sequence[int] = (),
+    ) -> StepTally:
+        """One serving step's measured totals: its parts' cached tallies
+        merged — byte/cycle identical to executing
+        :meth:`step_program`'s graph."""
+        tally = StepTally(m=0)
+        for m, contexts in self._parts(chunks, decode_contexts):
+            tally.merge(self._part_tally(m, contexts))
         return tally
 
     def step_pipeline(
-        self, m: int, contexts: Sequence[int] = (),
+        self, chunks: Sequence[Tuple[int, int]] = (),
+        decode_contexts: Sequence[int] = (),
     ) -> Tuple[int, int]:
         """One step's engine-view ``(serial, overlapped)`` cycles — the
-        merged batch graph scheduled through the pipelined model, scaled
-        to all model layers.
+        step's merged graph scheduled through the pipelined model, scaled
+        to all model layers (``(0, 0)`` for an empty step).
 
         The serial side equals :meth:`step_tally`'s ``cycles`` exactly
         (both sum the same per-stage round criticals); the overlapped
-        side is what the batch actually costs when dependency-independent
-        rounds — different slots' attention, the split projections —
-        interleave (``repro.legion.program.compute_pipeline``).  Composed
-        from the cached sub-program executions: nothing re-executes, the
-        merged graph only re-*schedules* the measured rounds.
+        side is what the step actually costs when dependency-independent
+        rounds — different slots' attention, chunk rounds against decode
+        rounds — interleave (``repro.legion.program.compute_pipeline``).
+        Nothing re-executes: the parts' cached executions hold every
+        round, and the step's skeleton graph (no synthesized operands —
+        this runs on the per-step hot path) only re-*schedules* them.
         """
-        contexts = self._ctx(tuple(contexts))
-        key = (m, contexts)
+        chunks = tuple((int(r), int(t)) for r, t in chunks)
+        decode_contexts = tuple(int(t) for t in decode_contexts)
+        if not chunks and not decode_contexts:
+            return (0, 0)
+        key = (chunks, len(decode_contexts), self._ctx(decode_contexts))
         cached = self._pipeline_cache.get(key)
         if cached is None:
-            self.step_tally(m, contexts)       # populate the round caches
-            # skeleton graph: same names/workloads/levels/ancestry as the
-            # executable step program, but no synthesized operand arrays —
-            # this runs on the per-decode-step hot path
-            program = lower_serve_step(
-                self.ops, m=m, contexts=contexts, heads=self.heads,
-                kv_heads=self.kv_heads, head_dim=self.head_dim,
-                layers=self.layers, seed=self.seed, operands=False,
-                page_tokens=self.page_tokens,
-            )
+            # populate the round caches
+            for m, contexts in self._parts(chunks, decode_contexts):
+                self._part_tally(m, contexts)
+            program = lower(self._spec(chunks, decode_contexts,
+                                       operands=False))
             rounds = merge_round_criticals(
-                {st.name: self._rounds[
-                    (st.workload.stage, st.workload.m, st.workload.k,
-                     st.workload.n, st.workload.count)]}
+                {st.name: self._rounds[_round_key(st.workload)]}
                 for st in program
             )
             pp = compute_pipeline(program, rounds)
@@ -664,172 +675,19 @@ class LegionServeBackend:
             self._pipeline_cache[key] = cached
         return cached
 
-    def step_pipeline_mixed(
-        self, chunks: Sequence[Tuple[int, int]], *, decode_m: int = 0,
-        decode_contexts: Sequence[int] = (),
-    ) -> Tuple[int, int]:
-        """One *mixed* (in-flight) step's engine-view ``(serial,
-        overlapped)`` cycles: every prefill chunk's subgraph merged with
-        the batched decode graph and scheduled as one pipelined step.
-
-        ``chunks`` are ``(rows, context)`` shapes (context = chunk offset
-        + rows); ``decode_contexts`` the decode slots' context tuple
-        (``decode_m`` defaults to its length).  Like
-        :meth:`step_pipeline`, nothing re-executes: the part-wise
-        ``step_tally`` calls populate the per-shape round caches and the
-        merged skeleton graph (``lower_serve_mixed(..., operands=False)``)
-        only re-schedules them.  The serial side equals the sum of the
-        parts' tallied cycles; the overlapped side is the step's actual
-        latency once chunk rounds interleave with decode rounds.
-        """
-        chunks = tuple((int(r), int(t)) for r, t in chunks)
-        decode_contexts = tuple(int(t) for t in decode_contexts)
-        if decode_m == 0:
-            decode_m = len(decode_contexts)
-        if not chunks:
-            return (self.step_pipeline(decode_m, decode_contexts)
-                    if decode_m else (0, 0))
-        key = (chunks, decode_m, decode_contexts, self.attention)
-        cached = self._mixed_cache.get(key)
-        if cached is None:
-            for rows, t in chunks:           # populate the round caches
-                self.step_tally(rows, (t,))
-            if decode_m:
-                self.step_tally(decode_m, decode_contexts)
-            if self.attention:
-                program = lower_serve_mixed(
-                    self.ops, chunks=chunks,
-                    decode_contexts=decode_contexts if decode_m else (),
-                    heads=self.heads, kv_heads=self.kv_heads,
-                    head_dim=self.head_dim, layers=self.layers,
-                    seed=self.seed, operands=False,
-                    page_tokens=self.page_tokens,
-                )
-            else:
-                parts = [lower_serve_step(self.ops, m=rows, seed=self.seed,
-                                          operands=False)
-                         for rows, _t in chunks]
-                tags = [f"{{p{i}}}" for i in range(len(parts))]
-                if decode_m:
-                    parts.append(lower_serve_step(
-                        self.ops, m=decode_m, seed=self.seed,
-                        operands=False))
-                    tags.append("{d}")
-                program = Program.merge(parts, tags=tags)
-                program.validate()
-            rounds = merge_round_criticals(
-                {st.name: self._rounds[
-                    (st.workload.stage, st.workload.m, st.workload.k,
-                     st.workload.n, st.workload.count)]}
-                for st in program
-            )
-            pp = compute_pipeline(program, rounds)
-            if not pp.ok:
-                raise AssertionError(
-                    f"mixed-step pipeline broke overlapped <= serial: {pp}"
-                )
-            cached = (pp.serial_cycles * self.layers,
-                      pp.overlapped_cycles * self.layers)
-            self._mixed_cache[key] = cached
-        return cached
-
-    def step_program_mixed(
-        self, chunks: Sequence[Tuple[int, int]],
-        decode_contexts: Sequence[int] = (),
-    ) -> Program:
-        """The *executable* merged mixed-phase Program (operands
-        synthesized) — what a :class:`~repro.legion.machine
-        .PipelinedExecutor` runs and a TimelineTracer measures; its
-        skeleton twin is what :meth:`step_pipeline_mixed` schedules."""
-        return lower_serve_mixed(
-            self.ops, chunks=tuple(chunks),
-            decode_contexts=tuple(decode_contexts), heads=self.heads,
-            kv_heads=self.kv_heads, head_dim=self.head_dim,
-            layers=self.layers, seed=self.seed,
-            page_tokens=self.page_tokens,
-        )
-
-    def mixed_step_tally(
-        self, chunks: Sequence[Tuple[int, int]],
-        decode_contexts: Sequence[int] = (),
-    ) -> StepTally:
-        """Measured totals of one mixed step: the part tallies merged —
-        byte/cycle identical to executing the merged graph itself."""
-        tally = StepTally(m=0)
-        for rows, t in chunks:
-            tally.merge(self.step_tally(rows, self._ctx((t,))))
-        decode_contexts = tuple(decode_contexts)
-        if decode_contexts:
-            tally.merge(self.step_tally(len(decode_contexts),
-                                        self._ctx(decode_contexts)))
-        return tally
-
-    def mixed_workloads(
-        self, chunks: Sequence[Tuple[int, int]],
-        decode_contexts: Sequence[int] = (),
-    ) -> List[GEMMWorkload]:
-        """Analytic workload list of one mixed step (chunk parts then the
-        decode part) — what :meth:`cross_validate_mixed` simulates."""
-        out: List[GEMMWorkload] = []
-        for rows, t in chunks:
-            out.extend(self.workloads(rows, (t,)))
-        decode_contexts = tuple(decode_contexts)
-        if decode_contexts:
-            out.extend(self.workloads(len(decode_contexts),
-                                      decode_contexts))
-        return out
-
-    def cross_validate_mixed(
-        self, chunks: Sequence[Tuple[int, int]],
-        decode_contexts: Sequence[int] = (), *, rtol: float = 0.05,
-    ) -> Tuple[List[StageValidation], List[CycleValidation]]:
-        """:meth:`cross_validate` for a mixed prefill+decode step graph:
-        measured per-stage tallies of the merged step vs ``simulate()``
-        on the same concatenated workload list (``simulate`` aggregates
-        by stage family, so both sides sum chunk and decode parts)."""
-        chunks = tuple((int(r), int(t)) for r, t in chunks)
-        tally = self.mixed_step_tally(chunks, decode_contexts)
-        report = simulate(self.cfg,
-                          self.mixed_workloads(chunks, decode_contexts))
-        traffic_vals: List[StageValidation] = []
-        cycle_vals: List[CycleValidation] = []
-        for stage, st in tally.stages.items():
-            sim = report.stages[stage]
-            traffic_vals.append(StageValidation(
-                stage=stage, measured=st.traffic,
-                analytic=TrafficTotals(
-                    weight_bytes=sim.weight_bytes, act_bytes=sim.act_bytes,
-                    psum_bytes=sim.psum_bytes,
-                    page_fetches=sim.page_fetches,
-                    page_bytes=sim.page_bytes,
-                    page_waste_bytes=sim.page_waste_bytes,
-                ),
-                rtol=rtol,
-            ))
-            cycle_vals.append(CycleValidation(
-                stage=stage, measured=st.cycles, analytic=sim.cycles,
-                rtol=rtol, analytic_breakdown=sim.cycle_breakdown,
-            ))
-        return traffic_vals, cycle_vals
-
     # ------------------------------------------------------------------ #
     def cross_validate(
-        self, m: int = 1, *, contexts: Optional[Sequence[int]] = None,
-        rtol: float = 0.05,
+        self, chunks: Sequence[Tuple[int, int]] = (),
+        decode_contexts: Sequence[int] = (), *, rtol: float = 0.05,
     ) -> Tuple[List[StageValidation], List[CycleValidation]]:
-        """Compare a step's measured tallies against ``simulate()`` on the
-        same extracted workloads — the serve-path falsifiability check,
-        now covering the act-to-act attention stages too.
-
-        Default ``contexts`` is prefill-shaped (``(m,)``: one slot
-        attending over its own rows); pass e.g. ``contexts=(64,)`` with
-        ``m=1`` for a decode step at context length 64.
-        """
-        if contexts is None:
-            contexts = (m,)
-        contexts = self._ctx(tuple(contexts))
-        tally = self.step_tally(m, contexts)
-        report = simulate(self.cfg, self.workloads(m, contexts))
+        """Compare a step's measured per-stage tallies against
+        ``simulate()`` on the same workload list — the serve-path
+        falsifiability check, attention stages included (``simulate``
+        aggregates by stage family, so both sides sum the step's parts).
+        E.g. ``decode_contexts=(64,)`` is one decode token at context 64,
+        ``chunks=((24, 24),)`` a 24-token prompt's prefill."""
+        tally = self.step_tally(chunks, decode_contexts)
+        report = simulate(self.cfg, self.workloads(chunks, decode_contexts))
         traffic_vals: List[StageValidation] = []
         cycle_vals: List[CycleValidation] = []
         for stage, st in tally.stages.items():

@@ -255,7 +255,7 @@ class PagedKVCache:
 
     def page_tables(self, uids) -> List[Tuple[int, ...]]:
         """Per-slot tables in ``uids`` order — the shape
-        ``lower_serve_batch(page_tables=...)`` validates against."""
+        ``ServeStepSpec(page_tables=...)`` validates against."""
         return [self.allocator.page_table(u) for u in uids]
 
     def eviction_order(self) -> List[int]:

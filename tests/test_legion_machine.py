@@ -465,12 +465,14 @@ def test_legion_exports_sorted_and_complete():
     # PR 10: the workload-zoo lowering surface — the unified dispatcher,
     # the spec family, and the zoo lowerings — is pinned public API
     for name in ("AttentionLoweringSpec", "HybridSpec", "LoweringSpec",
-                 "MoESpec", "SSDSpec", "ServeBatchSpec", "ServeMixedSpec",
-                 "ServeStepSpec", "lower", "lower_attention",
-                 "lower_hybrid", "lower_moe", "lower_serve_batch",
-                 "lower_serve_mixed", "lower_serve_step", "lower_ssd",
-                 "zoo_spec"):
+                 "MoESpec", "SSDSpec", "ServeStepSpec", "lower",
+                 "lower_attention", "lower_hybrid", "lower_moe",
+                 "lower_ssd", "zoo_spec"):
         assert name in legion.__all__ and hasattr(legion, name)
+    # one serve spec lowers every engine step: its twins stay gone
+    for name in ("ServeBatchSpec", "ServeMixedSpec", "lower_serve_batch",
+                 "lower_serve_mixed", "lower_serve_step"):
+        assert name not in legion.__all__ and not hasattr(legion, name)
     # the PR-3 deprecation shims were removed in PR 6 and must stay gone
     for name in ("execute_plan", "execute_workload", "ExecutionResult"):
         assert name not in legion.__all__ and not hasattr(legion, name)

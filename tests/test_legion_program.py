@@ -19,7 +19,7 @@ The acceptance gate for the `legion.Program` redesign:
 * `Program.merge` interleaves independent per-slot programs as an
   antichain — a merged two-slot decode batch runs bit-exact vs per-slot
   serial execution with overlapped <= serial and 0% traffic xval — and
-  `lower_serve_step(explicit_layers=N)` spans explicit transformer layers
+  `ServeStepSpec(explicit_layers=N)` spans explicit transformer layers
   through real cross-layer deps (diamond graphs overlap, chains stay
   exact).
 """
@@ -55,9 +55,9 @@ from repro.legion import (
     Ref,
     ShardedExecutor,
     TrafficTracer,
+    ServeStepSpec,
+    lower,
     lower_attention,
-    lower_serve_batch,
-    lower_serve_step,
     reference_outputs,
     requantize_int8,
     softmax_int8,
@@ -385,10 +385,15 @@ def _mlp_ops(rng, d_model=256, d_ff=128, layers=1):
             _Op(down, tern(1, d_ff, d_model))]
 
 
+def _serve(ops, **kw):
+    """Lower one engine step through the serve spec."""
+    return lower(ServeStepSpec(projections=ops, **kw))
+
+
 def test_lower_serve_step_decode_batched_graph():
     rng = np.random.default_rng(0)
-    prog = lower_serve_step(_proj_ops(rng), m=2, contexts=(5, 9),
-                            heads=4, kv_heads=2, head_dim=32)
+    prog = _serve(_proj_ops(rng), m=2, contexts=(5, 9),
+                  heads=4, kv_heads=2, head_dim=32)
     assert prog.names == ("qkv_proj", "attn_score[0]", "attn_output[0]",
                           "attn_score[1]", "attn_output[1]", "out_proj")
     # per-slot position-dependent K/N
@@ -416,13 +421,13 @@ def test_lower_serve_step_errors():
     rng = np.random.default_rng(1)
     ops = _proj_ops(rng)
     with pytest.raises(ValueError, match="heads"):
-        lower_serve_step(ops, m=1, contexts=(4,))
+        ServeStepSpec(projections=ops, m=1, contexts=(4,))
     with pytest.raises(ValueError, match="slots"):
-        lower_serve_step(ops, m=3, contexts=(4, 5), heads=4, kv_heads=2,
-                         head_dim=32)
+        ServeStepSpec(projections=ops, m=3, contexts=(4, 5), heads=4,
+                      kv_heads=2, head_dim=32)
     with pytest.raises(ValueError, match="qkv_proj"):
-        lower_serve_step(ops[1:], m=1, contexts=(4,), heads=4, kv_heads=2,
-                         head_dim=32)
+        ServeStepSpec(projections=ops[1:], m=1, contexts=(4,), heads=4,
+                      kv_heads=2, head_dim=32)
 
 
 # --------------------------------------------------------------------------- #
@@ -635,8 +640,8 @@ def test_lower_serve_step_multi_layer_explicit_deps():
     cycle error vs simulate() and runs bit-exact vs NumPy."""
     rng = np.random.default_rng(7)
     ops = _proj_ops(rng, layers=2) + _mlp_ops(rng, layers=2)
-    prog = lower_serve_step(ops, m=1, contexts=(8,), heads=4, kv_heads=2,
-                            head_dim=32, layers=2, explicit_layers=2)
+    prog = _serve(ops, m=1, contexts=(8,), heads=4, kv_heads=2,
+                  head_dim=32, layers=2, explicit_layers=2)
     assert prog.names == (
         "qkv_proj", "attn_score", "attn_output", "out_proj",
         "mlp_up", "mlp_down",
@@ -669,15 +674,15 @@ def test_lower_serve_step_multi_layer_validation():
     rng = np.random.default_rng(8)
     ops = _proj_ops(rng) + _mlp_ops(rng)             # layers=1 workloads
     with pytest.raises(ValueError, match="explicit_layers"):
-        lower_serve_step(ops, m=1, explicit_layers=0)
+        ServeStepSpec(projections=ops, m=1, explicit_layers=0)
     with pytest.raises(ValueError, match="cannot split"):
-        lower_serve_step(ops, m=1, layers=1, explicit_layers=2)
+        ServeStepSpec(projections=ops, m=1, layers=1, explicit_layers=2)
     with pytest.raises(ValueError, match="mlp_down"):
-        lower_serve_step(ops[:1], m=1, layers=2, explicit_layers=2)
+        ServeStepSpec(projections=ops[:1], m=1, layers=2, explicit_layers=2)
     # layer count must divide every projection's layers multiplier too
     mixed = _proj_ops(rng, layers=3) + _mlp_ops(rng, layers=3)
     with pytest.raises(ValueError, match="cannot split"):
-        lower_serve_step(mixed, m=1, layers=2, explicit_layers=2)
+        ServeStepSpec(projections=mixed, m=1, layers=2, explicit_layers=2)
 
 
 def test_lower_serve_batch_two_slots_two_layers():
@@ -685,8 +690,8 @@ def test_lower_serve_batch_two_slots_two_layers():
     layers and overlaps across slots under the pipelined executor."""
     rng = np.random.default_rng(9)
     ops = _proj_ops(rng, layers=2) + _mlp_ops(rng, layers=2)
-    prog = lower_serve_batch(ops, contexts=(5, 9), heads=4, kv_heads=2,
-                             head_dim=32, layers=2, explicit_layers=2)
+    prog = _serve(ops, m=2, contexts=(5, 9), heads=4, kv_heads=2,
+                  head_dim=32, layers=2, explicit_layers=2)
     # slot tags then layer tags; per-slot position-dependent K/N per layer
     assert prog["attn_score[1]@1"].workload.n == 9
     assert prog["attn_output[0]@1"].workload.k == 5
@@ -698,8 +703,5 @@ def test_lower_serve_batch_two_slots_two_layers():
     assert pp.ok
     assert pp.overlapped_cycles < pp.serial_cycles
     with pytest.raises(ValueError, match="slot context"):
-        lower_serve_batch(ops, contexts=(), heads=4, kv_heads=2,
-                          head_dim=32)
-    with pytest.raises(ValueError, match="rows_per_slot"):
-        lower_serve_batch(ops, contexts=(4,), heads=4, kv_heads=2,
-                          head_dim=32, rows_per_slot=0)
+        ServeStepSpec(projections=ops, contexts=(), heads=4, kv_heads=2,
+                      head_dim=32)

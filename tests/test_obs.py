@@ -216,7 +216,7 @@ def test_two_layer_serve_program_trace_parity(served):
     eng = ServeEngine(api, params, max_slots=2, max_seq=64)
     backend = LegionServeBackend(ACCEL := dlegion(), cfg, params)
     backend.attach(eng)
-    prog = backend.step_program(2, (8, 12), explicit_layers=2)
+    prog = backend.step_program(decode_contexts=(8, 12), explicit_layers=2)
 
     tracer = TimelineTracer(ACCEL)
     machine = Machine(ACCEL, backend=PipelinedExecutor(),
@@ -268,7 +268,7 @@ def test_mixed_step_program_trace_parity(served):
     mixed-phase steps are priced by the same machinery as pure decode."""
     cfg, api, params = served
     backend = LegionServeBackend(ACCEL := dlegion(), cfg, params)
-    prog = backend.step_program_mixed([(6, 6), (4, 10)], (8, 12))
+    prog = backend.step_program([(6, 6), (4, 10)], (8, 12))
 
     tracer = TimelineTracer(ACCEL)
     machine = Machine(ACCEL, backend=PipelinedExecutor(),
@@ -286,8 +286,7 @@ def test_mixed_step_program_trace_parity(served):
     assert ov.makespan == ser.makespan - rep.pipeline.hidden_cycles
     # the scheduler's skeleton twin prices the same step identically
     # (scaled to all model layers, like every engine-view number)
-    serial, overlapped = backend.step_pipeline_mixed(
-        [(6, 6), (4, 10)], decode_contexts=(8, 12))
+    serial, overlapped = backend.step_pipeline([(6, 6), (4, 10)], (8, 12))
     assert (serial, overlapped) == \
         (rep.pipeline.serial_cycles * cfg.layers,
          rep.pipeline.overlapped_cycles * cfg.layers)

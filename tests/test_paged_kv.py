@@ -28,8 +28,7 @@ from repro.configs import get_config, reduced
 from repro.core import dlegion
 from repro.core.workloads import ATTN_OUTPUT, ATTN_SCORE, GEMMWorkload, \
     decode_attention_workloads
-from repro.legion import Machine
-from repro.legion.program import lower_serve_mixed, lower_serve_step
+from repro.legion import Machine, ServeStepSpec, lower
 from repro.models import build_model
 from repro.obs import TimelineTracer
 from repro.serve import (
@@ -334,24 +333,24 @@ def test_lower_serve_step_validates_page_tables(bitnet):
     cfg, _api, params = bitnet
     ops = extract_projection_ops(cfg, params)
     hd = cfg.head_dim_
-    kw = dict(m=2, contexts=(9, 17), heads=cfg.n_heads, kv_heads=cfg.kv_heads,
-              head_dim=hd, page_tokens=8)
-    prog = lower_serve_step(ops, page_tables=((0, 1), (2, 3, 4)), **kw)
+    kw = dict(projections=ops, m=2, contexts=(9, 17), heads=cfg.n_heads,
+              kv_heads=cfg.kv_heads, head_dim=hd, page_tokens=8)
+    prog = lower(ServeStepSpec(page_tables=((0, 1), (2, 3, 4)), **kw))
     assert any(s.workload.page_tokens == 8 for s in prog.stages)
     with pytest.raises(ValueError, match="without page_tokens"):
-        lower_serve_step(ops, m=2, contexts=(9, 17), heads=cfg.n_heads,
-                         kv_heads=cfg.kv_heads, head_dim=hd,
-                         page_tables=((0, 1), (2, 3, 4)))
+        ServeStepSpec(projections=ops, m=2, contexts=(9, 17),
+                      heads=cfg.n_heads, kv_heads=cfg.kv_heads, head_dim=hd,
+                      page_tables=((0, 1), (2, 3, 4)))
     with pytest.raises(ValueError, match="page tables for"):
-        lower_serve_step(ops, page_tables=((0, 1),), **kw)
+        ServeStepSpec(page_tables=((0, 1),), **kw)
     with pytest.raises(ValueError, match="needs"):
-        lower_serve_step(ops, page_tables=((0, 1), (2, 3)), **kw)
+        ServeStepSpec(page_tables=((0, 1), (2, 3)), **kw)
     with pytest.raises(ValueError, match="chunk page tables"):
-        lower_serve_mixed(ops, chunks=[(4, 9)], decode_contexts=(13,),
-                          heads=cfg.n_heads, kv_heads=cfg.kv_heads,
-                          head_dim=hd, page_tokens=8,
-                          chunk_page_tables=((0, 1), (2,)),
-                          decode_page_tables=((3, 4),))
+        ServeStepSpec(projections=ops, chunks=[(4, 9)], m=1,
+                      contexts=(13,), heads=cfg.n_heads,
+                      kv_heads=cfg.kv_heads, head_dim=hd, page_tokens=8,
+                      chunk_page_tables=((0, 1), (2,)),
+                      page_tables=((3, 4),))
 
 
 # --------------------------------------------------------------------------- #
@@ -449,12 +448,12 @@ def test_backend_paged_pricing_and_cross_validation(bitnet):
     assert 0 < s1["page_waste_frac"] < 1
     assert s1["page_fetch_bytes"] > s1["page_waste_bytes"]
     # measured page channel == simulate(), decode and mixed graphs alike
-    tv, cv = b1.cross_validate(1, contexts=(13,))
+    tv, cv = b1.cross_validate(decode_contexts=(13,))
     for v in tv:
         assert all(e == 0.0 for e in v.errors.values()), str(v)
     for v in cv:
         assert v.ok, str(v)
-    tvm, _cvm = b1.cross_validate_mixed([(4, 9)], (7, 13))
+    tvm, _cvm = b1.cross_validate([(4, 9)], (7, 13))
     for v in tvm:
         assert all(e == 0.0 for e in v.errors.values()), str(v)
     # the measured budget carries the backend's own page geometry

@@ -14,6 +14,7 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro.core import dlegion
+from repro.legion import ServeStepSpec
 from repro.models import build_model
 from repro.serve import LegionServeBackend, ServeEngine
 from repro.serve.engine import prepare_params
@@ -77,7 +78,7 @@ def test_decode_step_cross_validates_traffic_and_cycles(served):
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
     traffic_vals, cycle_vals = backend.cross_validate(
-        m=1, contexts=(16,), rtol=0.05)
+        decode_contexts=(16,), rtol=0.05)
     assert len(traffic_vals) == len(cycle_vals) == 6
     assert {v.stage for v in traffic_vals} == {
         "qkv_proj", "attn_score", "attn_output", "out_proj",
@@ -94,18 +95,20 @@ def test_projection_only_backend_keeps_four_stages(served):
     """attention=False reproduces the PR-2 projection-only tallies."""
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params, attention=False)
-    traffic_vals, cycle_vals = backend.cross_validate(m=1, rtol=0.05)
+    traffic_vals, cycle_vals = backend.cross_validate(chunks=((1, 1),),
+                                                      rtol=0.05)
     assert len(traffic_vals) == len(cycle_vals) == 4
     for v in traffic_vals + cycle_vals:
         assert v.ok, str(v)
-    assert backend.step_tally(1).gemms == 4
+    assert backend.step_tally(decode_contexts=(1,)).gemms == 4
 
 
 def test_prefill_step_cross_validates(served):
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
-    # prefill default: one slot attending over its own 24 rows
-    traffic_vals, cycle_vals = backend.cross_validate(m=24, rtol=0.05)
+    # a whole-prompt prefill: one chunk attending over its own 24 rows
+    traffic_vals, cycle_vals = backend.cross_validate(chunks=((24, 24),),
+                                                      rtol=0.05)
     assert len(traffic_vals) == 6
     for v in traffic_vals + cycle_vals:
         assert v.ok, str(v)
@@ -118,8 +121,9 @@ def test_composed_tally_equals_full_step_program(served):
     the attention pair re-executes as the context advances."""
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
-    composed = backend.step_tally(2, (5, 9))
-    full = backend._tally_program(backend.step_program(2, (5, 9)), 2)
+    composed = backend.step_tally(decode_contexts=(5, 9))
+    full = backend._tally_program(
+        backend.step_program(decode_contexts=(5, 9)), 2)
     assert composed.gemms == full.gemms
     assert composed.cycles == full.cycles
     assert (composed.weight_bytes, composed.act_bytes, composed.psum_bytes) \
@@ -127,13 +131,15 @@ def test_composed_tally_equals_full_step_program(served):
     for stage in full.stages:
         assert composed.stages[stage].cycles == full.stages[stage].cycles
     # advancing the context reuses the cached projection part
-    backend.step_tally(2, (6, 10))
+    backend.step_tally(decode_contexts=(6, 10))
     assert set(backend._proj_cache) == {2}
     assert (1, 5) in backend._attn_cache and (1, 6) in backend._attn_cache
+    # a decode part's rows split evenly over its slots, checked once by
+    # the step spec every pricing method lowers through
     with pytest.raises(ValueError, match="slots"):
-        backend.step_tally(3, (4, 5))
-    with pytest.raises(ValueError, match="slots"):
-        backend.workloads(3, (4, 5))
+        ServeStepSpec(projections=backend.ops, m=3, contexts=(4, 5),
+                      heads=cfg.n_heads, kv_heads=cfg.kv_heads,
+                      head_dim=cfg.head_dim_)
 
 
 def test_attention_cost_grows_with_context(served):
@@ -141,13 +147,13 @@ def test_attention_cost_grows_with_context(served):
     bytes at a longer context — the admission-control signal."""
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
-    short = backend.step_tally(1, (4,))
-    mid = backend.step_tally(1, (48,))
+    short = backend.step_tally(decode_contexts=(4,))
+    mid = backend.step_tally(decode_contexts=(48,))
     # below one K-window / N-tile (128) the array shape hides the growth in
     # padding: cycles stay flat while stationary bytes already grow
     assert mid.weight_bytes > short.weight_bytes
     # crossing the tile boundary adds psum rounds and passes: cycles grow
-    long = backend.step_tally(1, (200,))
+    long = backend.step_tally(decode_contexts=(200,))
     assert long.cycles > short.cycles
     assert long.act_bytes > short.act_bytes
     assert short.gemms == long.gemms == 6
@@ -179,8 +185,8 @@ def test_engine_steps_accumulate_per_request_tallies(served):
         # exact standalone ledger: one prefill step attending its 8-token
         # prompt, then one m=1 decode step per token at its growing
         # position-dependent context (9, 10, ... — prompt + decoded so far)
-        expected = backend.step_tally(8, (8,)).cycles + sum(
-            backend.step_tally(1, (t,)).cycles
+        expected = backend.step_tally(chunks=((8, 8),)).cycles + sum(
+            backend.step_tally(decode_contexts=(t,)).cycles
             for t in range(9, 9 + tally.decode_tokens)
         )
         assert tally.cycles == expected
@@ -191,9 +197,10 @@ def test_engine_steps_accumulate_per_request_tallies(served):
                                      backend.per_request.values())
     # mean standalone per-token decode cost (context-dependent steps)
     assert s["cycles_per_decode_token"] == pytest.approx(
-        sum(backend.step_tally(1, (t,)).cycles for t in (9, 10, 11)) / 3.0)
+        sum(backend.step_tally(decode_contexts=(t,)).cycles
+            for t in (9, 10, 11)) / 3.0)
     # batched decode steps executed as m=2 programs with per-slot contexts
-    assert any(m == 2 and len(ctx) == 2 for m, ctx in backend._step_cache)
+    assert any(m == 2 and len(ctx) == 2 for m, ctx in backend._part_cache)
     # engine totals are batch-accurate: 3 prefills + the batched decode
     # steps, each counted once at its true batch size
     assert s["cycles"] == backend.totals.cycles > 0
@@ -262,14 +269,14 @@ def test_engine_view_overlapped_latency(served):
 
     # the merged schedule's serial side == the batched tally, cycle for
     # cycle (same per-stage round criticals, just not interleaved)
-    serial, overlapped = backend.step_pipeline(2, (9, 9))
-    assert serial == backend.step_tally(2, (9, 9)).cycles
+    serial, overlapped = backend.step_pipeline(decode_contexts=(9, 9))
+    assert serial == backend.step_tally(decode_contexts=(9, 9)).cycles
     assert overlapped < serial
     # single-slot steps are chains, but every stationary operand (weights,
     # the slot's KV cache) exists before its streamed input, so the
     # dependent boundaries still prefetch their fill: overlapped < serial
-    s1, o1 = backend.step_pipeline(1, (16,))
-    assert s1 == backend.step_tally(1, (16,)).cycles
+    s1, o1 = backend.step_pipeline(decode_contexts=(16,))
+    assert s1 == backend.step_tally(decode_contexts=(16,)).cycles
     assert 0 < o1 < s1
 
 
@@ -344,10 +351,11 @@ def test_sharded_executor_serve_step_matches_in_process(served):
     sharded = LegionServeBackend(ACCEL, cfg, params,
                                  executor=ShardedExecutor())
     assert sharded.machine.backend.name == "sharded"
-    a, b = inproc.step_tally(1, (8,)), sharded.step_tally(1, (8,))
+    a = inproc.step_tally(decode_contexts=(8,))
+    b = sharded.step_tally(decode_contexts=(8,))
     assert (a.cycles, a.weight_bytes, a.act_bytes, a.psum_bytes) == \
         (b.cycles, b.weight_bytes, b.act_bytes, b.psum_bytes)
-    traffic_vals, cycle_vals = sharded.cross_validate(m=1, contexts=(8,),
+    traffic_vals, cycle_vals = sharded.cross_validate(decode_contexts=(8,),
                                                       rtol=0.05)
     for v in traffic_vals + cycle_vals:
         assert v.ok, str(v)
@@ -362,10 +370,12 @@ def test_pipelined_executor_serve_step(served):
     inproc = LegionServeBackend(ACCEL, cfg, params)
     piped = LegionServeBackend(ACCEL, cfg, params,
                                executor=PipelinedExecutor())
-    a, b = inproc.step_tally(2, (5, 9)), piped.step_tally(2, (5, 9))
+    a = inproc.step_tally(decode_contexts=(5, 9))
+    b = piped.step_tally(decode_contexts=(5, 9))
     assert (a.cycles, a.weight_bytes, a.act_bytes) == \
         (b.cycles, b.weight_bytes, b.act_bytes)
-    rep = piped.machine.run(piped.step_program(2, (5, 9)), validate=False)
+    rep = piped.machine.run(piped.step_program(decode_contexts=(5, 9)),
+                            validate=False)
     assert rep.pipeline is not None
     assert rep.pipeline.overlapped_cycles <= rep.pipeline.serial_cycles
 
@@ -376,7 +386,7 @@ def test_mixed_step_cross_validates(served):
     workload list, all six families within tolerance."""
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
-    traffic_vals, cycle_vals = backend.cross_validate_mixed(
+    traffic_vals, cycle_vals = backend.cross_validate(
         [(8, 8), (4, 12)], (5, 9, 13), rtol=0.05)
     assert len(traffic_vals) == len(cycle_vals) == 6
     for v in traffic_vals + cycle_vals:
@@ -386,32 +396,33 @@ def test_mixed_step_cross_validates(served):
 
 
 def test_mixed_pipeline_serial_matches_parts(served):
-    """step_pipeline_mixed: the serial side equals the summed part
-    tallies exactly; the overlapped side is a real (<=) pipelined
-    latency; degenerate shapes delegate to the pure-decode path."""
+    """step_pipeline on a mixed step: the serial side equals the summed
+    part tallies exactly; the overlapped side is a real (<=) pipelined
+    latency; degenerate shapes price as the pure-decode step."""
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
     chunks, dctx = ((8, 8), (4, 12)), (5, 9, 13)
-    serial, overlapped = backend.step_pipeline_mixed(
-        chunks, decode_contexts=dctx)
-    assert serial == backend.mixed_step_tally(chunks, dctx).cycles
+    serial, overlapped = backend.step_pipeline(chunks, dctx)
+    assert serial == backend.step_tally(chunks, dctx).cycles
     assert 0 < overlapped <= serial
     # a mixed step beats running the phases back to back: the merged
     # graph overlaps chunk rounds with decode rounds
-    _, chunk_only = backend.step_pipeline_mixed(chunks)
-    _, decode_only = backend.step_pipeline(len(dctx), dctx)
+    _, chunk_only = backend.step_pipeline(chunks)
+    _, decode_only = backend.step_pipeline(decode_contexts=dctx)
     assert overlapped < chunk_only + decode_only
     # no chunks -> exactly the decode-only engine view
-    assert backend.step_pipeline_mixed((), decode_contexts=dctx) == \
-        backend.step_pipeline(len(dctx), dctx)
-    assert backend.step_pipeline_mixed(()) == (0, 0)
+    assert backend.step_pipeline((), dctx) == \
+        backend.step_pipeline(decode_contexts=dctx)
+    assert backend.step_pipeline(decode_contexts=dctx)[0] == \
+        backend.step_tally(decode_contexts=dctx).cycles
+    assert backend.step_pipeline(()) == (0, 0)
     # cached: the same shapes never rebuild the merged skeleton
-    key = (chunks, len(dctx), dctx, True)
-    assert backend._mixed_cache[key] == (serial, overlapped)
+    key = (chunks, len(dctx), dctx)
+    assert backend._pipeline_cache[key] == (serial, overlapped)
     # projection-only backends schedule mixed steps too
     proj = LegionServeBackend(ACCEL, cfg, params, attention=False)
-    s_p, o_p = proj.step_pipeline_mixed(chunks, decode_contexts=dctx)
-    assert s_p == proj.mixed_step_tally(chunks, dctx).cycles
+    s_p, o_p = proj.step_pipeline(chunks, dctx)
+    assert s_p == proj.step_tally(chunks, dctx).cycles
     assert 0 < o_p <= s_p
 
 
@@ -492,10 +503,189 @@ def test_live_admission_gates_intake(served):
 def test_step_tally_scales_with_model_layers(served):
     cfg, _api, params = served
     backend = LegionServeBackend(ACCEL, cfg, params)
-    tally = backend.step_tally(1, (4,))
+    tally = backend.step_tally(decode_contexts=(4,))
     per_layer = sum(
         st.cycles for st in tally.stages.values()
     ) / cfg.layers
     assert tally.cycles == pytest.approx(per_layer * cfg.layers)
     assert tally.gemms == 6
     assert tally.executed_passes > 0 and tally.skipped_passes == 0
+
+
+# --------------------------------------------------------------------------- #
+# Pinned backend behaviour: summary() and per-request tallies, bit for bit
+# --------------------------------------------------------------------------- #
+
+PINNED_PROMPTS = ((5, 11, 17, 23, 29), (3, 8, 13, 21, 34, 55, 89), (7, 9, 2))
+
+PINNED_CASES = {
+    "legacy": dict(chunk=None, page_tokens=0, attention=True),
+    "inflight": dict(chunk=4, page_tokens=0, attention=True),
+    "legacy-paged": dict(chunk=None, page_tokens=4, attention=True),
+    "inflight-paged": dict(chunk=4, page_tokens=4, attention=True),
+    "inflight-no-attention": dict(chunk=4, page_tokens=0, attention=False),
+}
+
+# Recorded before the backend's pricing methods were merged into one per
+# job: summary() and each request's (prefill_tokens, decode_tokens,
+# cycles, weight_bytes, act_bytes, psum_bytes), bit for bit.
+PINNED = {
+    'legacy': (
+        {
+            'requests': 3, 'prefill_steps': 3, 'decode_steps': 4,
+            'engine_steps': 4, 'prefill_tokens': 15, 'decode_tokens': 6,
+            'weight_bytes': 805888.0, 'act_bytes': 75264.0,
+            'psum_bytes': 240448.0, 'page_fetches': 0.0,
+            'page_fetch_bytes': 0.0, 'page_waste_bytes': 0.0,
+            'page_waste_frac': 0.0, 'cycles': 11632, 'stall_cycles': 0,
+            'stall_frac': 0.0, 'cycles_per_decode_token': 1424.0,
+            'us_per_decode_token': 1.4240000000000002,
+            'serial_cycles_per_step': 1840.0,
+            'overlapped_cycles_per_step': 1364.0,
+            'serial_cycles_per_decode_token': 1226.6666666666667,
+            'overlapped_cycles_per_decode_token': 909.3333333333334,
+            'pipeline_speedup': 1.348973607038123,
+            'overlapped_us_per_decode_token': 0.9093333333333333,
+        },
+        {
+            0: (5, 2, 4272, 328704.0, 25088.0, 80064.0),
+            1: (7, 2, 4272, 331776.0, 32256.0, 103488.0),
+            2: (3, 2, 4272, 325632.0, 17920.0, 56896.0),
+        },
+    ),
+    'inflight': (
+        {
+            'requests': 3, 'prefill_steps': 5, 'decode_steps': 4,
+            'engine_steps': 5, 'prefill_tokens': 15, 'decode_tokens': 6,
+            'weight_bytes': 1022464.0, 'act_bytes': 75264.0,
+            'psum_bytes': 239936.0, 'page_fetches': 0.0,
+            'page_fetch_bytes': 0.0, 'page_waste_bytes': 0.0,
+            'page_waste_frac': 0.0, 'cycles': 14480, 'stall_cycles': 0,
+            'stall_frac': 0.0, 'cycles_per_decode_token': 1424.0,
+            'us_per_decode_token': 1.4240000000000002,
+            'serial_cycles_per_step': 2896.0,
+            'overlapped_cycles_per_step': 1940.8,
+            'serial_cycles_per_decode_token': 1226.6666666666667,
+            'overlapped_cycles_per_decode_token': 909.3333333333334,
+            'pipeline_speedup': 1.4921681780708986,
+            'overlapped_us_per_decode_token': 0.9093333333333333,
+        },
+        {
+            0: (5, 2, 5696, 437248.0, 25088.0, 79936.0),
+            1: (7, 2, 5696, 439808.0, 32256.0, 103104.0),
+            2: (3, 2, 4272, 325632.0, 17920.0, 56896.0),
+        },
+    ),
+    'legacy-paged': (
+        {
+            'requests': 3, 'prefill_steps': 3, 'decode_steps': 4,
+            'engine_steps': 4, 'prefill_tokens': 15, 'decode_tokens': 6,
+            'weight_bytes': 809472.0, 'act_bytes': 75264.0,
+            'psum_bytes': 240448.0, 'page_fetches': 136.0,
+            'page_fetch_bytes': 17408.0, 'page_waste_bytes': 3584.0,
+            'page_waste_frac': 0.20588235294117646, 'cycles': 11632,
+            'stall_cycles': 0, 'stall_frac': 0.0,
+            'cycles_per_decode_token': 1424.0,
+            'us_per_decode_token': 1.4240000000000002,
+            'serial_cycles_per_step': 1840.0,
+            'overlapped_cycles_per_step': 1364.0,
+            'serial_cycles_per_decode_token': 1226.6666666666667,
+            'overlapped_cycles_per_decode_token': 909.3333333333334,
+            'pipeline_speedup': 1.348973607038123,
+            'overlapped_us_per_decode_token': 0.9093333333333333,
+        },
+        {
+            0: (5, 2, 4272, 330240.0, 25088.0, 80064.0),
+            1: (7, 2, 4272, 332800.0, 32256.0, 103488.0),
+            2: (3, 2, 4272, 326656.0, 17920.0, 56896.0),
+        },
+    ),
+    'inflight-paged': (
+        {
+            'requests': 3, 'prefill_steps': 5, 'decode_steps': 4,
+            'engine_steps': 5, 'prefill_tokens': 15, 'decode_tokens': 6,
+            'weight_bytes': 1026304.0, 'act_bytes': 75264.0,
+            'psum_bytes': 239936.0, 'page_fetches': 152.0,
+            'page_fetch_bytes': 19456.0, 'page_waste_bytes': 3840.0,
+            'page_waste_frac': 0.19736842105263158, 'cycles': 14480,
+            'stall_cycles': 0, 'stall_frac': 0.0,
+            'cycles_per_decode_token': 1424.0,
+            'us_per_decode_token': 1.4240000000000002,
+            'serial_cycles_per_step': 2896.0,
+            'overlapped_cycles_per_step': 1940.8,
+            'serial_cycles_per_decode_token': 1226.6666666666667,
+            'overlapped_cycles_per_decode_token': 909.3333333333334,
+            'pipeline_speedup': 1.4921681780708986,
+            'overlapped_us_per_decode_token': 0.9093333333333333,
+        },
+        {
+            0: (5, 2, 5696, 438784.0, 25088.0, 79936.0),
+            1: (7, 2, 5696, 441088.0, 32256.0, 103104.0),
+            2: (3, 2, 4272, 326656.0, 17920.0, 56896.0),
+        },
+    ),
+    'inflight-no-attention': (
+        {
+            'requests': 3, 'prefill_steps': 5, 'decode_steps': 4,
+            'engine_steps': 5, 'prefill_tokens': 15, 'decode_tokens': 6,
+            'weight_bytes': 811008.0, 'act_bytes': 32256.0,
+            'psum_bytes': 215040.0, 'page_fetches': 0.0,
+            'page_fetch_bytes': 0.0, 'page_waste_bytes': 0.0,
+            'page_waste_frac': 0.0, 'cycles': 5328, 'stall_cycles': 0,
+            'stall_frac': 0.0, 'cycles_per_decode_token': 592.0,
+            'us_per_decode_token': 0.592, 'serial_cycles_per_step': 1065.6,
+            'overlapped_cycles_per_step': 708.8,
+            'serial_cycles_per_decode_token': 394.6666666666667,
+            'overlapped_cycles_per_decode_token': 304.0,
+            'pipeline_speedup': 1.5033860045146727,
+            'overlapped_us_per_decode_token': 0.30400000000000005,
+        },
+        {
+            0: (5, 2, 2368, 360448.0, 10752.0, 71680.0),
+            1: (7, 2, 2368, 360448.0, 13824.0, 92160.0),
+            2: (3, 2, 1776, 270336.0, 7680.0, 51200.0),
+        },
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def served_kv():
+    cfg = reduced(get_config("bitnet-1.58b-kv"))
+    api = build_model(cfg)
+    params = prepare_params(api.init(jax.random.PRNGKey(0)))
+    return cfg, api, params
+
+
+def _pinned_run(served_kv, *, chunk, page_tokens, attention):
+    """Serve the fixed queue through an engine with the backend attached;
+    return the backend's summary and each request's cycles and bytes."""
+    from repro.serve import PagedKVCache
+
+    cfg, api, params = served_kv
+    paged = (PagedKVCache(total_pages=32, page_tokens=page_tokens)
+             if page_tokens else None)
+    eng = ServeEngine(api, params, max_slots=2, max_seq=32,
+                      prefill_chunk_tokens=chunk, paged_kv=paged)
+    backend = LegionServeBackend(ACCEL, cfg, params, attention=attention,
+                                 page_tokens=page_tokens).attach(eng)
+    for prompt in PINNED_PROMPTS:
+        eng.submit(np.asarray(prompt), max_new_tokens=3)
+    eng.run_until_done()
+    requests = {
+        uid: (r.prefill_tokens, r.decode_tokens, r.cycles, r.weight_bytes,
+              r.act_bytes, r.psum_bytes)
+        for uid, r in sorted(backend.per_request.items())
+    }
+    return backend.summary(), requests
+
+
+@pytest.mark.parametrize("case", sorted(PINNED_CASES))
+def test_backend_summary_pinned(served_kv, case):
+    """Legacy and in-flight engines, paged and unpaged, attention on and
+    off: the backend's summary and per-request tallies match the values
+    recorded for this queue exactly."""
+    summary, requests = _pinned_run(served_kv, **PINNED_CASES[case])
+    want_summary, want_requests = PINNED[case]
+    assert summary == want_summary
+    assert requests == want_requests

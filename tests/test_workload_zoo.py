@@ -32,7 +32,6 @@ from repro.legion import (
     lower,
     lower_attention,
     lower_moe,
-    lower_serve_step,
     lower_ssd,
     moe_stage_names,
     reference_outputs,
@@ -243,10 +242,23 @@ def test_lower_dispatches_serve_step_and_matches_alias():
     spec = ServeStepSpec(projections=ops, m=2, contexts=(5, 9), heads=h,
                          kv_heads=kv, head_dim=hd)
     via_dispatch = lower(spec)
-    via_alias = lower_serve_step(ops, m=2, contexts=(5, 9), heads=h,
-                                 kv_heads=kv, head_dim=hd)
-    assert via_dispatch.names == via_alias.names
+    assert via_dispatch.names == (
+        "qkv_proj", "attn_score[0]", "attn_output[0]",
+        "attn_score[1]", "attn_output[1]", "out_proj")
     assert Machine(CFG).run(via_dispatch).ok
+    # prefill chunks merge beside the decode part under {p<i>} / {d} tags
+    mixed = lower(ServeStepSpec(projections=ops, chunks=((4, 9),), m=2,
+                                contexts=(5, 9), heads=h, kv_heads=kv,
+                                head_dim=hd))
+    assert mixed.names == tuple(
+        f"{n}{{p0}}" for n in ("qkv_proj", "attn_score", "attn_output",
+                               "out_proj")
+    ) + tuple(f"{n}{{d}}" for n in via_dispatch.names)
+    assert Machine(CFG).run(mixed).ok
+    # without attention heads every part lowers to its projections alone
+    bare = lower(ServeStepSpec(projections=ops, chunks=((4, 9),), m=2))
+    assert bare.names == ("qkv_proj{p0}", "out_proj{p0}",
+                          "qkv_proj{d}", "out_proj{d}")
 
     # kwargs normalized onto the spec: bad combos raise at construction
     with pytest.raises(ValueError, match="cannot split"):
