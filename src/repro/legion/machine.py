@@ -1090,7 +1090,7 @@ class Machine:
         traffic/cycles by the program prefix.
         """
         from repro.legion.program import Ref
-        from repro.legion.runtime import _instance_view, synthesize_operands
+        from repro.legion.runtime import exact_int_matmul, synthesize_operands
 
         workload = stage.workload
         ztb = stage.ztb
@@ -1157,25 +1157,32 @@ class Machine:
         if check_outputs and not caller_books:
             with TraceAnnotation(CHECK_SPAN):
                 x_arr, w_arr = np.asarray(x), np.asarray(w)
-                for inst in range(ctx.count):
-                    if ctx.int_path:
-                        xi = _instance_view(x_arr, inst, 2).astype(np.int64)
-                        wi = _instance_view(w_arr, inst, 2).astype(np.int64)
-                        got = outputs[inst].astype(np.int64)
-                        mismatch = got != xi @ wi
-                    else:
-                        xi = _instance_view(x_arr, inst, 2).astype(np.float64)
-                        wi = _instance_view(w_arr, inst, 2).astype(np.float64)
-                        got = outputs[inst]
-                        mismatch = ~np.isclose(got, xi @ wi, rtol=1e-5,
-                                               atol=1e-5)
-                    if mismatch.any():
-                        raise AssertionError(
-                            f"{plan.stage} instance {inst}: runtime output "
-                            f"!= x @ w reference at {int(mismatch.sum())} "
-                            f"positions (mode {ctx.mode.name}, backend "
-                            f"{self.backend.name})"
-                        )
+                # [count, ...] operands may hold more instances than the plan
+                if x_arr.ndim == 3:
+                    x_arr = x_arr[:ctx.count]
+                if w_arr.ndim == 3:
+                    w_arr = w_arr[:ctx.count]
+                if ctx.int_path:
+                    ref, path = exact_int_matmul(x_arr, w_arr)
+                    mismatch = outputs != ref
+                else:
+                    path = "float"
+                    ref = np.matmul(x_arr.astype(np.float64),
+                                    w_arr.astype(np.float64))
+                    mismatch = ~np.isclose(outputs, ref, rtol=1e-5,
+                                           atol=1e-5)
+                bad = mismatch.reshape(ctx.count, -1).sum(axis=1)
+                if bad.any():
+                    inst = int(np.flatnonzero(bad)[0])
+                    raise AssertionError(
+                        f"{plan.stage} instance {inst}: runtime output "
+                        f"!= x @ w reference at {int(bad[inst])} "
+                        f"positions (mode {ctx.mode.name}, backend "
+                        f"{self.backend.name})"
+                    )
+            if self.metrics is not None:
+                self.metrics.counter("machine_check_stages",
+                                     labels=("path",)).inc(path=path)
 
         report = RunReport(
             outputs=outputs, plan=plan, mode=ctx.mode,

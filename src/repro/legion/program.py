@@ -72,6 +72,7 @@ from repro.core.sparsity import ZeroTileBook
 from repro.core.workloads import GEMMWorkload
 from repro.legion.latency import CycleBreakdown
 from repro.legion.modes import ModeSpec
+from repro.legion.runtime import exact_int_matmul
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.legion.machine import RunReport
@@ -527,17 +528,17 @@ def reference_outputs(program: Program) -> Dict[str, np.ndarray]:
         count = st.workload.count if st.workload is not None else (
             max(a.instance for a in st.plan.assignments) + 1
         )
+        x = x if x.ndim == 2 else x[:count]
+        w = w if w.ndim == 2 else w[:count]
         int_path = (np.issubdtype(x.dtype, np.integer)
                     and np.issubdtype(w.dtype, np.integer))
-        acc = np.int64 if int_path else np.float64
-        res = []
-        for i in range(count):
-            xi = (x if x.ndim == 2 else x[i]).astype(acc)
-            wi = (w if w.ndim == 2 else w[i]).astype(acc)
-            res.append(xi @ wi)
-        outs[st.name] = np.stack(res).astype(
-            np.int32 if int_path else np.float32
-        )
+        if int_path:
+            res, _ = exact_int_matmul(x, w)
+        else:
+            res = np.matmul(x.astype(np.float64), w.astype(np.float64))
+        outs[st.name] = np.broadcast_to(
+            res, (count,) + res.shape[-2:]
+        ).astype(np.int32 if int_path else np.float32)
     return outs
 
 

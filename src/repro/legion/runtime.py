@@ -10,7 +10,9 @@ This module keeps the pieces that are not session state:
 
 * :func:`validate_coverage` — a plan must tile each instance's N-range
   exactly once (gaps/overlaps are hard errors);
-* :func:`synthesize_operands` — reproducible int8 operands per workload.
+* :func:`synthesize_operands` — reproducible int8 operands per workload;
+* :func:`exact_int_matmul` — the exact integer ``x @ w`` that stage
+  outputs and :func:`~repro.legion.program.reference_outputs` are held to.
 
 The ``execute_plan``/``execute_workload`` shims that once lived here
 (deprecated in PR 3) were removed in PR 6; ``Machine(cfg).run(...)`` is
@@ -72,6 +74,41 @@ def validate_coverage(
 
 def _instance_view(arr: np.ndarray, inst: int, ndim: int) -> np.ndarray:
     return arr if arr.ndim == ndim else arr[inst]
+
+
+def _max_abs(arr: np.ndarray) -> int:
+    """Largest |element| of an integer array, as a Python int (no
+    overflow: ``np.abs`` of int8 -128 is -128)."""
+    return max(-int(arr.min()), int(arr.max())) if arr.size else 0
+
+
+# Float accumulators, narrowest first, and the bound up to which every
+# integer is one they hold exactly (2**(mantissa bits + 1)).
+_EXACT_UP_TO = ((np.float32, 2 ** 24), (np.float64, 2 ** 53))
+
+
+def exact_int_matmul(
+    x: np.ndarray, w: np.ndarray,
+) -> Tuple[np.ndarray, str]:
+    """Exact ``x @ w`` of integer operands as int64 ``[count, M, N]``, and
+    the path that computed it (``float32_exact``, ``float64_exact`` or
+    ``int64``).
+
+    ``x`` is ``[M, K]`` (shared) or ``[count, M, K]``, ``w`` ``[K, N]`` or
+    ``[count, K, N]``.  Every partial sum is bounded by
+    ``K * max|x| * max|w|`` (read from the data); the narrowest float whose
+    integers reach that bound computes the product in one BLAS matmul, and
+    each partial sum is then an integer it holds, in any summation order.
+    Past 2**53, one int64 matmul, which NumPy runs without BLAS.  All give
+    the same integers.
+    """
+    x, w = np.asarray(x), np.asarray(w)
+    bound = x.shape[-1] * _max_abs(x) * _max_abs(w)
+    acc = next((f for f, limit in _EXACT_UP_TO if bound <= limit), np.int64)
+    out = np.matmul(x.astype(acc), w.astype(acc)).astype(np.int64)
+    path = "int64" if acc is np.int64 else f"{np.dtype(acc).name}_exact"
+    return (out if out.ndim == 3 else out[None]), path
+
 
 def _pad_axis(arr: np.ndarray, axis: int, target: int) -> np.ndarray:
     if arr.shape[axis] == target:

@@ -43,6 +43,8 @@ from repro.legion import (
     TrafficTracer,
     synthesize_operands,
 )
+from repro.legion.runtime import exact_int_matmul
+from repro.obs.metrics import MetricsRegistry
 
 CFG = dlegion()                 # 8 Legions x 8 cores x 16x16
 CFG1 = dlegion(legions=1)
@@ -118,6 +120,140 @@ def test_plan_runs_check_outputs_by_default():
     rep = Machine(CFG, backend=Zeros()).run(plan, x, weights,
                                             check_outputs=False)
     assert not rep.outputs.any()
+
+
+def _int64_products(x, w, count):
+    return np.stack([
+        (x if x.ndim == 2 else x[i]).astype(np.int64)
+        @ (w if w.ndim == 2 else w[i]).astype(np.int64)
+        for i in range(count)
+    ])
+
+
+def _int_operands(case, rng):
+    """(x, w, count) for one exact_int_matmul case."""
+    if case == "shared_int8":
+        return (rng.integers(-8, 9, (16, 64)).astype(np.int8),
+                rng.integers(-1, 2, (3, 64, 8)).astype(np.int8), 3)
+    if case == "per_instance_int8_extremes":
+        x = rng.choice([-128, 127], (4, 16, 128)).astype(np.int8)
+        w = rng.choice([-128, 127], (4, 128, 8)).astype(np.int8)
+        return x, w, 4
+    if case == "per_instance_x_shared_w_int16":
+        return (rng.integers(-2 ** 15, 2 ** 15, (2, 8, 32)).astype(np.int16),
+                rng.integers(-2 ** 15, 2 ** 15, (32, 8)).astype(np.int16), 2)
+    if case == "int32_small":
+        return (rng.integers(-100, 101, (3, 8, 32)).astype(np.int32),
+                rng.integers(-100, 101, (3, 32, 8)).astype(np.int32), 3)
+    if case == "bound_just_under_2_24":
+        # 16 * 2**10 * (2**10 - 1) = 2**24 - 2**14, with extreme elements
+        x = rng.choice([-2 ** 10, 2 ** 10, 2 ** 10 - 3], (2, 4, 16))
+        w = rng.choice([-(2 ** 10 - 1), 2 ** 10 - 1, 2 ** 10 - 5], (2, 16, 4))
+        return x.astype(np.int16), w.astype(np.int32), 2
+    if case == "bound_just_under_2_53":
+        # 8 * 2**25 * (2**25 - 1) = 2**53 - 2**28, with extreme elements
+        x = rng.choice([-2 ** 25, 2 ** 25, 2 ** 25 - 3], (2, 4, 8))
+        w = rng.choice([-(2 ** 25 - 1), 2 ** 25 - 1, 2 ** 25 - 5], (2, 8, 4))
+        return x.astype(np.int64), w.astype(np.int32), 2
+    # each product (2**b + 1)**2 = 2**(2b) + 2**(b+1) + 1; three of them
+    # sum to an odd integer past 2**(2b+1), which the narrower float
+    # cannot hold
+    b = {"bound_over_2_24": 12, "bound_over_2_53": 26}[case]
+    x = np.full((2, 4, 3), 2 ** b + 1, np.int64)
+    w = np.full((2, 3, 4), 2 ** b + 1, np.int64)
+    w[1] *= -1
+    return x, w, 2
+
+
+@pytest.mark.parametrize("case,path,inexact_in", [
+    ("shared_int8", "float32_exact", None),
+    ("per_instance_int8_extremes", "float32_exact", None),
+    ("per_instance_x_shared_w_int16", "float64_exact", None),
+    ("int32_small", "float32_exact", None),
+    ("bound_just_under_2_24", "float32_exact", None),
+    ("bound_over_2_24", "float64_exact", np.float32),
+    ("bound_just_under_2_53", "float64_exact", None),
+    ("bound_over_2_53", "int64", np.float64),
+])
+def test_exact_int_matmul_equals_int64_product(case, path, inexact_in):
+    """The check's matmul is the int64 product bit for bit: in the
+    narrowest float that holds K * max|x| * max|w| (BLAS), in int64 past
+    2**53."""
+    x, w, count = _int_operands(case, np.random.default_rng(7))
+    got, took = exact_int_matmul(x, w)
+    want = _int64_products(x, w, count)
+    assert took == path
+    assert got.dtype == np.int64 and got.shape == want.shape
+    assert np.array_equal(got, want)
+    if inexact_in is not None:
+        # the narrower float's product of these operands rounds: the wider
+        # path is what keeps the result exact
+        narrow = np.matmul(x.astype(inexact_in), w.astype(inexact_in))
+        assert not np.array_equal(narrow.astype(np.int64), want)
+
+
+PATHS = ("float32_exact", "float64_exact", "int64", "float")
+
+
+def _checked_stage(path):
+    """(plan, x, w) for a W8 stage whose output check takes ``path``."""
+    w8 = _w8()
+    plan = plan_stage(CFG, w8)
+    x, weights = synthesize_operands(w8, seed=3)
+    x = np.broadcast_to(x, (w8.count,) + x.shape[-2:]).copy()
+    if path == "float":
+        return plan, x.astype(np.float32), weights.astype(np.float32)
+    if path != "float32_exact":
+        # int32 extremes the products never meet: x's column 0 meets w's
+        # zeroed row 0, w's row 1 meets x's zeroed column 1 — the bound
+        # passes 2**24 (and, with both, 2**53), the outputs stay small
+        x, weights = x.astype(np.int32), weights.astype(np.int32)
+        weights[:, 0, :] = 0
+        x[:, :, 1] = 0
+        x[0, 0, 0] = np.iinfo(np.int32).max
+        if path == "int64":
+            weights[0, 1, 0] = np.iinfo(np.int32).max
+    return plan, x, weights
+
+
+@pytest.mark.parametrize("path", ["float32_exact", "float64_exact", "int64"])
+def test_check_catches_one_wrong_element(path):
+    """One wrong output element still fails the stage check, on every
+    integer path, naming its instance and position count."""
+
+    class OffByOne(InProcessExecutor):
+        name = "off-by-one"
+
+        def execute(self, ctx, instruments):
+            out = super().execute(ctx, instruments)
+            out[1, 3, 5] += 1
+            return out
+
+    plan, x, weights = _checked_stage(path)
+    reg = MetricsRegistry()
+    Machine(CFG, metrics=reg).run(plan, x, weights)
+    assert reg.counter("machine_check_stages", labels=("path",)) \
+        .value(path=path) == 1
+    with pytest.raises(AssertionError,
+                       match="instance 1: runtime output != x @ w reference "
+                             "at 1 positions"):
+        Machine(CFG, backend=OffByOne()).run(plan, x, weights)
+
+
+@pytest.mark.parametrize(
+    "path", ["float32_exact", "float64_exact", "int64", "float"])
+def test_check_counts_its_path(path):
+    """machine_check_stages counts each checked stage under its path, and
+    nothing when the check is off."""
+    plan, x, weights = _checked_stage(path)
+    reg = MetricsRegistry()
+    machine = Machine(CFG, metrics=reg)
+    machine.run(plan, x, weights)
+    machine.run(plan, x, weights)
+    machine.run(plan, x, weights, check_outputs=False)
+    counter = reg.counter("machine_check_stages", labels=("path",))
+    assert {p: counter.value(path=p)
+            for p in PATHS} == {p: (2 if p == path else 0) for p in PATHS}
 
 
 def test_run_input_errors():
